@@ -14,7 +14,6 @@ import argparse
 import time
 
 import jax
-from repro.compat import set_mesh
 import jax.numpy as jnp
 import numpy as np
 
@@ -46,7 +45,7 @@ def main():
         prompts = jnp.asarray(rng.randint(
             0, cfg.vocab_size, (args.batch, args.prompt_len)).astype(np.int32))
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # ---- prefill ---------------------------------------------------
         t0 = time.perf_counter()
         first_tok, cache, cur = jax.jit(

@@ -19,7 +19,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-from repro.compat import set_mesh
 import numpy as np
 
 from repro.configs.base import get_config, reduced_config
@@ -73,7 +72,7 @@ def main():
 
     watchdog = StragglerWatchdog()
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         result = run_loop(
             train_step=step_fn, make_batch=mb, params=params,
             opt_state=opt_state, n_steps=args.steps,

@@ -1,91 +1,22 @@
-"""Version-tolerant JAX API shims.
+"""Mesh construction with Auto axes.
 
-The codebase is written against the modern JAX surface (``jax.shard_map``
-with ``check_vma``, ``jax.sharding.AxisType``, ``jax.set_mesh``,
-``jax.lax.pvary``).  Deployment environments pin older releases (this
-container ships 0.4.37, where none of those exist yet), so every use of
-a moved/renamed API goes through this module instead of ``jax`` directly:
-
-  * ``shard_map``  — ``jax.shard_map(check_vma=...)`` on new JAX,
-    ``jax.experimental.shard_map.shard_map(check_rep=...)`` on old.
-  * ``pvary``      — varying-axes annotation; a data no-op, so the old-JAX
-    fallback is the identity (old shard_map with ``check_rep=False`` does
-    not track varying axes at all).
-  * ``make_mesh``  — drops the ``axis_types`` kwarg when unsupported.
-  * ``set_mesh``   — falls back to ``jax.sharding.use_mesh`` or the plain
-    ``Mesh`` context manager.
-  * ``AxisType``   — stand-in enum when ``jax.sharding.AxisType`` is absent.
+``jax.make_mesh`` gives every axis ``AxisType.Explicit`` unless told
+otherwise.  The library's operands are placed with ``NamedSharding``
+and its multiplies run in ``shard_map`` programs that expect Auto
+(GSPMD) axes, so every mesh is built here.
 
 Keep this module dependency-free (jax only) so anything may import it.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["AxisType", "HAS_AXIS_TYPES", "make_mesh", "shard_map",
-           "pvary", "set_mesh", "axis_size"]
-
-
-try:  # JAX >= 0.5: axis types are real (Auto/Explicit/Manual sharding)
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-    HAS_AXIS_TYPES = True
-except ImportError:  # old JAX: every mesh axis behaves like Auto
-    HAS_AXIS_TYPES = False
-
-    class AxisType:  # minimal stand-in so call sites can still name them
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+__all__ = ["make_mesh"]
 
 
-def make_mesh(axis_shapes, axis_names, axis_types=None):
-    """``jax.make_mesh`` that tolerates missing ``axis_types`` support."""
-    axis_shapes = tuple(axis_shapes)
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
     axis_names = tuple(axis_names)
-    if HAS_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-if hasattr(jax, "shard_map"):  # JAX >= 0.6 public API
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
-
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-
-    def axis_size(axis_name):
-        # classic idiom: psum of a Python scalar folds to the axis size
-        return jax.lax.psum(1, axis_name)
-
-
-if hasattr(jax.lax, "pvary"):
-    pvary = jax.lax.pvary
-else:
-
-    def pvary(x, axis_name):
-        return x
-
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-elif hasattr(jax.sharding, "use_mesh"):
-    set_mesh = jax.sharding.use_mesh  # type: ignore[attr-defined]
-else:
-    def set_mesh(mesh):
-        # old JAX: Mesh is itself a context manager (global resource env)
-        return mesh
+    return jax.make_mesh(tuple(axis_shapes), axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))

@@ -36,8 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .blocking import GridSpec
 from .schedule import (RolledSpec, Schedule, execute_schedule,
                        resolve_pipeline_depth)
@@ -127,8 +125,7 @@ def build_cannon_schedule(
         prologue=prologue,
         shift=shift,
         empty_steps=frozenset(empty_steps),
-        rolled=RolledSpec(shift=rolled_shift,
-                          vary_axes=(row_axis, col_axis)),
+        rolled=RolledSpec(shift=rolled_shift),
         comm_op=f"ppermute(a:{col_axis}, b:{row_axis})",
         prologue_comm_bytes=prologue_bytes,
         # the final step receives no shift: n_steps - 1 shifts total
@@ -326,6 +323,6 @@ def cannon_matmul(
     # the ppermute skew/shift callables are shape-agnostic, so the same
     # schedule drives single products and batches alike
     spec = P(*([None] * (a.ndim - 2)), grid.row_axis, grid.col_axis)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                   out_specs=spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(a, b)

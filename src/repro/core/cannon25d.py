@@ -34,8 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .blocking import GridSpec
 from .cannon import _default_local_matmul, build_cannon_schedule
 from .schedule import Schedule, execute_schedule, resolve_pipeline_depth
@@ -162,6 +160,6 @@ def cannon25d_matmul(
         # psum_scatter chunk p of the local block goes to pod p => the
         # stack axis is the *minor* factor of the row partition.
         out_spec = P((grid.row_axis, grid.stack_axis), grid.col_axis)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec2d, spec2d),
-                   out_specs=out_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec2d, spec2d),
+                       out_specs=out_spec, check_vma=False)
     return fn(a, b)

@@ -74,8 +74,6 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pvary
-
 __all__ = [
     "Schedule",
     "RolledSpec",
@@ -114,7 +112,6 @@ class RolledSpec:
     """
 
     shift: Callable  # carry -> carry (step-independent)
-    vary_axes: Tuple[str, ...]  # grid axes the accumulator varies over
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,9 +205,6 @@ def execute_schedule(
             c_c = c_c + local_matmul(a_c, b_c).astype(accum_dtype)
             return rolled.shift(inner), c_c
 
-        # the zero-init accumulator must enter the loop already marked
-        # varying over the grid axes (its per-step updates are)
-        c = pvary(c, rolled.vary_axes)
         _, c = jax.lax.fori_loop(0, n, body, (carry, c))
         return sched.epilogue(c).astype(out_dtype)
 

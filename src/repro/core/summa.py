@@ -36,8 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .blocking import GridSpec
 from .cannon import _default_local_matmul
 from .schedule import Schedule, execute_schedule, resolve_pipeline_depth
@@ -371,6 +369,6 @@ def summa_matmul(
     # leading batch dims (a fused product batch (G, m, k)) replicate;
     # the trailing two axes shard over the process grid as always
     spec = P(*([None] * (a.ndim - 2)), grid.row_axis, grid.col_axis)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                   out_specs=spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(a, b)

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .blocking import GridSpec
 from .cannon import _default_local_matmul
 from .schedule import Schedule, execute_schedule, resolve_pipeline_depth
@@ -324,6 +322,6 @@ def tall_skinny_matmul(
     else:  # ts_k
         in_specs = (P(None, axes), P(axes, None))
         out_spec = P(None, None) if reduce == "all_reduce" else P(axes, None)
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_spec, check_vma=False)
     return fn(a, b)

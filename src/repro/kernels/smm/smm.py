@@ -9,7 +9,7 @@ those CUDA dimensions exist on TPU; the TPU-native parameter space is:
   * MXU alignment padding (the systolic array wants multiples of
     (8, 128) lanes; small DBCSR blocks of 22/64 are padded by ops.py),
   * the grid layout (one grid step per stack entry, scalar-prefetched
-    indices).
+    indices, flattened so a 30,000-entry stack fits in SMEM).
 
 The stack's (a, b, c) indices are data: they drive *which* blocks each
 grid step touches.  That requires scalar prefetch
@@ -39,22 +39,25 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["smm_pallas_call"]
 
 
-def _smm_kernel(triples_ref, a_ref, b_ref, c_in_ref, c_out_ref):
+def _smm_kernel(triples_ref, a_ref, b_ref, c_in_ref, c_out_ref, *, width):
     s = pl.program_id(0)
+    row = width * s  # entry s occupies triples_ref[row : row + width]
     # first grid step of this C block's contiguous run?
     prev_same = jnp.where(
-        s > 0, triples_ref[jnp.maximum(s - 1, 0), 2] == triples_ref[s, 2], False
+        s > 0,
+        triples_ref[jnp.maximum(row - width, 0) + 2] == triples_ref[row + 2],
+        False,
     )
     prod = jnp.dot(
         a_ref[0].astype(jnp.float32),
         b_ref[0].astype(jnp.float32),
         preferred_element_type=jnp.float32,
     )
-    if triples_ref.shape[1] > 3:
+    if width > 3:
         # masked triples (fused-executor stack padding): column 3 is a
         # validity flag — zero the padding entries' product so their
         # accumulation into the scratch C block is a no-op.
-        prod = prod * triples_ref[s, 3].astype(jnp.float32)
+        prod = prod * triples_ref[row + 3].astype(jnp.float32)
 
     @pl.when(jnp.logical_not(prev_same))
     def _init():  # start of run: seed with the incoming C block
@@ -70,29 +73,34 @@ def smm_pallas_call(
     a_blocks: jax.Array,  # (Na, bm, bk)
     b_blocks: jax.Array,  # (Nb, bk, bn)
     c_blocks: jax.Array,  # (Nc, bm, bn) float32
-    triples: jax.Array,   # (S, 3) int32, c-runs contiguous
+    triples: jax.Array,   # (S, 3|4) int32, c-runs contiguous
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    s_len = triples.shape[0]
+    s_len, width = triples.shape
     _, bm, bk = a_blocks.shape
     _, bk2, bn = b_blocks.shape
     assert bk == bk2, (a_blocks.shape, b_blocks.shape)
 
+    # Scalar-prefetch the triples flattened to (width * S,): SMEM pads
+    # the minor dim of a 2-D operand to 128 lanes (512 B per entry), so
+    # an (S, 4) operand outgrows v5e's 1 MiB of SMEM at ~2,000 entries,
+    # while the flat layout costs 16 B per entry (~65,000 entries).
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(s_len,),
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda s, t: (t[s, 0], 0, 0)),
-            pl.BlockSpec((1, bk, bn), lambda s, t: (t[s, 1], 0, 0)),
-            pl.BlockSpec((1, bm, bn), lambda s, t: (t[s, 2], 0, 0)),
+            pl.BlockSpec((1, bm, bk), lambda s, t: (t[width * s], 0, 0)),
+            pl.BlockSpec((1, bk, bn), lambda s, t: (t[width * s + 1], 0, 0)),
+            pl.BlockSpec((1, bm, bn), lambda s, t: (t[width * s + 2], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda s, t: (t[s, 2], 0, 0)),
+        out_specs=pl.BlockSpec((1, bm, bn),
+                               lambda s, t: (t[width * s + 2], 0, 0)),
     )
     return pl.pallas_call(
-        _smm_kernel,
+        functools.partial(_smm_kernel, width=width),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(c_blocks.shape, jnp.float32),
         input_output_aliases={3: 0},  # c_blocks buffer is donated to out
         interpret=interpret,
-    )(triples, a_blocks, b_blocks, c_blocks)
+    )(triples.reshape(-1), a_blocks, b_blocks, c_blocks)

@@ -9,7 +9,6 @@ import time           # noqa: E402
 import traceback      # noqa: E402
 
 import jax            # noqa: E402
-from repro.compat import set_mesh
 
 from repro.configs.base import ARCHS, SHAPES, get_config    # noqa: E402
 from repro.launch.mesh import make_production_mesh, HW      # noqa: E402
@@ -61,7 +60,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     jit_kwargs = dict(in_shardings=in_sh, donate_argnums=donate)
     if out_sh is not None:
         jit_kwargs["out_shardings"] = out_sh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, **jit_kwargs).lower(*args)
         t_lower = time.time() - t0
         t0 = time.time()

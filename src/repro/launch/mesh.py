@@ -16,7 +16,7 @@ module does not touch jax device state.
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh as _make_mesh_compat
+from repro.compat import make_mesh
 
 __all__ = ["make_production_mesh", "make_mesh", "HW"]
 
@@ -33,11 +33,4 @@ HW = {
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh_compat(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh with Auto axis types (tests, reduced configs)."""
-    return _make_mesh_compat(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes))
+    return make_mesh(shape, axes)

@@ -49,7 +49,6 @@ def main():
     from repro.train.elastic import StragglerWatchdog, run_loop
     from repro.train.optimizer import OptConfig, make_optimizer
     from repro.train.train_step import make_train_step, shardings_for
-    from repro.compat import set_mesh
 
     if args.mesh == "production":
         mesh = make_production_mesh(multi_pod=False)
@@ -85,7 +84,7 @@ def main():
         return jax.device_put({k: jnp.asarray(v) for k, v in b.items()}, b_sh)
 
     watchdog = StragglerWatchdog()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         result = run_loop(
             train_step=step_fn, make_batch=mb, params=params,
             opt_state=opt_state, n_steps=args.steps,

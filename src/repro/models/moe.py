@@ -33,8 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
-
 from .common import ParamDef, act_fn
 
 __all__ = ["moe_defs", "moe_apply"]
@@ -96,7 +94,7 @@ def moe_local(
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-device MoE body (inside shard_map). Returns (partial_out, aux)."""
     t, d = x.shape
-    e, e_loc = cfg.n_experts, cfg.n_experts // axis_size(tp_axis)
+    e, e_loc = cfg.n_experts, cfg.n_experts // jax.lax.axis_size(tp_axis)
     k = cfg.top_k
     cap = _capacity(t, cfg)
     m = jax.lax.axis_index(tp_axis)
@@ -149,7 +147,7 @@ def moe_local(
             # shard contracts its slice and the small (E_loc, C, f)
             # activations are psum'd — the decode-side replacement for
             # the 1.4 GB/layer weight gathers (EXPERIMENTS.md §Perf).
-            nd = axis_size(fsdp_axes)
+            nd = jax.lax.axis_size(fsdp_axes)
             ix = jax.lax.axis_index(fsdp_axes)
             dsl = d // nd
             tok_slice = jax.lax.dynamic_slice_in_dim(
@@ -285,7 +283,7 @@ def moe_apply(
     # check_vma=False: with B=1 decode the tokens are replicated over the
     # data axes while FSDP weight-gathers still run over them — outputs
     # are replicated by construction but the static analysis can't see it.
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspec, P(dp_part, None, None)),

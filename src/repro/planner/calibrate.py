@@ -182,8 +182,6 @@ def micro_calibrate(mesh=None, grid=None, reps: int = 5) -> Dict[str, float]:
     if mesh is not None and grid is not None and mesh.devices.size > 1:
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
         axes = (grid.row_axis, grid.col_axis)
         spec = P(axes[0], axes[1])
         pr, pc = grid.grid_shape(mesh)
@@ -200,9 +198,9 @@ def micro_calibrate(mesh=None, grid=None, reps: int = 5) -> Dict[str, float]:
                     x = jax.lax.psum(x + np.float32(i), axes)
                 return x
 
-            return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
-                                     out_specs=P(None, None),
-                                     check_vma=False))
+            return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                                         out_specs=P(None, None),
+                                         check_vma=False))
 
         reps_n = 8
         tiny = jnp.ones((pr, pc), jnp.float32)

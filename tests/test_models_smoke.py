@@ -6,7 +6,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from repro.compat import set_mesh
 
 from repro.configs.base import ARCHS, get_config, reduced_config
 from repro.launch.mesh import make_mesh
@@ -38,7 +37,7 @@ def test_forward_and_loss(arch, mesh):
     cfg = reduced_config(get_config(arch))
     params = T.model_init(cfg, jax.random.PRNGKey(0))
     batch = _batch(cfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, hidden, aux, _ = jax.jit(
             lambda p, b: T.forward(p, b["inputs"], cfg, mesh))(params, batch)
         loss, metrics = jax.jit(
@@ -57,7 +56,7 @@ def test_train_step_decreases_loss(arch, mesh):
     opt_state = opt.init(params)
     step = make_train_step(cfg, mesh, opt)
     batch = _batch(cfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep = jax.jit(step, donate_argnums=(0, 1))
         losses = []
         for _ in range(4):
@@ -79,7 +78,7 @@ def test_prefill_decode_consistency(arch, mesh):
     b, s = 2, 12
     batch = _batch(cfg, b=b, s=s, seed=3)
     inputs = batch["inputs"]
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # full forward logits at the last position
         logits_full, _, _, _ = T.forward(params, inputs, cfg, mesh)
         # prefill on the first s-1 tokens, then decode token s-1

@@ -39,7 +39,7 @@ import json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import make_mesh, pvary, shard_map
+from repro.compat import make_mesh
 from repro.core.blocking import GridSpec
 from repro.core.cannon import (_default_local_matmul, _shift_perm,
                                _skew_perm, cannon_matmul, cannon_step_masks)
@@ -86,7 +86,6 @@ def legacy_cannon_local_steps(a_blk, b_blk, *, pg, row_axis, col_axis,
             a_c = jax.lax.ppermute(a_c, col_axis, shift_a)
             b_c = jax.lax.ppermute(b_c, row_axis, shift_b)
             return a_c, b_c, c_c
-        c_blk = pvary(c_blk, (row_axis, col_axis))
         _, _, c_blk = jax.lax.fori_loop(0, n_steps, body, (a_blk, b_blk, c_blk))
     return c_blk
 
@@ -103,8 +102,8 @@ def legacy_cannon(a, b, *, mesh, grid, local_matmul, out_dtype=None,
             out_dtype=jnp.float32, double_buffer=double_buffer)
         return c.astype(out_dtype)
     spec = P(grid.row_axis, grid.col_axis)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=spec, check_vma=False)(a, b)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)(a, b)
 
 
 def legacy_cannon25d(a, b, *, mesh, grid, local_matmul, out_dtype=None):
@@ -123,8 +122,8 @@ def legacy_cannon25d(a, b, *, mesh, grid, local_matmul, out_dtype=None):
             out_dtype=jnp.float32, skew=False, steps=spr)
         return jax.lax.psum(c_partial, grid.stack_axis).astype(out_dtype)
     spec2d = P(grid.row_axis, grid.col_axis)
-    return shard_map(body, mesh=mesh, in_specs=(spec2d, spec2d),
-                     out_specs=spec2d, check_vma=False)(a, b)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec2d, spec2d),
+                         out_specs=spec2d, check_vma=False)(a, b)
 
 
 def legacy_summa(a, b, *, mesh, grid, local_matmul, out_dtype=None):
@@ -160,8 +159,8 @@ def legacy_summa(a, b, *, mesh, grid, local_matmul, out_dtype=None):
                 c = c + part.astype(jnp.float32)
         return c.astype(out_dtype)
     spec = P(row_ax, col_ax)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=spec, check_vma=False)(a, b)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)(a, b)
 
 
 def legacy_ts_k(a, b, *, mesh, grid, local_matmul, out_dtype=None,
@@ -178,8 +177,9 @@ def legacy_ts_k(a, b, *, mesh, grid, local_matmul, out_dtype=None,
                                      tiled=True)
         return c.astype(out_dtype)
     out_spec = P(None, None) if reduce == "all_reduce" else P(axes, None)
-    return shard_map(body_k, mesh=mesh, in_specs=(P(None, axes), P(axes, None)),
-                     out_specs=out_spec, check_vma=False)(a, b)
+    return jax.shard_map(body_k, mesh=mesh,
+                         in_specs=(P(None, axes), P(axes, None)),
+                         out_specs=out_spec, check_vma=False)(a, b)
 
 
 # ---- battery ----------------------------------------------------------

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from conftest import run_subprocess_devices
@@ -86,9 +87,9 @@ def _count_named_calls(jaxpr, name) -> int:
         for v in eqn.params.values():
             subs = v if isinstance(v, (list, tuple)) else [v]
             for s in subs:
-                if isinstance(s, jax.core.ClosedJaxpr):
+                if isinstance(s, jex_core.ClosedJaxpr):
                     s = s.jaxpr
-                if isinstance(s, jax.core.Jaxpr):
+                if isinstance(s, jex_core.Jaxpr):
                     count += _count_named_calls(s, name)
     return count
 
