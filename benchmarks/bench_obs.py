@@ -1,18 +1,19 @@
-"""Telemetry layer: tracing overhead + trace-schema + scoreboard gates.
+"""Telemetry layer: tracing overhead + profiler-span + scoreboard gates.
 
 The observability contract (repro.obs) has three measurable halves,
 and this bench gates all of them in CI:
 
-  overhead    a TRACED multiply (spans, per-step timeline, plan-outcome
-              logging) vs the identical untraced one on the pinned
-              deterministic config — tracing must cost <= 5% (or fall
-              inside an absolute jitter floor; the disabled-by-default
-              path is separately bitwise-gated in tests/test_obs.py)
-  trace       the Chrome-trace JSON exported for one traced
-              ``dbcsr.multiply(return_plan=True)`` must pass
-              ``validate_chrome_trace`` (schema, nesting, finite
-              timestamps), and the synthetic schedule-step spans must
-              sum consistently with the measured dispatch wall time
+  overhead    a TRACED multiply (span records, synchronised dispatch,
+              plan-outcome logging) vs the identical untraced one on the
+              pinned deterministic config — tracing must cost <= 5% (or
+              fall inside an absolute jitter floor; the disabled-by-
+              default path is separately bitwise-gated in
+              tests/test_obs.py)
+  spans       one ``dbcsr.multiply`` under a ``jax.profiler`` session
+              must leave ``dbcsr.multiply`` > ``dbcsr.plan``,
+              ``dbcsr.stacks``, ``dbcsr.dispatch``, ``dbcsr.finish`` in
+              the profiler's trace, in that order and inside the root,
+              with the JAX runtime's counters on the root and dispatch
   scoreboard  a pinned algorithm sweep must leave one
               predicted-vs-actual row per executed algorithm, each
               with a finite signed relative error — the input
@@ -45,7 +46,9 @@ EXEC_KW = dict(algorithm="cannon", densify=False, local_kernel="ref",
 
 OVERHEAD_GATE = 0.05          # traced <= 5% over untraced ...
 OVERHEAD_ABS_FLOOR_S = 2e-3   # ... or within the host-timing jitter floor
-STEP_SUM_TOL = 0.05           # children-vs-dispatch duration agreement
+PHASES = ["dbcsr.plan", "dbcsr.stacks", "dbcsr.dispatch", "dbcsr.finish"]
+COUNTERS = ("lowerings", "traces", "compiles", "cache_hits", "lower_s",
+            "compile_s", "lowered")
 SWEEP_ALGOS = ("cannon", "summa", "ts_k")
 
 
@@ -104,49 +107,39 @@ def bench_overhead(mesh, geometry, block, reps, rng):
     return row
 
 
-def bench_trace_schema(mesh, geometry, block, rng, out_dir):
-    """One traced multiply -> valid Chrome trace + consistent durations."""
+def bench_profile_spans(mesh, geometry, block, rng, out_dir):
+    """One profiled multiply -> the phase spans nested on one clock."""
     m, k, n = geometry
     a = dbcsr.create(rng.randn(m, k).astype(np.float32), mesh=mesh,
                      block_size=block)
     b = dbcsr.create(rng.randn(k, n).astype(np.float32), mesh=mesh,
                      block_size=block)
-    obs.enable()
-    c, plan = dbcsr.multiply(a, b, mesh=mesh, return_plan=True, **EXEC_KW)
-    jax.block_until_ready(c.data)
-    obs.disable()
-    spans = obs.last_trace()
+    trace_dir = os.path.join(out_dir, "obs_profile")
+    with jax.profiler.trace(trace_dir):
+        c = dbcsr.multiply(a, b, mesh=mesh, **EXEC_KW)
+        jax.block_until_ready(c.data)
+    spans = obs.profile_spans(trace_dir)
 
-    trace_path = os.path.join(out_dir, "obs_multiply_trace.json")
-    chrome = obs.to_chrome_trace(spans)
-    obs.write_chrome_trace(trace_path, spans)
-    errors = obs.validate_chrome_trace(chrome)
-
-    by_id = {s.span_id: s for s in spans}
     roots = [s for s in spans if s.parent_id is None]
-    dispatches = [s for s in spans if s.name == "dispatch"]
-    consistency = {"n_spans": len(spans), "n_roots": len(roots),
-                   "n_dispatch": len(dispatches)}
-    durations_ok = len(roots) == 1 and len(dispatches) == 1
-    if durations_ok:
-        root, disp = roots[0], dispatches[0]
-        kids = [s for s in spans if s.parent_id == disp.span_id]
-        kid_sum = sum(s.dur for s in kids)
-        rel_gap = (abs(kid_sum - disp.dur) / disp.dur
-                   if disp.dur > 0 else float("inf"))
-        durations_ok = (bool(kids) and rel_gap <= STEP_SUM_TOL
-                        and root.dur >= disp.dur > 0)
-        consistency.update({
-            "root_s": root.dur, "dispatch_s": disp.dur,
-            "step_children": len(kids), "children_sum_s": kid_sum,
-            "rel_gap": rel_gap, "tol": STEP_SUM_TOL,
-        })
-    row = {"trace_path": trace_path, "schema_errors": errors,
-           "consistency": consistency, "durations_ok": durations_ok}
-    print(f"trace:    {len(spans)} spans -> {trace_path}  "
-          f"schema errors: {len(errors)}  "
-          f"step-sum gap: {consistency.get('rel_gap', float('nan'))*100:.1f}% "
-          f"(tol {STEP_SUM_TOL*100:.0f}%)")
+    nested_ok = len(roots) == 1 and roots[0].name == "dbcsr.multiply"
+    row = {"trace_dir": trace_dir, "n_spans": len(spans),
+           "n_roots": len(roots)}
+    if nested_ok:
+        root = roots[0]
+        kids = sorted((s for s in spans if s.parent_id == root.span_id),
+                      key=lambda s: s.t0)
+        disp = [s for s in kids if s.name == "dbcsr.dispatch"]
+        nested_ok = ([s.name for s in kids] == PHASES
+                     and sum(s.dur for s in kids) <= root.dur
+                     and all(key in s.attrs for key in COUNTERS
+                             for s in [root] + disp))
+        row.update(root_s=root.dur, phases_s={s.name: s.dur for s in kids},
+                   root_counters={key: root.attrs.get(key)
+                                  for key in COUNTERS})
+    row["nested_ok"] = nested_ok
+    print(f"spans:    {len(spans)} dbcsr.* spans -> {trace_dir}  "
+          f"nested with counters: {nested_ok}")
+    print(obs.render_timeline(spans))
     return row
 
 
@@ -181,11 +174,11 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="small geometry, few reps -> obs_smoke.json")
     ap.add_argument("--check", action="store_true",
-                    help="exit nonzero unless tracing overhead <= 5%, the "
-                         "Chrome trace validates with consistent "
-                         "durations, and the sweep scoreboard has a "
-                         "finite predicted-vs-actual row per algorithm "
-                         "(CI gate)")
+                    help="exit nonzero unless tracing overhead <= 5%, a "
+                         "profiled multiply leaves its phase spans nested "
+                         "with the runtime's counters, and the sweep "
+                         "scoreboard has a finite predicted-vs-actual row "
+                         "per algorithm (CI gate)")
     ap.add_argument("--out", default="artifacts/bench")
     ap.add_argument("--obs-dir", default="artifacts/obs",
                     help="log dir for the sweep's plan_outcomes.jsonl "
@@ -203,19 +196,18 @@ def main():
     os.makedirs(args.obs_dir, exist_ok=True)
 
     overhead = bench_overhead(mesh, geometry, block, reps, rng)
-    trace = bench_trace_schema(mesh, geometry, block, rng, args.out)
+    spans = bench_profile_spans(mesh, geometry, block, rng, args.out)
     scoreboard = bench_scoreboard(mesh, geometry, block, rng, args.obs_dir)
 
     gates = {
         "overhead_ok": bool(overhead["ok"]),
-        "trace_valid": not trace["schema_errors"],
-        "durations_consistent": bool(trace["durations_ok"]),
+        "spans_nested": bool(spans["nested_ok"]),
         "scoreboard_complete": bool(scoreboard["complete"]),
     }
     result = {
         "exec_kw": {k: str(v) for k, v in EXEC_KW.items()},
         "overhead": overhead,
-        "trace": trace,
+        "spans": spans,
         "scoreboard": scoreboard,
         "gates": gates,
     }

@@ -60,19 +60,18 @@ def main():
     #     cannon25d+densified     -         -           -           -
     #                           infeasible: no replication axis
     print(plan_multiply(n, n, n, mesh_shape=(4, 4)).explain())
-    # one TRACED run: the telemetry layer (repro.obs) turns the same
-    # schedule metadata into a span timeline + Chrome trace instead of
-    # a raw stats dump (open artifacts/obs/multiply_trace.json in
-    # ui.perfetto.dev or chrome://tracing)
+    # one TRACED run: the multiply's phase spans land in a jax.profiler
+    # trace beside the device ops (open artifacts/obs/profile in
+    # TensorBoard's profile plugin or ui.perfetto.dev), and the
+    # telemetry layer (repro.obs) records them for its breakdown
     obs.enable(log_dir="artifacts/obs")
-    _, xplan = distributed_matmul(Ad, Bd, mesh=mesh, grid=grid,
-                                  return_plan=True)
-    trace = obs.last_trace()
-    obs.write_chrome_trace("artifacts/obs/multiply_trace.json", trace)
-    print("  trace timeline (spans; full trace -> "
-          "artifacts/obs/multiply_trace.json):")
-    print(obs.render_timeline(trace))
-    print(obs.render_breakdown(trace))
+    with jax.profiler.trace("artifacts/obs/profile"):
+        _, xplan = distributed_matmul(Ad, Bd, mesh=mesh, grid=grid,
+                                      return_plan=True)
+    print("  profiled timeline (dbcsr.* spans; full trace -> "
+          "artifacts/obs/profile):")
+    print(obs.render_timeline(obs.profile_spans("artifacts/obs/profile")))
+    print(obs.render_breakdown(obs.last_trace()))
     obs.disable()  # timed comparisons below run with zero overhead
     c1, t_auto = timed("auto (planner)", jax.jit(
         lambda a, b: distributed_matmul(a, b, mesh=mesh, grid=grid)), Ad, Bd)

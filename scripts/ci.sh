@@ -70,10 +70,12 @@ python benchmarks/bench_abft.py --smoke --check
 PYTHONPATH=src python -m repro.robustness.chaos --report
 
 # telemetry (repro.obs): tracing-on overhead <= 5% (or inside the
-# baseline's own jitter spread), exported Chrome trace validates with
-# span durations consistent against the measured dispatch wall time,
-# and the pinned algorithm sweep leaves a finite predicted-vs-actual
-# scoreboard row per algorithm (artifacts/bench/obs_smoke.json)
+# baseline's own jitter spread), a multiply under a jax.profiler
+# session leaves dbcsr.multiply > plan, stacks, dispatch, finish nested
+# in the profiler's trace with the JAX runtime's counters on the root
+# and dispatch, and the pinned algorithm sweep leaves a finite
+# predicted-vs-actual scoreboard row per algorithm
+# (artifacts/bench/obs_smoke.json)
 python benchmarks/bench_obs.py --smoke --check
 
 # tensor contractions (repro.tensor): the planner's matricization

@@ -356,34 +356,39 @@ def multiply(
     eps)-uniform by construction, so ``filter_eps`` semantics inside a
     batch are identical to this single-product path.
     """
-    from .multiply import distributed_matmul
+    from .multiply import _distributed_matmul, is_live, root_attrs
 
-    an = bn = None
-    if filter_eps is not None:
-        an, bn = a.norms(), b.norms()
-    c_data, plan = distributed_matmul(
-        a.data, b.data, mesh=mesh, grid=a.grid,
-        algorithm=algorithm, densify=densify,
-        block_m=a.layout.block_rows, block_k=a.layout.block_cols,
-        block_n=b.layout.block_cols,
-        a_mask=a.block_mask, b_mask=b.block_mask,
-        a_norms=an, b_norms=bn, filter_eps=filter_eps,
-        verify=verify, return_plan=True, **kw,
-    )
-    c_layout = BlockLayout(a.layout.rows, b.layout.cols,
-                           a.layout.block_rows, b.layout.block_cols)
-    # the eps path zeroes the payload outside the retained support —
-    # load-bearing on BOTH local paths: the densified GEMM computes
-    # sub-eps blocks the retained mask excludes, and the blocked path's
-    # SPMD union-of-max steps let a rank deposit small contributions
-    # into blocks outside the global retained support
-    mask, zero = _product_mask(a, b, an, bn, filter_eps)
-    c_data = _apply_result_mask(c_data, mask, zero, a.layout.block_rows,
-                                b.layout.block_cols)
-    c = DBCSRMatrix(c_data, c_layout, a.grid, mask)
-    c.last_plan = plan
-    c.verification = plan.verification
-    return (c, plan) if return_plan else c
+    def finish(c_data, plan):
+        c_layout = BlockLayout(a.layout.rows, b.layout.cols,
+                               a.layout.block_rows, b.layout.block_cols)
+        # the eps path zeroes the payload outside the retained support —
+        # load-bearing on BOTH local paths: the densified GEMM computes
+        # sub-eps blocks the retained mask excludes, and the blocked
+        # path's SPMD union-of-max steps let a rank deposit small
+        # contributions into blocks outside the global retained support
+        mask, zero = _product_mask(a, b, an, bn, filter_eps)
+        c_data = _apply_result_mask(c_data, mask, zero,
+                                    a.layout.block_rows, b.layout.block_cols)
+        c = DBCSRMatrix(c_data, c_layout, a.grid, mask)
+        c.last_plan = plan
+        c.verification = plan.verification
+        return (c, plan) if return_plan else c
+
+    live = is_live(a.data, b.data)
+    with obs.maybe_span(live, "multiply", cat="multiply", counters=True,
+                        **root_attrs(algorithm, a.data, b.data)):
+        an = bn = None
+        if filter_eps is not None:
+            an, bn = a.norms(), b.norms()
+        return _distributed_matmul(
+            a.data, b.data, mesh=mesh, grid=a.grid,
+            algorithm=algorithm, densify=densify,
+            block_m=a.layout.block_rows, block_k=a.layout.block_cols,
+            block_n=b.layout.block_cols,
+            a_mask=a.block_mask, b_mask=b.block_mask,
+            a_norms=an, b_norms=bn, filter_eps=filter_eps,
+            verify=verify, return_plan=True, _live=live, _finish=finish,
+            **kw)
 
 
 def create_tensor(array, *, mesh, grid=GridSpec(), block_sizes,

@@ -119,8 +119,10 @@ def densified_local_matmul(precision=jax.lax.Precision.DEFAULT,
         return f
 
     def f(a, b):
-        return jax.lax.dot(a, b, precision=precision,
-                           preferred_element_type=jnp.float32)
+        # a stable name for the dot's device op in a profiler trace
+        with jax.named_scope("dbcsr.local_dot"):
+            return jax.lax.dot(a, b, precision=precision,
+                               preferred_element_type=jnp.float32)
 
     return f
 

@@ -56,6 +56,7 @@ imbalance when the predicted compute saved exceeds the shuffle's cost
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import List, Optional, Tuple
 
@@ -396,8 +397,8 @@ def _schedule_stats(algorithm: str, *, grid, mesh, local_shape, itemsize,
                     reduce_kw: dict, n_groups: int = 1) -> dict:
     """Per-step comm-vs-compute split of the executed schedule, priced
     with the calibrated hardware constants (host-side observability —
-    attached to executed plans as ``schedule_stats`` and emitted as
-    schedule-step spans by the telemetry layer).  ``n_groups`` scales
+    attached to executed plans as ``schedule_stats``, its total comm
+    bytes on the telemetry layer's dispatch span).  ``n_groups`` scales
     comm bytes and dense flops for the fused batched dispatch, whose
     every step moves/computes G same-geometry products at once."""
     from repro.planner.calibrate import get_hardware_model
@@ -475,65 +476,9 @@ def _schedule_stats(algorithm: str, *, grid, mesh, local_shape, itemsize,
     }
 
 
-def _emit_step_spans(parent, t0: float, total_s: float, ss: dict) -> None:
-    """Carve the measured dispatch interval ``[t0, t0+total_s]`` into
-    synthetic schedule-step spans (prologue / step[t] {comm, stacks} /
-    epilogue), each sized by the cost model's per-step weight from
-    ``_schedule_stats`` and scaled so they sum exactly to the measured
-    wall time.  The host driver can't time individual shard_map steps
-    (one fused device program), so this is the best per-step attribution
-    available — attrs carry the *exact* comm-bytes/flops/occupancy."""
-    tracer = obs.get_tracer()
-    if tracer is None or parent is None or total_s <= 0.0:
-        return
-    w_pro = ss.get("prologue_comm_s", 0.0)
-    w_epi = ss.get("epilogue_comm_s", 0.0)
-    steps = ss.get("steps", [])
-    w_sum = w_pro + w_epi + sum(s["comm_s"] + s["compute_s"]
-                                for s in steps)
-    if w_sum <= 0.0:
-        return
-    scale = total_s / w_sum
-    cur = t0
-    if w_pro > 0.0:
-        tracer.emit("prologue", "comm", t0=cur, dur=w_pro * scale,
-                    parent=parent,
-                    attrs={"comm_bytes": ss.get("prologue_comm_bytes", 0),
-                           "comm_op": ss.get("comm_op")})
-        cur += w_pro * scale
-    for s in steps:
-        sdur = (s["comm_s"] + s["compute_s"]) * scale
-        srec = tracer.emit(
-            f"step[{s['step']}]", "schedule-step", t0=cur, dur=sdur,
-            parent=parent,
-            attrs={"step": s["step"], "skipped": s["skipped"],
-                   "comm_bytes": s["comm_bytes"], "flops": s["flops"],
-                   "occupancy": s.get("occupancy"),
-                   "n_entries": s.get("n_entries"),
-                   "rank_entries": s.get("rank_entries"),
-                   "rank_imbalance": s.get("rank_imbalance")})
-        if s["comm_s"] > 0.0:
-            tracer.emit("comm", "comm", t0=cur, dur=s["comm_s"] * scale,
-                        parent=srec,
-                        attrs={"comm_bytes": s["comm_bytes"],
-                               "comm_op": ss.get("comm_op")})
-        if s["compute_s"] > 0.0:
-            tracer.emit("stacks", "compute",
-                        t0=cur + s["comm_s"] * scale,
-                        dur=s["compute_s"] * scale, parent=srec,
-                        attrs={"flops": s["flops"],
-                               "occupancy": s.get("occupancy")})
-        cur += sdur
-    if w_epi > 0.0:
-        tracer.emit("epilogue", "comm", t0=cur, dur=w_epi * scale,
-                    parent=parent,
-                    attrs={"comm_bytes": ss.get("epilogue_comm_bytes", 0),
-                           "comm_op": ss.get("comm_op")})
-
-
 def _verified_result(verify, a, b, c, rerun, *, plan, block_m, block_k,
                      block_n, a_mask, b_mask, a_norms, b_norms, filter_eps,
-                     verify_budget, _tele: bool = False):
+                     verify_budget, _live: bool = False):
     """ABFT verification of a raw product (repro.robustness.abft):
     price the checksum overhead against the plan (``verify="auto"``),
     screen the operands with the finite tripwires, apply any installed
@@ -561,10 +506,10 @@ def _verified_result(verify, a, b, c, rerun, *, plan, block_m, block_k,
     def _repair_rerun():
         # a detection re-executes the deterministic dispatch once; the
         # repair span makes that second dispatch visible in the trace
-        with obs.maybe_span(_tele, "repair", cat="repair"):
+        with obs.maybe_span(_live, "repair", cat="repair"):
             return rerun()
 
-    with obs.maybe_span(_tele, "verify", cat="verify", mode=verify) as vsp:
+    with obs.maybe_span(_live, "verify", cat="verify", mode=verify) as vsp:
         guards.assert_finite(a, "A")
         guards.assert_finite(b, "B")
         c = chaos.apply_result_hook(c)
@@ -696,16 +641,16 @@ def distributed_matmul(
     split of the executed schedule (``schedule_stats``).  Only usable
     outside jit — the plan is a host-side object.
 
-    Telemetry (repro.obs): with ``obs.enable()`` active — and only
-    then — the call records a ``multiply`` span nesting plan ->
-    dispatch -> schedule-step -> comm/stacks (plus verify -> repair)
-    and logs the plan's predicted-vs-measured cost for the planner
-    scoreboard.  Disabled (the default) or under ``jax.jit`` tracing
-    this wrapper adds one boolean check and the output is bit
-    identical.
+    Spans (repro.obs): outside ``jax.jit`` tracing the call opens the
+    profiler annotations ``dbcsr.multiply`` ⊃ ``dbcsr.plan``,
+    ``dbcsr.stacks``, ``dbcsr.dispatch``, ``dbcsr.finish`` (plus
+    ``dbcsr.verify`` ⊃ ``dbcsr.repair``), with the JAX runtime's
+    lowerings and compiles on the root and the dispatch; inactive with
+    no profiler session.  With ``obs.enable()`` active — and only then
+    — they are also recorded as spans, the dispatch waits for the
+    device, and the plan's predicted-vs-measured cost is logged for the
+    planner scoreboard.  The output is bit identical either way.
     """
-    tele = obs.enabled() and not (isinstance(a, jax.core.Tracer)
-                                  or isinstance(b, jax.core.Tracer))
     call = dict(
         mesh=mesh, grid=grid, algorithm=algorithm, densify=densify,
         block_m=block_m, block_k=block_k, block_n=block_n,
@@ -716,14 +661,25 @@ def distributed_matmul(
         pipeline_depth=pipeline_depth, double_buffer=double_buffer,
         verify=verify, verify_budget=verify_budget,
         return_plan=return_plan, **kw)
-    if not tele:
-        return _distributed_matmul(a, b, **call)
+    live = is_live(a, b)
+    with obs.maybe_span(live, "multiply", cat="multiply", counters=True,
+                        **root_attrs(algorithm, a, b)):
+        return _distributed_matmul(a, b, _live=live, **call)
+
+
+def is_live(*operands) -> bool:
+    """Spans open only for concrete operands, never while ``jax.jit``
+    traces the call."""
+    return not any(isinstance(x, jax.core.Tracer) for x in operands)
+
+
+def root_attrs(algorithm: str, a, b) -> dict:
+    """The root span's attrs: the requested algorithm and the shape."""
     attrs = {"algorithm": algorithm}
     if getattr(a, "ndim", 0) == 2 and getattr(b, "ndim", 0) == 2:
         attrs.update(m=int(a.shape[0]), k=int(a.shape[1]),
                      n=int(b.shape[1]))
-    with obs.span("multiply", cat="multiply", **attrs):
-        return _distributed_matmul(a, b, _tele=True, **call)
+    return attrs
 
 
 def _distributed_matmul(
@@ -754,12 +710,18 @@ def _distributed_matmul(
     verify: Optional[str] = None,
     verify_budget: Optional[float] = None,
     return_plan: bool = False,
-    _tele: bool = False,
+    _live: bool = False,
+    _finish=None,
     **kw,
 ) -> jax.Array:
-    """``distributed_matmul`` body (see its docstring); ``_tele`` is
-    the per-call telemetry flag resolved by the public wrapper
-    (False when telemetry is disabled or under jit tracing)."""
+    """``distributed_matmul`` body (see its docstring), inside the
+    caller's root span.  ``_live`` is the per-call span flag (False
+    under jit tracing); spans are recorded, and the enabled path's
+    synchronisation and plan-outcome log run, only when telemetry is
+    on as well.  ``_finish(c, plan)``, when given, is the caller's own
+    finishing of the product (``dbcsr.multiply``'s result mask and
+    wrap), run inside the finish span; its value is returned."""
+    _tele = _live and obs.enabled()
     m, k = a.shape
     k2, n = b.shape
     if k != k2:
@@ -768,54 +730,54 @@ def _distributed_matmul(
         raise ValueError(
             f"verify must be None, 'checksum' or 'auto', got {verify!r}")
 
-    filtering = filter_eps is not None
-    if filtering and a_norms is None and b_norms is None:
-        # on-the-fly: derive the block norms from the payloads (one
-        # blockwise reduction each; masked so absent blocks report 0)
-        from repro.sparsity.norms import block_norms_of
+    with obs.maybe_span(_live, "plan", cat="plan") as psp:
+        filtering = filter_eps is not None
+        if filtering and a_norms is None and b_norms is None:
+            # on-the-fly: derive the block norms from the payloads (one
+            # blockwise reduction each; masked so absent blocks report 0)
+            from repro.sparsity.norms import block_norms_of
 
-        a_norms = block_norms_of(a, block_m, block_k, a_mask)
-        b_norms = block_norms_of(b, block_k, block_n, b_mask)
+            a_norms = block_norms_of(a, block_m, block_k, a_mask)
+            b_norms = block_norms_of(b, block_k, block_n, b_mask)
 
-    # ---- global mask/norm normalisation + rank-exact resolution -------
-    # (hoisted above planning: the per-rank load imbalance of the
-    # C-chunk decomposition feeds the planner's rank-exact pricing and
-    # its costed rebalance decision)
-    pr0, pc0 = grid.grid_shape(mesh)
-    n_ranks_all = pr0 * pc0 * (1 if grid.stack_axis is None
-                               else grid.stack_size(mesh))
-    masked = a_mask is not None or b_mask is not None or filtering
-    am = bmk = an_g = bn_g = None
-    if masked:
-        am, bmk = _block_masks(m, k, n, block_m, block_k, block_n,
-                               a_mask, b_mask)
-        if filtering:
-            # norms ride the same slicing machinery as the masks;
-            # mask-absent blocks are forced to norm 0 so one >= eps
-            # comparison folds both criteria per rank
-            from repro.sparsity.norms import normalize_block_norms
+        # ---- global mask/norm normalisation + rank-exact resolution -------
+        # (hoisted above planning: the per-rank load imbalance of the
+        # C-chunk decomposition feeds the planner's rank-exact pricing and
+        # its costed rebalance decision)
+        pr0, pc0 = grid.grid_shape(mesh)
+        n_ranks_all = pr0 * pc0 * (1 if grid.stack_axis is None
+                                   else grid.stack_size(mesh))
+        masked = a_mask is not None or b_mask is not None or filtering
+        am = bmk = an_g = bn_g = None
+        if masked:
+            am, bmk = _block_masks(m, k, n, block_m, block_k, block_n,
+                                   a_mask, b_mask)
+            if filtering:
+                # norms ride the same slicing machinery as the masks;
+                # mask-absent blocks are forced to norm 0 so one >= eps
+                # comparison folds both criteria per rank
+                from repro.sparsity.norms import normalize_block_norms
 
-            an_g, bn_g = normalize_block_norms(
-                am.shape[0], am.shape[1], bmk.shape[1], a_norms, b_norms)
-            an_g = np.where(am, an_g, np.float32(0.0))
-            bn_g = np.where(bmk, bn_g, np.float32(0.0))
-    use_rank = rank_exact is not False and masked and n_ranks_all > 1
-    rank_imb = None
-    if use_rank and am.shape[0] % pr0 == 0 and bmk.shape[1] % pc0 == 0:
-        from repro.sparsity.balance import (chunk_imbalance,
-                                            retained_block_weights)
+                an_g, bn_g = normalize_block_norms(
+                    am.shape[0], am.shape[1], bmk.shape[1], a_norms, b_norms)
+                an_g = np.where(am, an_g, np.float32(0.0))
+                bn_g = np.where(bmk, bn_g, np.float32(0.0))
+        use_rank = rank_exact is not False and masked and n_ranks_all > 1
+        rank_imb = None
+        if use_rank and am.shape[0] % pr0 == 0 and bmk.shape[1] % pc0 == 0:
+            from repro.sparsity.balance import (chunk_imbalance,
+                                                retained_block_weights)
 
-        rank_imb = chunk_imbalance(
-            retained_block_weights(am, bmk, an_g, bn_g, filter_eps),
-            pr0, pc0)
+            rank_imb = chunk_imbalance(
+                retained_block_weights(am, bmk, an_g, bn_g, filter_eps),
+                pr0, pc0)
 
-    plan = None
-    # telemetry forces a plan even for pinned algorithms: the planner
-    # scoreboard needs predicted_s for every executed plan
-    if algorithm == "auto" or return_plan or verify is not None or _tele:
-        from repro.planner.plan import plan_multiply
+        plan = None
+        # telemetry forces a plan even for pinned algorithms: the planner
+        # scoreboard needs predicted_s for every executed plan
+        if algorithm == "auto" or return_plan or verify is not None or _tele:
+            from repro.planner.plan import plan_multiply
 
-        with obs.maybe_span(_tele, "plan", cat="plan") as psp:
             mesh_shape = ((pr0, pc0) if grid.stack_axis is None
                           else (pr0, pc0, grid.stack_size(mesh)))
             occ = _global_occupancy(m, k, n, block_m, block_k, block_n,
@@ -857,178 +819,179 @@ def _distributed_matmul(
                     predicted_s=float(plan.predicted_s),
                     occupancy=float(plan.occupancy),
                     trivial=bool(plan.trivial))
-    if densify is None:
-        densify = True  # legacy default for fixed algorithms
-    if algorithm not in ("cannon", "cannon25d", "ts_k", "ts_m", "ts_n",
-                        "summa"):
-        raise ValueError(f"unknown algorithm {algorithm!r}")
-    depth = resolve_pipeline_depth(pipeline_depth, double_buffer)
+        if densify is None:
+            densify = True  # legacy default for fixed algorithms
+        if algorithm not in ("cannon", "cannon25d", "ts_k", "ts_m", "ts_n",
+                            "summa"):
+            raise ValueError(f"unknown algorithm {algorithm!r}")
+        depth = resolve_pipeline_depth(pipeline_depth, double_buffer)
 
-    # ---- costed rebalance: permute the block distribution -------------
-    # The planner arms this only when predicted compute saved by
-    # flattening per-rank load imbalance exceeds the shuffle's amortized
-    # cost (plan.rebalance); ``rebalance=True/False`` overrides.  Only
-    # block rows of A/C and block cols of B/C move — K stays identity so
-    # every C block keeps its accumulation order — and the inverse
-    # permutation is applied to C inside the re-runnable dispatch
-    # closure (ABFT repair re-executions stay self-consistent).
-    rb = None
-    do_rebalance = (rebalance if rebalance is not None
-                    else plan is not None and plan.rebalance)
-    if (do_rebalance and not densify and use_rank
-            and am.shape[0] % pr0 == 0 and bmk.shape[1] % pc0 == 0):
-        from repro.sparsity.balance import plan_rebalance
+    with obs.maybe_span(_live, "stacks", cat="stacks"):
+        # ---- costed rebalance: permute the block distribution -------------
+        # The planner arms this only when predicted compute saved by
+        # flattening per-rank load imbalance exceeds the shuffle's amortized
+        # cost (plan.rebalance); ``rebalance=True/False`` overrides.  Only
+        # block rows of A/C and block cols of B/C move — K stays identity so
+        # every C block keeps its accumulation order — and the inverse
+        # permutation is applied to C inside the re-runnable dispatch
+        # closure (ABFT repair re-executions stay self-consistent).
+        rb = None
+        do_rebalance = (rebalance if rebalance is not None
+                        else plan is not None and plan.rebalance)
+        if (do_rebalance and not densify and use_rank
+                and am.shape[0] % pr0 == 0 and bmk.shape[1] % pc0 == 0):
+            from repro.sparsity.balance import plan_rebalance
 
-        cand = plan_rebalance(am, bmk, pr0, pc0, a_norms=an_g,
-                              b_norms=bn_g, filter_eps=filter_eps)
-        if not cand.identity:
-            rb = cand
-    a_exec, b_exec = a, b
-    if rb is not None:
-        from repro.sparsity.balance import (permute_block_cols,
-                                            permute_block_rows)
+            cand = plan_rebalance(am, bmk, pr0, pc0, a_norms=an_g,
+                                  b_norms=bn_g, filter_eps=filter_eps)
+            if not cand.identity:
+                rb = cand
+        a_exec, b_exec = a, b
+        if rb is not None:
+            from repro.sparsity.balance import (permute_block_cols,
+                                                permute_block_rows)
 
-        pm_idx, pn_idx = np.asarray(rb.perm_m), np.asarray(rb.perm_n)
-        a_exec = permute_block_rows(a, rb.perm_m, block_m)
-        b_exec = permute_block_cols(b, rb.perm_n, block_n)
-        am = am[pm_idx]
-        bmk = bmk[:, pn_idx]
-        if an_g is not None:
-            an_g = an_g[pm_idx]
-        if bn_g is not None:
-            bn_g = bn_g[:, pn_idx]
-        obs.counter("planner.rebalance.applied").inc()
+            pm_idx, pn_idx = np.asarray(rb.perm_m), np.asarray(rb.perm_n)
+            a_exec = permute_block_rows(a, rb.perm_m, block_m)
+            b_exec = permute_block_cols(b, rb.perm_n, block_n)
+            am = am[pm_idx]
+            bmk = bmk[:, pn_idx]
+            if an_g is not None:
+                an_g = an_g[pm_idx]
+            if bn_g is not None:
+                bn_g = bn_g[:, pn_idx]
+            obs.counter("planner.rebalance.applied").inc()
 
-    # ---- local multiply geometry (per schedule step) ------------------
-    pr, pc = grid.grid_shape(mesh)
-    pg = p_all = n_panels = None
-    if algorithm.startswith("ts_"):
-        p_all = pr * pc * grid.stack_size(mesh)
-        shapes = {
-            "ts_k": (m, k // p_all, n),
-            "ts_m": (m // p_all, k, n),
-            "ts_n": (m, k, n // p_all),
-        }
-        ml, kl, nl = shapes[algorithm]
-    elif algorithm in ("cannon", "cannon25d"):
-        # Local multiply is (m/pg, k/pg) @ (k/pg, n/pg) on the square
-        # grid Cannon requires.  Deriving the inner dim from pc alone
-        # (the old ``k // pc``) silently mis-sized B's stack-plan
-        # geometry whenever pr != pc: gathers clamp out-of-range
-        # block indices instead of failing, producing wrong C.
-        pg = grid.validate_square(mesh)
-        if (m % pg or k % pg or n % pg) and not densify:
-            raise ValueError(
-                f"shape ({m},{k},{n}) not divisible by grid side {pg}")
-        ml, kl, nl = m // pg, k // pg, n // pg
-    elif kw.get("bcast") == "gather":
-        # PUMMA-style broadcast: the local multiply sees the
-        # all-gathered full-K row of A / column of B — a single
-        # stack-plan geometry on any grid shape.
-        if (m % pr or n % pc) and not densify:
-            raise ValueError(
-                f"shape ({m},{n}) not divisible by grid {pr}x{pc}")
-        ml, kl, nl = m // pr, k, n // pc
-    else:
-        # summa psum: every panel's local multiply is
-        # (m/pr, k/n_panels) @ (k/n_panels, n/pc) — one per-panel
-        # stack-plan geometry shared by all panels, so non-square
-        # grids are fine (for square grids k/n_panels == k/pc, the
-        # historical full-local-K geometry).
-        n_panels = summa_n_panels(pr, pc)
-        if (m % pr or n % pc or k % n_panels) and not densify:
-            raise ValueError(
-                f"shape ({m},{k},{n}) not divisible by summa grid "
-                f"{pr}x{pc} with {n_panels} panels")
-        ml, kl, nl = m // pr, k // n_panels, n // pc
-
-    # ---- local multiply strategy (densified vs blocked) --------------
-    if densify:
-        lm = densified_local_matmul(precision, kernel=local_kernel)
-    else:
-        blocked_kw = dict(
-            block_m=block_m, block_k=block_k, block_n=block_n,
-            stack_size=stack_size, align=align,
-            kernel=local_kernel or "smm", stack_bins=stack_bins)
-        if not masked:
-            lm = blocked_local_matmul(ml, kl, nl, **blocked_kw)
+        # ---- local multiply geometry (per schedule step) ------------------
+        pr, pc = grid.grid_shape(mesh)
+        pg = p_all = n_panels = None
+        if algorithm.startswith("ts_"):
+            p_all = pr * pc * grid.stack_size(mesh)
+            shapes = {
+                "ts_k": (m, k // p_all, n),
+                "ts_m": (m // p_all, k, n),
+                "ts_n": (m, k, n // p_all),
+            }
+            ml, kl, nl = shapes[algorithm]
         elif algorithm in ("cannon", "cannon25d"):
-            c_repl = (grid.stack_size(mesh)
-                      if algorithm == "cannon25d" else 1)
-            if use_rank:
-                lm = _stepwise_rank_blocked_lm(
-                    ml, kl, nl,
-                    rank_steps=cannon_rank_steps(
-                        am, bmk, pg, c_repl, a_norms=an_g, b_norms=bn_g),
-                    rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
-                    filter_eps=filter_eps, **blocked_kw)
-            else:
-                steps = [{"pair_mask": pm}
-                         for pm in cannon_step_masks(am, bmk, pg, c_repl)]
-                if filtering:
-                    for s, pn in zip(steps, cannon_step_norms(
-                            an_g, bn_g, pg, c_repl)):
-                        s.update(pair_norms=pn, filter_eps=filter_eps)
-                lm = _stepwise_blocked_lm(ml, kl, nl, mask_steps=steps,
-                                          **blocked_kw)
-        elif algorithm == "summa" and kw.get("bcast") != "gather":
-            if use_rank:
-                lm = _stepwise_rank_blocked_lm(
-                    ml, kl, nl,
-                    rank_steps=summa_rank_steps(
-                        am, bmk, pr, pc, n_panels,
-                        a_norms=an_g, b_norms=bn_g),
-                    rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
-                    filter_eps=filter_eps, **blocked_kw)
-            else:
-                steps = [{"a_mask": ua, "b_mask": ub} for ua, ub in
-                         summa_step_masks(am, bmk, pr, pc, n_panels)]
-                if filtering:
-                    for s, (una, unb) in zip(steps, summa_step_norms(
-                            an_g, bn_g, pr, pc, n_panels)):
-                        s.update(a_norms=una, b_norms=unb,
-                                 filter_eps=filter_eps)
-                lm = _stepwise_blocked_lm(ml, kl, nl, mask_steps=steps,
-                                          **blocked_kw)
-        elif algorithm == "summa":
-            if use_rank:
-                lm = _single_rank_lm(
-                    ml, kl, nl,
-                    rank_kwargs=summa_gather_rank_steps(
-                        am, bmk, pr, pc, a_norms=an_g, b_norms=bn_g),
-                    rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
-                    filter_eps=filter_eps, **blocked_kw)
-            else:
-                ua, ub = summa_gather_masks(am, bmk, pr, pc)
-                norm_kw = {}
-                if filtering:
-                    una, unb = summa_gather_norms(an_g, bn_g, pr, pc)
-                    norm_kw = dict(a_norms=una, b_norms=unb,
-                                   filter_eps=filter_eps)
-                lm = blocked_local_matmul(ml, kl, nl, a_mask=ua, b_mask=ub,
-                                          **norm_kw, **blocked_kw)
+            # Local multiply is (m/pg, k/pg) @ (k/pg, n/pg) on the square
+            # grid Cannon requires.  Deriving the inner dim from pc alone
+            # (the old ``k // pc``) silently mis-sized B's stack-plan
+            # geometry whenever pr != pc: gathers clamp out-of-range
+            # block indices instead of failing, producing wrong C.
+            pg = grid.validate_square(mesh)
+            if (m % pg or k % pg or n % pg) and not densify:
+                raise ValueError(
+                    f"shape ({m},{k},{n}) not divisible by grid side {pg}")
+            ml, kl, nl = m // pg, k // pg, n // pg
+        elif kw.get("bcast") == "gather":
+            # PUMMA-style broadcast: the local multiply sees the
+            # all-gathered full-K row of A / column of B — a single
+            # stack-plan geometry on any grid shape.
+            if (m % pr or n % pc) and not densify:
+                raise ValueError(
+                    f"shape ({m},{n}) not divisible by grid {pr}x{pc}")
+            ml, kl, nl = m // pr, k, n // pc
         else:
-            if use_rank:
-                lm = _single_rank_lm(
-                    ml, kl, nl,
-                    rank_kwargs=ts_rank_steps(
-                        algorithm, am, bmk, p_all,
-                        a_norms=an_g, b_norms=bn_g),
-                    rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
-                    filter_eps=filter_eps, **blocked_kw)
+            # summa psum: every panel's local multiply is
+            # (m/pr, k/n_panels) @ (k/n_panels, n/pc) — one per-panel
+            # stack-plan geometry shared by all panels, so non-square
+            # grids are fine (for square grids k/n_panels == k/pc, the
+            # historical full-local-K geometry).
+            n_panels = summa_n_panels(pr, pc)
+            if (m % pr or n % pc or k % n_panels) and not densify:
+                raise ValueError(
+                    f"shape ({m},{k},{n}) not divisible by summa grid "
+                    f"{pr}x{pc} with {n_panels} panels")
+            ml, kl, nl = m // pr, k // n_panels, n // pc
+
+        # ---- local multiply strategy (densified vs blocked) --------------
+        if densify:
+            lm = densified_local_matmul(precision, kernel=local_kernel)
+        else:
+            blocked_kw = dict(
+                block_m=block_m, block_k=block_k, block_n=block_n,
+                stack_size=stack_size, align=align,
+                kernel=local_kernel or "smm", stack_bins=stack_bins)
+            if not masked:
+                lm = blocked_local_matmul(ml, kl, nl, **blocked_kw)
+            elif algorithm in ("cannon", "cannon25d"):
+                c_repl = (grid.stack_size(mesh)
+                          if algorithm == "cannon25d" else 1)
+                if use_rank:
+                    lm = _stepwise_rank_blocked_lm(
+                        ml, kl, nl,
+                        rank_steps=cannon_rank_steps(
+                            am, bmk, pg, c_repl, a_norms=an_g, b_norms=bn_g),
+                        rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
+                        filter_eps=filter_eps, **blocked_kw)
+                else:
+                    steps = [{"pair_mask": pm}
+                             for pm in cannon_step_masks(am, bmk, pg, c_repl)]
+                    if filtering:
+                        for s, pn in zip(steps, cannon_step_norms(
+                                an_g, bn_g, pg, c_repl)):
+                            s.update(pair_norms=pn, filter_eps=filter_eps)
+                    lm = _stepwise_blocked_lm(ml, kl, nl, mask_steps=steps,
+                                              **blocked_kw)
+            elif algorithm == "summa" and kw.get("bcast") != "gather":
+                if use_rank:
+                    lm = _stepwise_rank_blocked_lm(
+                        ml, kl, nl,
+                        rank_steps=summa_rank_steps(
+                            am, bmk, pr, pc, n_panels,
+                            a_norms=an_g, b_norms=bn_g),
+                        rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
+                        filter_eps=filter_eps, **blocked_kw)
+                else:
+                    steps = [{"a_mask": ua, "b_mask": ub} for ua, ub in
+                             summa_step_masks(am, bmk, pr, pc, n_panels)]
+                    if filtering:
+                        for s, (una, unb) in zip(steps, summa_step_norms(
+                                an_g, bn_g, pr, pc, n_panels)):
+                            s.update(a_norms=una, b_norms=unb,
+                                     filter_eps=filter_eps)
+                    lm = _stepwise_blocked_lm(ml, kl, nl, mask_steps=steps,
+                                              **blocked_kw)
+            elif algorithm == "summa":
+                if use_rank:
+                    lm = _single_rank_lm(
+                        ml, kl, nl,
+                        rank_kwargs=summa_gather_rank_steps(
+                            am, bmk, pr, pc, a_norms=an_g, b_norms=bn_g),
+                        rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
+                        filter_eps=filter_eps, **blocked_kw)
+                else:
+                    ua, ub = summa_gather_masks(am, bmk, pr, pc)
+                    norm_kw = {}
+                    if filtering:
+                        una, unb = summa_gather_norms(an_g, bn_g, pr, pc)
+                        norm_kw = dict(a_norms=una, b_norms=unb,
+                                       filter_eps=filter_eps)
+                    lm = blocked_local_matmul(ml, kl, nl, a_mask=ua, b_mask=ub,
+                                              **norm_kw, **blocked_kw)
             else:
-                norm_kw = {}
-                if filtering:
-                    norm_kw = dict(ts_step_norms(algorithm, an_g, bn_g,
-                                                 p_all),
-                                   filter_eps=filter_eps)
-                lm = blocked_local_matmul(
-                    ml, kl, nl, **ts_step_masks(algorithm, am, bmk, p_all),
-                    **norm_kw, **blocked_kw)
-    if not densify and obs.enabled():
-        imb = _rank_imbalance_of(_rank_totals(lm))
-        if imb is not None:
-            obs.histogram("executor.rank_imbalance").observe(imb)
+                if use_rank:
+                    lm = _single_rank_lm(
+                        ml, kl, nl,
+                        rank_kwargs=ts_rank_steps(
+                            algorithm, am, bmk, p_all,
+                            a_norms=an_g, b_norms=bn_g),
+                        rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
+                        filter_eps=filter_eps, **blocked_kw)
+                else:
+                    norm_kw = {}
+                    if filtering:
+                        norm_kw = dict(ts_step_norms(algorithm, an_g, bn_g,
+                                                     p_all),
+                                       filter_eps=filter_eps)
+                    lm = blocked_local_matmul(
+                        ml, kl, nl, **ts_step_masks(algorithm, am, bmk, p_all),
+                        **norm_kw, **blocked_kw)
+        if not densify and obs.enabled():
+            imb = _rank_imbalance_of(_rank_totals(lm))
+            if imb is not None:
+                obs.histogram("executor.rank_imbalance").observe(imb)
 
     # ---- data-exchange algorithm (all via the schedule engine) --------
     # The dispatch is wrapped in a re-runnable closure: at a fixed
@@ -1076,22 +1039,22 @@ def _distributed_matmul(
     dispatch_times: List[float] = []
 
     def _run_traced():
-        # telemetry off: exactly the legacy path — no timing, no sync
-        if not _tele:
-            return _run()
-        with obs.span("dispatch", cat="dispatch", algorithm=algorithm,
-                      densify=bool(densify), pipeline_depth=depth) as dsp:
+        with obs.maybe_span(_live, "dispatch", cat="dispatch",
+                            counters=True, algorithm=algorithm,
+                            densify=bool(densify),
+                            pipeline_depth=depth) as dsp:
+            # telemetry off: enqueue and return — no timing, no sync
+            if not _tele:
+                return _run()
             t0 = time.perf_counter()
             c = jax.block_until_ready(_run())
-            dt = time.perf_counter() - t0
-        dispatch_times.append(dt)
+            dispatch_times.append(time.perf_counter() - t0)
         try:
             ss = _sched_stats()
         except Exception:
             ss = None  # telemetry must never break the multiply
         if ss is not None:
             dsp.set(comm_bytes=int(ss.get("total_comm_bytes", 0)))
-            _emit_step_spans(dsp.rec, t0, dt, ss)
         return c
 
     c = _run_traced()
@@ -1102,31 +1065,31 @@ def _distributed_matmul(
             block_m=block_m, block_k=block_k, block_n=block_n,
             a_mask=a_mask, b_mask=b_mask, a_norms=a_norms, b_norms=b_norms,
             filter_eps=filter_eps, verify_budget=verify_budget,
-            _tele=_tele)
-    if _tele and plan is not None and not plan.trivial and dispatch_times:
-        # predicted-vs-actual planner accounting: first dispatch is the
-        # clean run (a repair re-execution would re-measure the same
-        # deterministic program)
-        obs.record_plan_outcome(
-            kind="multiply", algorithm=algorithm, densify=bool(densify),
-            m=m, k=k, n=n, occupancy=float(plan.occupancy),
-            predicted_s=float(plan.predicted_s),
-            measured_s=float(dispatch_times[0]),
-            pipeline_depth=int(depth))
-    if not return_plan:
-        return c
-    import dataclasses as _dc
-
-    es = _collect_executor_stats(lm, densify)
-    if es is not None:
-        es["rebalance_applied"] = rb is not None
-        if rb is not None:
-            es["rebalance_method"] = rb.method
-            es["rebalance_imbalance_before"] = rb.imbalance_before
-            es["rebalance_imbalance_after"] = rb.imbalance_after
-    plan = _dc.replace(
-        plan,
-        executor_stats=es,
-        schedule_stats=_sched_stats(),
-        verification=verification)
-    return c, plan
+            _live=_live)
+    with obs.maybe_span(_live, "finish", cat="finish"):
+        if _tele and plan is not None and not plan.trivial \
+                and dispatch_times:
+            # predicted-vs-actual planner accounting: first dispatch is
+            # the clean run (a repair re-execution would re-measure the
+            # same deterministic program)
+            obs.record_plan_outcome(
+                kind="multiply", algorithm=algorithm, densify=bool(densify),
+                m=m, k=k, n=n, occupancy=float(plan.occupancy),
+                predicted_s=float(plan.predicted_s),
+                measured_s=float(dispatch_times[0]),
+                pipeline_depth=int(depth))
+        if return_plan:
+            es = _collect_executor_stats(lm, densify)
+            if es is not None:
+                es["rebalance_applied"] = rb is not None
+                if rb is not None:
+                    es["rebalance_method"] = rb.method
+                    es["rebalance_imbalance_before"] = rb.imbalance_before
+                    es["rebalance_imbalance_after"] = rb.imbalance_after
+            plan = dataclasses.replace(
+                plan,
+                executor_stats=es,
+                schedule_stats=_sched_stats(),
+                verification=verification)
+        out = (c, plan) if return_plan else c
+        return out if _finish is None else _finish(*out)

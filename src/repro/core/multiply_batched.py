@@ -46,6 +46,7 @@ Pallas GEMM may tile differently from per-product ``lax.dot``).
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import List, Optional, Sequence
 
@@ -59,8 +60,8 @@ from .blocking import GridSpec
 from .cannon import cannon_matmul, cannon_step_masks, cannon_step_norms
 from .densify import grouped_densified_local_matmul
 from .engine import batched_stack_executor
-from .multiply import (_block_masks, _emit_step_spans, _global_occupancy,
-                       _masks_empty, _schedule_stats)
+from .multiply import (_block_masks, _global_occupancy,
+                       _masks_empty, _schedule_stats, is_live)
 from .schedule import resolve_pipeline_depth
 from .summa import (summa_matmul, summa_n_panels, summa_step_masks,
                     summa_step_norms)
@@ -173,15 +174,15 @@ def distributed_matmul_batched(
 ):
     """C[g] = A[g] @ B[g] for every product ``g`` of a fused batch.
 
-    With telemetry on (``obs.enable()``), records a
-    ``multiply_batched`` span nesting plan -> dispatch ->
-    schedule-step children (G-scaled comm bytes / flops) and logs the
-    batched plan's predicted-vs-measured fused cost; disabled or under
-    jit tracing the call is bit-identical with one boolean of
-    overhead.  See ``_distributed_matmul_batched`` for semantics.
+    Outside ``jax.jit`` tracing the call opens the profiler annotations
+    ``dbcsr.multiply_batched`` ⊃ ``dbcsr.plan``, ``dbcsr.stacks``,
+    ``dbcsr.dispatch``, ``dbcsr.finish``, with the JAX runtime's
+    lowerings and compiles on the root and the dispatch; with telemetry
+    on (``obs.enable()``) they are also recorded, the dispatch waits
+    for the device, and the batched plan's predicted-vs-measured fused
+    cost is logged.  The output is bit identical either way.  See
+    ``_distributed_matmul_batched`` for semantics.
     """
-    tele = obs.enabled() and not (isinstance(a, jax.core.Tracer)
-                                  or isinstance(b, jax.core.Tracer))
     call = dict(
         mesh=mesh, grid=grid, algorithm=algorithm, densify=densify,
         block_m=block_m, block_k=block_k, block_n=block_n,
@@ -190,14 +191,14 @@ def distributed_matmul_batched(
         filter_eps=filter_eps, precision=precision,
         pipeline_depth=pipeline_depth, double_buffer=double_buffer,
         return_plan=return_plan, **kw)
-    if not tele:
-        return _distributed_matmul_batched(a, b, **call)
     attrs = {"algorithm": algorithm}
     if getattr(a, "ndim", 0) == 3 and getattr(b, "ndim", 0) == 3:
         attrs.update(n_groups=int(a.shape[0]), m=int(a.shape[1]),
                      k=int(a.shape[2]), n=int(b.shape[2]))
-    with obs.span("multiply_batched", cat="multiply", **attrs):
-        return _distributed_matmul_batched(a, b, _tele=True, **call)
+    live = is_live(a, b)
+    with obs.maybe_span(live, "multiply_batched", cat="multiply",
+                        counters=True, **attrs):
+        return _distributed_matmul_batched(a, b, _live=live, **call)
 
 
 def _distributed_matmul_batched(
@@ -223,7 +224,7 @@ def _distributed_matmul_batched(
     pipeline_depth: Optional[int] = None,
     double_buffer: Optional[bool] = None,
     return_plan: bool = False,
-    _tele: bool = False,
+    _live: bool = False,
     **kw,
 ):
     """C[g] = A[g] @ B[g] for every product ``g`` of a fused batch.
@@ -263,24 +264,27 @@ def _distributed_matmul_batched(
                          "dispatch (the all-gathered full-K row would be "
                          "replicated per product)")
 
-    filtering = filter_eps is not None
-    if filtering and a_norms is None and b_norms is None:
-        from repro.sparsity.norms import block_norms_of
+    _tele = _live and obs.enabled()
+    with obs.maybe_span(_live, "plan", cat="plan") as psp:
+        filtering = filter_eps is not None
+        if filtering and a_norms is None and b_norms is None:
+            from repro.sparsity.norms import block_norms_of
 
-        a_norms = [block_norms_of(a[gi], block_m, block_k,
-                                  _per_group(a_masks, gi, g_count, "a_masks"))
-                   for gi in range(g_count)]
-        b_norms = [block_norms_of(b[gi], block_k, block_n,
-                                  _per_group(b_masks, gi, g_count, "b_masks"))
-                   for gi in range(g_count)]
+            a_norms = [block_norms_of(
+                a[gi], block_m, block_k,
+                _per_group(a_masks, gi, g_count, "a_masks"))
+                for gi in range(g_count)]
+            b_norms = [block_norms_of(
+                b[gi], block_k, block_n,
+                _per_group(b_masks, gi, g_count, "b_masks"))
+                for gi in range(g_count)]
 
-    plan = None
-    # telemetry forces a plan even for pinned algorithms (scoreboard
-    # needs the predicted fused cost)
-    if algorithm == "auto" or return_plan or _tele:
-        from repro.planner.plan import plan_multiply_batched
+        plan = None
+        # telemetry forces a plan even for pinned algorithms (scoreboard
+        # needs the predicted fused cost)
+        if algorithm == "auto" or return_plan or _tele:
+            from repro.planner.plan import plan_multiply_batched
 
-        with obs.maybe_span(_tele, "plan", cat="plan") as psp:
             pr0, pc0 = grid.grid_shape(mesh)
             occs = [
                 _global_occupancy(
@@ -323,90 +327,95 @@ def _distributed_matmul_batched(
                     predicted_fused_s=float(plan.predicted_fused_s),
                     predicted_looped_s=float(plan.predicted_looped_s),
                     occupancy=float(occ), trivial=bool(plan.trivial))
-    if densify is None:
-        densify = True  # mirror distributed_matmul's fixed-algorithm default
-    if algorithm not in BATCHED_ALGORITHMS:
-        raise ValueError(
-            f"batched dispatch supports {BATCHED_ALGORITHMS}, got "
-            f"{algorithm!r} (the tall-skinny / 2.5D schedules are not "
-            f"batch-shape-agnostic)")
-    depth = resolve_pipeline_depth(pipeline_depth, double_buffer)
-
-    # ---- local multiply geometry ------------------------------------
-    pr, pc = grid.grid_shape(mesh)
-    pg = n_panels = None
-    if algorithm == "cannon":
-        pg = grid.validate_square(mesh)
-        if (m % pg or k % pg or n % pg) and not densify:
+        if densify is None:
+            # mirror distributed_matmul's fixed-algorithm default
+            densify = True
+        if algorithm not in BATCHED_ALGORITHMS:
             raise ValueError(
-                f"shape ({m},{k},{n}) not divisible by grid side {pg}")
-        ml, kl, nl = m // pg, k // pg, n // pg
-    else:
-        n_panels = summa_n_panels(pr, pc)
-        if (m % pr or n % pc or k % n_panels) and not densify:
-            raise ValueError(
-                f"shape ({m},{k},{n}) not divisible by summa grid "
-                f"{pr}x{pc} with {n_panels} panels")
-        ml, kl, nl = m // pr, k // n_panels, n // pc
+                f"batched dispatch supports {BATCHED_ALGORITHMS}, got "
+                f"{algorithm!r} (the tall-skinny / 2.5D schedules are not "
+                f"batch-shape-agnostic)")
+        depth = resolve_pipeline_depth(pipeline_depth, double_buffer)
 
-    # ---- local multiply strategy ------------------------------------
-    no_masks = a_masks is None and b_masks is None
-    if densify:
-        lm = grouped_densified_local_matmul(precision, kernel=local_kernel)
-    else:
-        batched_kw = dict(
-            block_m=block_m, block_k=block_k, block_n=block_n,
-            stack_size=stack_size, align=align,
-            kernel=local_kernel or "smm")
-        if no_masks and not filtering:
-            lm = batched_stack_executor(g_count, ml, kl, nl, **batched_kw)
+    with obs.maybe_span(_live, "stacks", cat="stacks"):
+        # ---- local multiply geometry ------------------------------------
+        pr, pc = grid.grid_shape(mesh)
+        pg = n_panels = None
+        if algorithm == "cannon":
+            pg = grid.validate_square(mesh)
+            if (m % pg or k % pg or n % pg) and not densify:
+                raise ValueError(
+                    f"shape ({m},{k},{n}) not divisible by grid side {pg}")
+            ml, kl, nl = m // pg, k // pg, n // pg
         else:
-            group_ab = []
-            for gi in range(g_count):
-                am, bmk = _block_masks(
-                    m, k, n, block_m, block_k, block_n,
-                    _per_group(a_masks, gi, g_count, "a_masks"),
-                    _per_group(b_masks, gi, g_count, "b_masks"))
-                an_g = bn_g = None
-                if filtering:
-                    from repro.sparsity.norms import normalize_block_norms
+            n_panels = summa_n_panels(pr, pc)
+            if (m % pr or n % pc or k % n_panels) and not densify:
+                raise ValueError(
+                    f"shape ({m},{k},{n}) not divisible by summa grid "
+                    f"{pr}x{pc} with {n_panels} panels")
+            ml, kl, nl = m // pr, k // n_panels, n // pc
 
-                    an_g, bn_g = normalize_block_norms(
-                        am.shape[0], am.shape[1], bmk.shape[1],
-                        _per_group(a_norms, gi, g_count, "a_norms"),
-                        _per_group(b_norms, gi, g_count, "b_norms"))
-                    an_g = np.where(am, an_g, np.float32(0.0))
-                    bn_g = np.where(bmk, bn_g, np.float32(0.0))
-                group_ab.append((am, bmk, an_g, bn_g))
-            if algorithm == "cannon":
-                n_steps = pg
-                per_group = [cannon_step_masks(am, bmk, pg)
-                             for am, bmk, _, _ in group_ab]
-                steps = [[{"pair_mask": per_group[gi][t]}
-                          for gi in range(g_count)] for t in range(n_steps)]
-                if filtering:
-                    per_group_n = [cannon_step_norms(an_g, bn_g, pg)
-                                   for _, _, an_g, bn_g in group_ab]
-                    for t in range(n_steps):
-                        for gi in range(g_count):
-                            steps[t][gi]["pair_norms"] = per_group_n[gi][t]
+        # ---- local multiply strategy ------------------------------------
+        no_masks = a_masks is None and b_masks is None
+        if densify:
+            lm = grouped_densified_local_matmul(precision, kernel=local_kernel)
+        else:
+            batched_kw = dict(
+                block_m=block_m, block_k=block_k, block_n=block_n,
+                stack_size=stack_size, align=align,
+                kernel=local_kernel or "smm")
+            if no_masks and not filtering:
+                lm = batched_stack_executor(g_count, ml, kl, nl, **batched_kw)
             else:
-                n_steps = n_panels
-                per_group = [summa_step_masks(am, bmk, pr, pc, n_panels)
-                             for am, bmk, _, _ in group_ab]
-                steps = [[dict(zip(("a_mask", "b_mask"), per_group[gi][t]))
-                          for gi in range(g_count)] for t in range(n_steps)]
-                if filtering:
-                    per_group_n = [summa_step_norms(an_g, bn_g, pr, pc,
-                                                    n_panels)
-                                   for _, _, an_g, bn_g in group_ab]
-                    for t in range(n_steps):
-                        for gi in range(g_count):
-                            una, unb = per_group_n[gi][t]
-                            steps[t][gi].update(a_norms=una, b_norms=unb)
-            lm = _stepwise_batched_lm(
-                g_count, ml, kl, nl, group_mask_steps=steps,
-                filter_eps=filter_eps, **batched_kw)
+                group_ab = []
+                for gi in range(g_count):
+                    am, bmk = _block_masks(
+                        m, k, n, block_m, block_k, block_n,
+                        _per_group(a_masks, gi, g_count, "a_masks"),
+                        _per_group(b_masks, gi, g_count, "b_masks"))
+                    an_g = bn_g = None
+                    if filtering:
+                        from repro.sparsity.norms import normalize_block_norms
+
+                        an_g, bn_g = normalize_block_norms(
+                            am.shape[0], am.shape[1], bmk.shape[1],
+                            _per_group(a_norms, gi, g_count, "a_norms"),
+                            _per_group(b_norms, gi, g_count, "b_norms"))
+                        an_g = np.where(am, an_g, np.float32(0.0))
+                        bn_g = np.where(bmk, bn_g, np.float32(0.0))
+                    group_ab.append((am, bmk, an_g, bn_g))
+                if algorithm == "cannon":
+                    n_steps = pg
+                    per_group = [cannon_step_masks(am, bmk, pg)
+                                 for am, bmk, _, _ in group_ab]
+                    steps = [[{"pair_mask": per_group[gi][t]}
+                              for gi in range(g_count)]
+                             for t in range(n_steps)]
+                    if filtering:
+                        per_group_n = [cannon_step_norms(an_g, bn_g, pg)
+                                       for _, _, an_g, bn_g in group_ab]
+                        for t in range(n_steps):
+                            for gi in range(g_count):
+                                steps[t][gi]["pair_norms"] = per_group_n[gi][t]
+                else:
+                    n_steps = n_panels
+                    per_group = [summa_step_masks(am, bmk, pr, pc, n_panels)
+                                 for am, bmk, _, _ in group_ab]
+                    steps = [[dict(zip(("a_mask", "b_mask"),
+                                       per_group[gi][t]))
+                              for gi in range(g_count)]
+                             for t in range(n_steps)]
+                    if filtering:
+                        per_group_n = [summa_step_norms(an_g, bn_g, pr, pc,
+                                                        n_panels)
+                                       for _, _, an_g, bn_g in group_ab]
+                        for t in range(n_steps):
+                            for gi in range(g_count):
+                                una, unb = per_group_n[gi][t]
+                                steps[t][gi].update(a_norms=una, b_norms=unb)
+                lm = _stepwise_batched_lm(
+                    g_count, ml, kl, nl, group_mask_steps=steps,
+                    filter_eps=filter_eps, **batched_kw)
 
     # ---- data exchange (one schedule for the whole batch) ------------
     def _run():
@@ -418,41 +427,40 @@ def _distributed_matmul_batched(
             a, b, mesh=mesh, grid=grid, local_matmul=lm,
             precision=precision, pipeline_depth=depth, **kw)
 
-    if not _tele:
-        c = _run()
-    else:
-        with obs.span("dispatch", cat="dispatch", algorithm=algorithm,
-                      densify=bool(densify), pipeline_depth=depth,
-                      n_groups=g_count) as dsp:
+    dispatch_s = None
+    with obs.maybe_span(_live, "dispatch", cat="dispatch", counters=True,
+                        algorithm=algorithm, densify=bool(densify),
+                        pipeline_depth=depth, n_groups=g_count) as dsp:
+        if not _tele:
+            c = _run()   # enqueue and return — no timing, no sync
+        else:
             t0 = time.perf_counter()
             c = jax.block_until_ready(_run())
-            dt = time.perf_counter() - t0
-        try:
-            # per-step spans from the single-product schedule model,
-            # G-scaled (comm bytes and dense flops multiply by the
-            # group count on the fused batch)
-            itemsize = int(jnp.dtype(
-                jnp.promote_types(a.dtype, b.dtype)).itemsize)
-            ss = _schedule_stats(
-                algorithm, grid=grid, mesh=mesh, local_shape=(ml, kl, nl),
-                itemsize=itemsize, lm=lm, densify=densify,
-                pipeline_depth=depth, reduce_kw=kw, n_groups=g_count)
-        except Exception:
-            ss = None  # telemetry must never break the multiply
-        if ss is not None:
-            dsp.set(comm_bytes=int(ss.get("total_comm_bytes", 0)))
-            _emit_step_spans(dsp.rec, t0, dt, ss)
-        if plan is not None and not plan.trivial:
-            obs.record_plan_outcome(
-                kind="multiply_batched", algorithm=algorithm,
-                densify=bool(densify), n_groups=g_count, m=m, k=k, n=n,
-                fuse=bool(plan.fuse),
-                predicted_s=float(plan.predicted_fused_s),
-                measured_s=float(dt), pipeline_depth=int(depth))
-    if not return_plan:
-        return c
-    import dataclasses as _dc
-
-    plan = _dc.replace(
-        plan, executor_stats=_collect_batched_executor_stats(lm, densify))
-    return c, plan
+            dispatch_s = time.perf_counter() - t0
+    with obs.maybe_span(_live, "finish", cat="finish"):
+        if _tele:
+            try:
+                # G-scaled comm bytes of the single-product schedule
+                itemsize = int(jnp.dtype(
+                    jnp.promote_types(a.dtype, b.dtype)).itemsize)
+                ss = _schedule_stats(
+                    algorithm, grid=grid, mesh=mesh,
+                    local_shape=(ml, kl, nl), itemsize=itemsize, lm=lm,
+                    densify=densify, pipeline_depth=depth, reduce_kw=kw,
+                    n_groups=g_count)
+                dsp.set(comm_bytes=int(ss.get("total_comm_bytes", 0)))
+            except Exception:
+                pass  # telemetry must never break the multiply
+            if plan is not None and not plan.trivial:
+                obs.record_plan_outcome(
+                    kind="multiply_batched", algorithm=algorithm,
+                    densify=bool(densify), n_groups=g_count, m=m, k=k,
+                    n=n, fuse=bool(plan.fuse),
+                    predicted_s=float(plan.predicted_fused_s),
+                    measured_s=float(dispatch_s), pipeline_depth=int(depth))
+        if not return_plan:
+            return c
+        plan = dataclasses.replace(
+            plan,
+            executor_stats=_collect_batched_executor_stats(lm, densify))
+        return c, plan

@@ -103,4 +103,5 @@ def smm_pallas_call(
         out_shape=jax.ShapeDtypeStruct(c_blocks.shape, jnp.float32),
         input_output_aliases={3: 0},  # c_blocks buffer is donated to out
         interpret=interpret,
+        name="smm",
     )(triples.reshape(-1), a_blocks, b_blocks, c_blocks)

@@ -4,13 +4,14 @@ Consumes the JSONL logs a traced run leaves behind
 (``events.jsonl`` + ``plan_outcomes.jsonl`` under ``--dir``) and
 renders the two views the paper's evidence needs:
 
-  breakdown   comm-vs-compute-vs-verify wall-time split, summed over
-              span categories (plan / comm / compute / verify /
-              repair) across all recorded multiplies
+  breakdown   self time per phase (plan / stacks / dispatch / finish /
+              matricize / verify / repair) across all recorded
+              multiplies
   scoreboard  predicted-vs-actual planner cost per algorithm
 
-``render_timeline`` prints one trace as an indented tree — the same
-nesting the Chrome-trace export shows graphically.
+``render_timeline`` prints one trace as an indented tree — recorded
+spans, or the ``dbcsr.*`` spans of a profiler trace
+(``export.profile_spans``).
 """
 from __future__ import annotations
 
@@ -26,34 +27,25 @@ from .scoreboard import planner_scoreboard, render_scoreboard
 __all__ = ["category_breakdown", "render_breakdown", "render_timeline",
            "main"]
 
-# categories whose spans are mutually exclusive slices of a dispatch
-# ("matricize" = the tensor subsystem's unfold/refold phases under a
-# contract root — disjoint from the nested multiply's own phases)
-_PHASE_CATS = ("plan", "matricize", "comm", "compute", "verify", "repair")
+# the phases of a multiply ("matricize" = the tensor subsystem's
+# unfold/refold phases under a contract root)
+_PHASE_CATS = ("plan", "stacks", "dispatch", "finish", "matricize",
+               "verify", "repair")
 
 
 def category_breakdown(spans: Sequence[SpanRecord]) -> Dict[str, float]:
-    """Total seconds per span category.
-
-    ``comm``/``compute`` are the synthetic schedule-step children of a
-    dispatch (model-weighted slices of the measured wall time), so
-    comm + compute ~= dispatch.  ``verify`` is reported *exclusive* of
-    nested repair re-execution — a repaired multiply shows its second
-    dispatch under ``repair``, not double-counted under ``verify``.
-    """
-    by_id = {s.span_id: s for s in spans}
+    """Self seconds per span category: each span's duration less the
+    part its children cover, so nested phases are never counted twice
+    (a repair's re-run dispatch counts under ``dispatch``, the checksum
+    work around it under ``verify``).  ``total`` is the roots' time."""
+    child_s: Dict[int, float] = collections.defaultdict(float)
+    for s in spans:
+        if s.dur >= 0 and s.parent_id is not None:
+            child_s[s.parent_id] += s.dur
     out: Dict[str, float] = collections.defaultdict(float)
     for s in spans:
-        if s.dur < 0 or s.cat not in _PHASE_CATS:
-            continue
-        out[s.cat] += s.dur
-    # make verify exclusive of its repair children
-    for s in spans:
-        if s.cat != "repair" or s.dur < 0:
-            continue
-        parent = by_id.get(s.parent_id)
-        if parent is not None and parent.cat == "verify":
-            out["verify"] -= s.dur
+        if s.dur >= 0 and s.cat in _PHASE_CATS:
+            out[s.cat] += s.dur - child_s[s.span_id]
     roots = [s for s in spans if s.parent_id is None and s.dur >= 0]
     out["total"] = sum(s.dur for s in roots)
     return dict(out)
@@ -72,9 +64,8 @@ def render_breakdown(spans: Sequence[SpanRecord]) -> str:
     return "\n".join(lines)
 
 
-def render_timeline(spans: Sequence[SpanRecord], *,
-                    max_steps: int = 6) -> str:
-    """One trace as an indented tree (collapses long step runs)."""
+def render_timeline(spans: Sequence[SpanRecord]) -> str:
+    """One trace as an indented tree."""
     spans = [s for s in spans if s.dur >= 0]
     if not spans:
         return "(empty trace)"
@@ -87,41 +78,26 @@ def render_timeline(spans: Sequence[SpanRecord], *,
     lines: List[str] = []
 
     def _attrs(s: SpanRecord) -> str:
-        keys = ("algorithm", "comm_bytes", "flops", "occupancy",
-                "rank_imbalance", "skipped", "detected", "repaired")
+        keys = ("algorithm", "comm_bytes", "lowerings", "compiles",
+                "cache_hits", "lower_s", "detected", "repaired")
         parts = [f"{k}={s.attrs[k]}" for k in keys
                  if s.attrs.get(k) is not None]
         return ("  [" + " ".join(parts) + "]") if parts else ""
 
     def _walk(parent_id: Optional[int], depth: int) -> None:
-        kids = children.get(parent_id, [])
-        steps = [s for s in kids if s.cat == "schedule-step"]
-        shown = kids
-        if len(steps) > max_steps:
-            keep = set(id(s) for s in steps[:max_steps // 2]
-                       ) | set(id(s) for s in steps[-max_steps // 2:])
-            shown = [s for s in kids
-                     if s.cat != "schedule-step" or id(s) in keep]
-        n_hidden = len(kids) - len(shown)
-        for s in shown:
+        for s in children.get(parent_id, []):
             lines.append(f"{'  ' * depth}{s.name:<20} "
                          f"{s.dur*1e3:9.3f} ms{_attrs(s)}")
             _walk(s.span_id, depth + 1)
-        if n_hidden > 0:
-            lines.append(f"{'  ' * depth}... ({n_hidden} more steps)")
 
-    roots = children.get(None, [])
-    for root in roots:
-        lines.append(f"{root.name:<20} {root.dur*1e3:9.3f} ms"
-                     f"{_attrs(root)}")
-        _walk(root.span_id, 1)
+    _walk(None, 0)
     return "\n".join(lines)
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs report",
-        description="Render the comm/compute/verify breakdown and the "
+        description="Render the per-phase breakdown and the "
                     "planner predicted-vs-actual scoreboard from a "
                     "traced run's JSONL logs.")
     ap.add_argument("--dir", default=os.path.join("artifacts", "obs"),

@@ -1,31 +1,47 @@
-"""Structured spans and the predicted-vs-actual plan-outcome log.
+"""Structured spans, on the profiler's clock, and the predicted-vs-actual
+plan-outcome log.
 
-A :class:`Tracer` records :class:`SpanRecord` rows — host-side timed
-intervals with parent/child nesting — for the multiply pipeline:
+A span is one API with two sinks.  ``span(name)`` always opens a
+profiler annotation named ``dbcsr.<name>`` (``jax.profiler.
+TraceAnnotation``): under a profiler session it lands on the caller's
+host thread in the profiler's own trace, on the same clock as the
+device ops; with no session it is an inactive TraceMe.  Under
+``enable()`` the span is also kept as a :class:`SpanRecord` — a
+host-side timed interval with parent/child nesting — for the multiply
+pipeline:
 
     multiply                       (root, one per dbcsr.multiply)
-      plan                         planner decision
-      dispatch                     device execution (block_until_ready)
-        prologue / step[t] / epilogue   schedule model, scaled to fit
-          comm, stacks                  the measured dispatch wall time
+      plan                         norms, masks, occupancy, planner
+      stacks                       the local multiply: stack plans,
+                                   per-step masks and norms
+      dispatch                     the schedule engine's call: closure,
+                                   trace, lowering, compile-cache
+                                   lookup, launch
       verify                       ABFT checksum verification
         repair                     re-execution after a detection
-          dispatch ...
+          dispatch
+      finish                       executor/schedule stats, result mask
 
-Telemetry is OFF by default and the contract is *zero overhead, bit
-identical results* when off: instrumented call sites test a local
-``_tele`` flag (``obs.enabled()`` and not under ``jax.jit`` tracing)
-once per call and skip every span/timing/``block_until_ready`` when it
-is false.  ``span()`` returns a shared no-op object when disabled, so
-stray call sites cost one attribute check.
+``span(..., counters=True)`` (the root and ``dispatch``) attaches the
+JAX runtime's counts over the span (``runtime.py``: ``lowerings``,
+``traces``, ``compiles``, ``cache_hits``, ``lower_s``, ``trace_s``,
+``compile_s``, ``lowered``) to the annotation's metadata and the
+record's attrs, computed only when a profiler session runs or
+telemetry is on.
+
+Call sites gate spans on ``not under jax.jit tracing`` (``maybe_span``
+with the operands' tracer check); telemetry decides only the records,
+the plan-outcome log and the enabled path's synchronisation.  Telemetry
+is OFF by default and the contract is *bit identical results and zero
+registry entries* when off.
 
 ``enable(log_dir=...)`` additionally appends every completed trace to
 ``<log_dir>/events.jsonl`` and every plan outcome (predicted vs
 measured cost per executed plan) to ``<log_dir>/plan_outcomes.jsonl``
 — the file ``planner.calibrate --check-drift`` consumes.
 
-This module must not import jax or anything from ``repro.core`` /
-``repro.planner`` (they import us).
+This module imports jax only when a span first opens, and nothing from
+``repro.core`` / ``repro.planner`` (they import us).
 """
 from __future__ import annotations
 
@@ -36,13 +52,16 @@ import os
 import time
 from typing import Dict, List, Optional
 
+from . import runtime
+
 __all__ = [
     "SpanRecord", "Tracer", "enable", "disable", "enabled",
-    "get_tracer", "span", "maybe_span", "event", "last_trace",
+    "span", "maybe_span", "last_trace",
     "record_plan_outcome", "plan_outcomes", "clear_plan_outcomes",
-    "EVENTS_LOG", "PLAN_OUTCOMES_LOG",
+    "EVENTS_LOG", "PLAN_OUTCOMES_LOG", "PROFILE_PREFIX",
 ]
 
+PROFILE_PREFIX = "dbcsr."
 EVENTS_LOG = "events.jsonl"
 PLAN_OUTCOMES_LOG = "plan_outcomes.jsonl"
 
@@ -50,8 +69,7 @@ PLAN_OUTCOMES_LOG = "plan_outcomes.jsonl"
 @dataclasses.dataclass
 class SpanRecord:
     """One timed interval.  ``t0`` is ``time.perf_counter()`` seconds;
-    ``dur`` is seconds (synthetic schedule-step spans get explicit
-    ``t0``/``dur`` carved out of the measured dispatch interval)."""
+    ``dur`` is seconds."""
 
     name: str
     cat: str
@@ -79,30 +97,65 @@ class SpanRecord:
             attrs=dict(d.get("attrs") or {}))
 
 
-class _ActiveSpan:
-    """Context manager for an open span; ``set()`` attaches attrs."""
+_ANNOTATION = None   # jax.profiler.TraceAnnotation, imported on first use
 
-    __slots__ = ("_tracer", "rec")
 
-    def __init__(self, tracer: "Tracer", rec: SpanRecord):
-        self._tracer = tracer
-        self.rec = rec
+class _Span:
+    """An open span: the profiler annotation ``dbcsr.<name>``, the
+    :class:`SpanRecord` under ``enable()``, and with ``counters`` the
+    JAX runtime's counts over the span on both.  ``set()`` attaches
+    attrs to the record."""
+
+    __slots__ = ("_name", "_cat", "_attrs", "_counters", "_ann", "_mark",
+                 "_tracer", "rec")
+
+    def __init__(self, name: str, cat: str, counters: bool, attrs: dict):
+        self._name = name
+        self._cat = cat
+        self._attrs = attrs
+        self._counters = counters
+        self._mark = None
+        self._tracer = None
+        self.rec: Optional[SpanRecord] = None
 
     def set(self, **attrs) -> None:
-        self.rec.attrs.update(attrs)
+        if self.rec is not None:
+            self.rec.attrs.update(attrs)
 
-    def __enter__(self) -> "_ActiveSpan":
+    def __enter__(self) -> "_Span":
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            from jax.profiler import TraceAnnotation
+
+            _ANNOTATION = TraceAnnotation
+        self._ann = _ANNOTATION(PROFILE_PREFIX + self._name)
+        self._ann.__enter__()
+        if _ENABLED and _TRACER is not None:
+            self._tracer = _TRACER
+            self.rec = _TRACER.begin(self._name, self._cat, **self._attrs)
+        if self._counters:
+            runtime.install()
+            if self.rec is not None or self._ann.is_enabled():
+                self._mark = runtime.mark()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if exc_type is not None:
-            self.rec.attrs.setdefault("error", exc_type.__name__)
-        self._tracer.end(self.rec)
+        try:
+            if self._mark is not None:
+                counts = runtime.since(self._mark)
+                self._ann.set_metadata(**counts)
+                self.set(**counts)
+            if self.rec is not None:
+                if exc_type is not None:
+                    self.rec.attrs.setdefault("error", exc_type.__name__)
+                self._tracer.end(self.rec)
+        finally:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 class _NoopSpan:
-    """Shared do-nothing span for the disabled path."""
+    """Shared do-nothing span for a call site under jit tracing."""
 
     __slots__ = ()
     rec = None
@@ -153,23 +206,6 @@ class Tracer:
         if rec.parent_id is None:
             self._root_ids.append(rec.span_id)
             self._flush_trace(rec)
-
-    def emit(self, name: str, cat: str, *, t0: float, dur: float,
-             parent: Optional[SpanRecord] = None,
-             attrs: Optional[dict] = None) -> SpanRecord:
-        """Append a synthetic (already-timed) span, e.g. schedule-step
-        intervals carved out of a measured dispatch."""
-        rec = SpanRecord(
-            name=name, cat=cat, span_id=next(self._ids),
-            parent_id=parent.span_id if parent is not None else None,
-            trace_id=(parent.trace_id if parent is not None
-                      else next(self._ids)),
-            t0=float(t0), dur=float(dur), attrs=dict(attrs or {}))
-        self.spans.append(rec)
-        return rec
-
-    def span(self, name: str, cat: str = "span", **attrs) -> _ActiveSpan:
-        return _ActiveSpan(self, self.begin(name, cat, **attrs))
 
     def current(self) -> Optional[SpanRecord]:
         return self._stack[-1] if self._stack else None
@@ -232,32 +268,20 @@ def enabled() -> bool:
     return _ENABLED
 
 
-def get_tracer() -> Optional[Tracer]:
-    return _TRACER if _ENABLED else None
-
-
-def span(name: str, cat: str = "span", **attrs):
-    """Open a span on the active tracer; no-op when disabled."""
-    if not _ENABLED or _TRACER is None:
-        return NOOP_SPAN
-    return _TRACER.span(name, cat, **attrs)
+def span(name: str, cat: str = "span", *, counters: bool = False,
+         **attrs) -> _Span:
+    """Open a span: the profiler annotation ``dbcsr.<name>`` always, a
+    record on the active tracer when enabled; ``counters`` attaches the
+    JAX runtime's counts over the span to both."""
+    return _Span(name, cat, counters, attrs)
 
 
 def maybe_span(cond: bool, name: str, cat: str = "span", **attrs):
-    """``span()`` gated on a call-site flag (e.g. the per-call
-    ``_tele`` bool that also excludes ``jax.jit`` tracing)."""
+    """``span()`` gated on a call-site flag: False (the operands are
+    ``jax.jit`` tracers) gives the shared no-op span."""
     if not cond:
         return NOOP_SPAN
     return span(name, cat, **attrs)
-
-
-def event(name: str, cat: str = "event", **attrs) -> None:
-    """Zero-duration marker attached to the innermost open span."""
-    if not _ENABLED or _TRACER is None:
-        return
-    t = time.perf_counter()
-    _TRACER.emit(name, cat, t0=t, dur=0.0, parent=_TRACER.current(),
-                 attrs=attrs)
 
 
 def last_trace() -> List[SpanRecord]:

@@ -1,14 +1,15 @@
-"""Telemetry battery: metrics registry, span nesting, exporters, the
-disabled-path zero-overhead contract, and predicted-vs-actual planner
-accounting (repro.obs).
+"""Telemetry battery: metrics registry, span nesting, profiler spans
+and the JAX runtime's counters on them, the disabled-path contract, and
+predicted-vs-actual planner accounting (repro.obs).
 
-The hard contract under test (ISSUE 8 acceptance): with telemetry
-DISABLED (the default) the multiply paths are bitwise identical to an
-enabled-then-disabled process and add ZERO registry entries; with it
-ENABLED one ``dbcsr.multiply`` leaves a well-formed span tree whose
-synthetic schedule-step durations sum consistently with the measured
-dispatch wall time, exports valid Chrome-trace JSON, and records a
-predicted-vs-measured plan outcome for the scoreboard.
+The hard contract under test: with telemetry DISABLED (the default)
+the multiply paths are bitwise identical to an enabled-then-disabled
+process, add ZERO registry entries, never wait on the device and run
+no planner they did not run before; under a profiler session one
+``dbcsr.multiply`` leaves nested ``dbcsr.*`` host events carrying the
+call's lowerings and compiles; with telemetry ENABLED it leaves a
+well-formed span tree (multiply > plan, stacks, dispatch, finish) and
+records a predicted-vs-measured plan outcome for the scoreboard.
 """
 import json
 import os
@@ -66,6 +67,15 @@ def _spans_by_name(spans, name):
 
 def _children(spans, parent):
     return [s for s in spans if s.parent_id == parent.span_id]
+
+
+def _profiled(log_dir, fn):
+    """``fn()`` under a profiler session writing to ``log_dir``; its
+    value and the ``dbcsr.*`` spans of the trace."""
+    with jax.profiler.trace(str(log_dir)):
+        out = fn()
+        jax.block_until_ready(getattr(out, "data", out))
+    return out, obs.profile_spans(str(log_dir))
 
 
 # ---------------------------------------------------------------------------
@@ -146,11 +156,16 @@ def test_span_nesting_and_last_trace():
 
 
 def test_span_disabled_is_shared_noop():
-    assert obs.span("x") is obs.NOOP_SPAN
+    # a call site under jit tracing gets the shared no-op span
     assert obs.maybe_span(False, "x") is obs.NOOP_SPAN
-    with obs.span("x") as sp:      # must be safely enterable
+    with obs.maybe_span(False, "x") as sp:
         sp.set(ignored=1)
+    # telemetry off: a live span is an annotation and keeps no record
+    with obs.span("x", counters=True) as sp:
+        sp.set(ignored=1)
+    assert sp.rec is None
     assert obs.last_trace() == []
+    assert len(obs.registry()) == 0
 
 
 def test_span_exception_tagged_and_stack_recovers():
@@ -176,25 +191,57 @@ def _toy_trace():
     return obs.last_trace()
 
 
-def test_chrome_trace_valid_and_written(tmp_path):
-    spans = _toy_trace()
-    chrome = obs.to_chrome_trace(spans)
-    assert obs.validate_chrome_trace(chrome) == []
-    path = str(tmp_path / "trace.json")
-    obs.write_chrome_trace(path, spans)
-    with open(path) as f:
-        assert obs.validate_chrome_trace(json.load(f)) == []
+def test_profile_spans_nest_and_carry_metadata(tmp_path):
+    # only dbcsr.* annotations are read back, nested by containment on
+    # their thread; a counting span carries the runtime's counts
+    def fn():
+        with jax.profiler.TraceAnnotation("other"):
+            with obs.span("root", counters=True):
+                with obs.span("child"):
+                    pass
+                return jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(3))
+
+    _, spans = _profiled(tmp_path, fn)
+    root, child = sorted(spans, key=lambda s: s.t0)
+    assert (root.name, child.name) == ("dbcsr.root", "dbcsr.child")
+    assert root.parent_id is None and child.parent_id == root.span_id
+    assert root.t0 <= child.t0 and child.t0 + child.dur <= root.t0 + root.dur
+    assert child.attrs == {}
+    assert root.attrs["lowerings"] >= 1 and root.attrs["compiles"] >= 1
+    assert "lambda" in root.attrs["lowered"]
+    assert "dbcsr.child" in obs.render_timeline(spans)
 
 
-def test_chrome_trace_validator_catches_tampering():
-    chrome = obs.to_chrome_trace(_toy_trace())
-    xs = [e for e in chrome["traceEvents"] if e["ph"] == "X"]
-    xs[0]["dur"] = -5.0                      # negative duration
-    xs[1]["args"]["parent_id"] = 10 ** 9     # orphan parent
-    errors = obs.validate_chrome_trace(chrome)
-    assert errors
-    assert obs.validate_chrome_trace({"traceEvents": []})
-    assert obs.validate_chrome_trace([1, 2, 3])
+def test_runtime_counts_lowerings_and_publishes_only_when_enabled():
+    from jax import monitoring
+    from repro.obs import runtime
+
+    runtime.install()
+    mark = runtime.mark()
+    monitoring.record_event_duration_secs(runtime.LOWER, 0.25,
+                                          fun_name="f,g#h")
+    monitoring.record_event_duration_secs(runtime.LOWER, 0.5,
+                                          fun_name="f,g#h")
+    monitoring.record_event_duration_secs(runtime.TRACE, 0.125,
+                                          fun_name="f")
+    monitoring.record_event_duration_secs(runtime.COMPILE, 1.0,
+                                          fun_name="f")
+    monitoring.record_event(runtime.CACHE_HIT)
+    counts = runtime.since(mark)
+    assert counts == {"traces": 1, "lowerings": 2, "compiles": 1,
+                      "cache_hits": 1, "trace_s": pytest.approx(0.125),
+                      "lower_s": pytest.approx(0.75),
+                      "compile_s": pytest.approx(1.0), "lowered": "f g h"}
+    assert len(obs.registry()) == 0   # telemetry off: counted, not published
+    obs.enable()
+    mark = runtime.mark()
+    for i in range(40):
+        monitoring.record_event_duration_secs(runtime.LOWER, 0.0,
+                                              fun_name=f"program_{i}")
+    counts = runtime.since(mark)
+    assert len(counts["lowered"]) == runtime.NAMES_MAX
+    assert counts["lowered"].startswith("program_0;program_1;")
+    assert obs.counter("jax.lowerings").value == 40
 
 
 def test_jsonl_event_log_round_trip(tmp_path):
@@ -238,7 +285,7 @@ def test_report_cli(tmp_path, capsys):
 # ---------------------------------------------------------------------------
 
 
-def test_disabled_is_bitwise_identical_and_adds_no_metrics(rng):
+def test_disabled_is_bitwise_identical_and_adds_no_metrics(rng, tmp_path):
     mesh = _mesh11()
     a = _operand(rng, 128, 128, mesh=mesh)
     b = _operand(rng, 128, 128, mesh=mesh)
@@ -251,6 +298,12 @@ def test_disabled_is_bitwise_identical_and_adds_no_metrics(rng):
         "disabled multiply must add zero registry entries"
     assert obs.last_trace() == []
 
+    # a profiler session sees the spans, and changes neither the
+    # product nor the registry
+    c_prof, spans = _profiled(tmp_path, lambda: dbcsr.multiply(a, b, **kw))
+    assert spans and len(obs.registry()) == 0
+    assert obs.last_trace() == []
+
     obs.enable()
     c_on = dbcsr.multiply(a, b, **kw)
     jax.block_until_ready(c_on.data)
@@ -259,11 +312,54 @@ def test_disabled_is_bitwise_identical_and_adds_no_metrics(rng):
 
     assert (np.asarray(c_on.data) == np.asarray(c_off.data)).all()
     assert (np.asarray(c_off2.data) == np.asarray(c_off.data)).all()
+    assert (np.asarray(c_prof.data) == np.asarray(c_off.data)).all()
 
 
-def test_enabled_under_jit_records_nothing(rng):
-    # operands are jax tracers under jit: the per-call _tele flag must
-    # veto spans even though the global switch is on
+def test_disabled_never_waits_nor_plans_a_pinned_call(rng, tmp_path,
+                                                      monkeypatch):
+    # telemetry off, with or without a profiler session: the dispatch
+    # only enqueues, and a pinned call runs no planner; telemetry on:
+    # the dispatch waits and the planner prices the call for the
+    # scoreboard, as before
+    import repro.planner.plan as planner
+
+    calls = {"wait": 0, "plan": 0}
+    wait, plan_multiply = jax.block_until_ready, planner.plan_multiply
+
+    def counted_wait(x):
+        calls["wait"] += 1
+        return wait(x)
+
+    def counted_plan(*args, **kwargs):
+        calls["plan"] += 1
+        return plan_multiply(*args, **kwargs)
+
+    mesh = _mesh11()
+    grid = GridSpec("data", "model")
+    A = jnp.asarray(rng.randn(64, 64).astype(np.float32))
+    B = jnp.asarray(rng.randn(64, 64).astype(np.float32))
+
+    def call():
+        return distributed_matmul(A, B, mesh=mesh, grid=grid, block_m=32,
+                                  block_k=32, block_n=32, **EXEC_KW)
+
+    ref = wait(call())
+    monkeypatch.setattr(jax, "block_until_ready", counted_wait)
+    monkeypatch.setattr(planner, "plan_multiply", counted_plan)
+    assert (np.asarray(call()) == np.asarray(ref)).all()
+    with jax.profiler.trace(str(tmp_path)):
+        assert (np.asarray(call()) == np.asarray(ref)).all()
+    assert calls == {"wait": 0, "plan": 0}
+    obs.enable()
+    call()
+    assert calls["wait"] >= 1 and calls["plan"] == 1
+    assert len(obs.plan_outcomes()) == 1
+
+
+def test_enabled_under_jit_records_nothing(rng, tmp_path):
+    # operands are jax tracers under jit: the per-call flag must veto
+    # spans — records and profiler annotations — even though the global
+    # switch is on and a profiler session runs
     mesh = _mesh11()
     grid = GridSpec("data", "model")
     A = rng.randn(64, 64).astype(np.float32)
@@ -273,10 +369,12 @@ def test_enabled_under_jit_records_nothing(rng):
     fn = jax.jit(lambda x, y: distributed_matmul(
         x, y, mesh=mesh, grid=grid, block_m=32, block_k=32, block_n=32,
         **EXEC_KW))
-    C = jax.block_until_ready(fn(A, B))
+    with jax.profiler.trace(str(tmp_path)):
+        C = jax.block_until_ready(fn(A, B))
     np.testing.assert_allclose(np.asarray(C), A @ B, rtol=2e-4, atol=2e-4)
     assert tracer.spans == []
     assert obs.plan_outcomes() == []
+    assert obs.profile_spans(str(tmp_path)) == []
 
 
 # ---------------------------------------------------------------------------
@@ -297,31 +395,58 @@ def test_traced_multiply_span_tree_and_outcome(rng):
     (root,) = [s for s in spans if s.parent_id is None]
     assert root.name == "multiply" and root.cat == "multiply"
     kids = {s.name: s for s in _children(spans, root)}
-    assert set(kids) == {"plan", "dispatch"}
+    assert set(kids) == {"plan", "stacks", "dispatch", "finish"}
+    assert len(spans) == 5   # the phases have no children of their own
     assert kids["plan"].attrs["algorithm"] == "cannon"
     disp = kids["dispatch"]
     assert disp.attrs["comm_bytes"] >= 0
-
-    # synthetic schedule-step children fill the measured dispatch
-    # interval: sum(children) ~= dispatch dur, root covers dispatch
-    steps = _children(spans, disp)
-    assert steps and all(s.cat in ("comm", "schedule-step")
-                         for s in steps)
-    ssum = sum(s.dur for s in steps)
-    assert ssum == pytest.approx(disp.dur, rel=0.1)
-    assert root.dur >= disp.dur > 0
-    step_spans = [s for s in steps if s.cat == "schedule-step"]
-    assert all("flops" in s.attrs and "comm_bytes" in s.attrs
-               for s in step_spans)
+    # the dispatch and the root carry the runtime's counters over their
+    # intervals; the root's include the dispatch's
+    for key in ("lowerings", "traces", "compiles", "cache_hits"):
+        assert root.attrs[key] >= disp.attrs[key] >= 0
+    assert root.attrs["lower_s"] >= disp.attrs["lower_s"] >= 0.0
+    assert isinstance(disp.attrs["lowered"], str)
+    # the phases run one after another inside the root
+    order = sorted(kids.values(), key=lambda s: s.t0)
+    assert [s.name for s in order] == ["plan", "stacks", "dispatch",
+                                       "finish"]
+    for s, t in zip(order, order[1:]):
+        assert s.t0 + s.dur <= t.t0
+    assert root.dur >= sum(s.dur for s in order) > 0
+    # the per-step model stays on the plan, not in the trace
+    steps = plan.schedule_stats["steps"]
+    assert steps and all("flops" in s and "comm_bytes" in s
+                         and "occupancy" in s for s in steps)
 
     # every traced non-trivial multiply records predicted-vs-measured
     (out,) = obs.plan_outcomes()
     assert out["algorithm"] == "cannon"
     assert out["predicted_s"] == pytest.approx(float(plan.predicted_s))
-    assert 0 < out["measured_s"] <= root.dur
+    assert 0 < out["measured_s"] <= disp.dur
 
-    # and the whole trace exports as valid Chrome-trace JSON
-    assert obs.validate_chrome_trace(obs.to_chrome_trace(spans)) == []
+
+def test_profiled_multiply_span_tree_and_counters(rng, tmp_path):
+    # telemetry off: the profiler alone sees the phases nested on its
+    # clock, and the first call's root names what it lowered
+    mesh = _mesh11()
+    a = _operand(rng, 96, 96, mesh=mesh)
+    b = _operand(rng, 96, 96, mesh=mesh)
+    _, spans = _profiled(tmp_path, lambda: dbcsr.multiply(
+        a, b, mesh=mesh, **EXEC_KW))
+    (root,) = [s for s in spans if s.parent_id is None]
+    assert root.name == "dbcsr.multiply"
+    kids = [s.name for s in sorted(_children(spans, root),
+                                   key=lambda s: s.t0)]
+    assert kids == ["dbcsr.plan", "dbcsr.stacks", "dbcsr.dispatch",
+                    "dbcsr.finish"]
+    (disp,) = _spans_by_name(spans, "dbcsr.dispatch")
+    assert root.attrs["lowerings"] >= disp.attrs["lowerings"] >= 1
+    assert root.attrs["compiles"] >= 1
+    assert root.attrs["lower_s"] > 0.0
+    assert root.attrs["lowered"] and disp.attrs["lowered"]
+    assert set(disp.attrs["lowered"].split(";")) <= set(
+        root.attrs["lowered"].split(";"))
+    assert obs.last_trace() == [] and len(obs.registry()) == 0
 
 
 def test_traced_fused_batched_span_tree(rng):
@@ -339,9 +464,11 @@ def test_traced_fused_batched_span_tree(rng):
     assert root.name == "multiply_batched"
     assert root.attrs["n_groups"] == 3
     kids = {s.name: s for s in _children(spans, root)}
-    assert set(kids) == {"plan", "dispatch"}
-    assert _children(spans, kids["dispatch"]), \
-        "fused dispatch must carry schedule-step children"
+    assert set(kids) == {"plan", "stacks", "dispatch", "finish"}
+    disp = kids["dispatch"]
+    assert disp.attrs["n_groups"] == 3 and disp.attrs["comm_bytes"] >= 0
+    assert root.attrs["compiles"] >= disp.attrs["compiles"] >= 0
+    assert not _children(spans, disp)
     # ONE fused dispatch — no nested per-request "multiply" roots
     assert _spans_by_name(spans, "multiply") == []
     # fuse-or-loop decision counters (gated, enabled here)
@@ -393,6 +520,17 @@ def test_traced_abft_repair_nests_second_dispatch(rng):
     (out,) = obs.plan_outcomes()
     first = min(dispatches, key=lambda s: s.t0)
     assert out["measured_s"] == pytest.approx(first.dur, rel=0.25)
+
+
+def test_local_dot_named_in_its_op_metadata():
+    # where the densified local multiply is traced into one program, its
+    # dot carries a stable name for the device trace
+    from repro.core.densify import densified_local_matmul
+
+    x = jnp.ones((8, 8), jnp.float32)
+    text = jax.jit(densified_local_matmul()).lower(x, x).as_text(
+        debug_info=True)
+    assert "dbcsr.local_dot/dot_general" in text
 
 
 # ---------------------------------------------------------------------------
