@@ -56,9 +56,11 @@ imbalance when the predicted compute saved exceeds the shuffle's cost
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
 import time
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -476,6 +478,87 @@ def _schedule_stats(algorithm: str, *, grid, mesh, local_shape, itemsize,
     }
 
 
+# ---------------------------------------------------------------------------
+# dispatch: the schedule call, and the densified path's cached programs
+# ---------------------------------------------------------------------------
+
+
+def _schedule_matmul(algorithm: str, a, b, *, mesh, grid, local_matmul,
+                     precision, pipeline_depth: int, **kw):
+    """C = A @ B through ``algorithm``'s schedule (core/schedule.py);
+    ``kw`` carries the schedule's own options (``reduce``, ``bcast``)."""
+    if algorithm == "cannon":
+        return cannon_matmul(
+            a, b, mesh=mesh, grid=grid, local_matmul=local_matmul,
+            precision=precision, pipeline_depth=pipeline_depth, **kw)
+    if algorithm == "cannon25d":
+        return cannon25d_matmul(
+            a, b, mesh=mesh, grid=grid, local_matmul=local_matmul,
+            precision=precision, pipeline_depth=pipeline_depth, **kw)
+    if algorithm in ("ts_k", "ts_m", "ts_n"):
+        return tall_skinny_matmul(
+            a, b, mesh=mesh, grid=grid, mode=algorithm,
+            local_matmul=local_matmul, precision=precision,
+            pipeline_depth=pipeline_depth, **kw)
+    return summa_matmul(
+        a, b, mesh=mesh, grid=grid, local_matmul=local_matmul,
+        precision=precision, pipeline_depth=pipeline_depth, **kw)
+
+
+_PROGRAM_CACHE_SIZE = 512   # as the planner's plan cache
+_programs: "collections.OrderedDict[tuple, Callable]" = \
+    collections.OrderedDict()
+_programs_lock = threading.Lock()
+
+
+def _operand_key(x) -> tuple:
+    """What a compiled program depends on in an operand: its abstract
+    value (shape, dtype, weak type) and, for a concrete array, its
+    sharding and whether it is committed to it."""
+    if isinstance(x, jax.core.Tracer):
+        return jax.typeof(x), None, None
+    return (jax.typeof(x), getattr(x, "sharding", None),
+            getattr(x, "committed", None))
+
+
+def _densified_program(algorithm: str, a, b, *, mesh, grid, depth: int,
+                       precision, local_kernel: Optional[str],
+                       kw: dict) -> Tuple[Callable, bool]:
+    """The densified dispatch as one ``jax.jit`` program ``(a, b) ->
+    C``, and whether it came from the cache (a hit: this key's program
+    is compiled, so the call neither traces, lowers nor compiles).
+
+    The key is the static configuration the program depends on
+    (algorithm, mesh, grid, pipeline depth, precision, local kernel and
+    the schedule's keyword options) and both operands' ``_operand_key``.
+    The program builds its local multiply and its schedule call from
+    the key alone, so the algorithms' ``shard_map`` is traced under
+    ``jit`` once per key instead of run eagerly, one program per
+    primitive, on every call.  Least recently used keys beyond
+    ``_PROGRAM_CACHE_SIZE`` are dropped with their executables."""
+    key = (algorithm, mesh, grid, depth, precision, local_kernel,
+           tuple(sorted(kw.items())), _operand_key(a), _operand_key(b))
+    with _programs_lock:
+        program = _programs.get(key)
+        if program is not None:
+            _programs.move_to_end(key)
+            return program, True
+    lm = densified_local_matmul(precision, kernel=local_kernel)
+    options = dict(key[6])
+
+    def densified_dispatch(a, b):
+        return _schedule_matmul(algorithm, a, b, mesh=mesh, grid=grid,
+                                local_matmul=lm, precision=precision,
+                                pipeline_depth=depth, **options)
+
+    program = jax.jit(densified_dispatch)
+    with _programs_lock:
+        _programs[key] = program
+        while len(_programs) > _PROGRAM_CACHE_SIZE:
+            _programs.popitem(last=False)
+    return program, False
+
+
 def _verified_result(verify, a, b, c, rerun, *, plan, block_m, block_k,
                      block_n, a_mask, b_mask, a_norms, b_norms, filter_eps,
                      verify_budget, _live: bool = False):
@@ -614,6 +697,16 @@ def distributed_matmul(
     A/C and block cols of B/C (never K: that would reorder every C
     block's accumulation), and is inverted on C before returning.
 
+    Dispatch: the densified path runs as one cached ``jax.jit``
+    program per key (algorithm, mesh, grid, pipeline depth, precision,
+    local kernel, the schedule's keyword options and the operands'
+    shapes, dtypes and shardings), so a warm call with fresh values
+    neither traces, lowers nor compiles; under an outer ``jax.jit`` the
+    program is traced into the caller's.  The blocked path's local
+    multiply closes over this call's host plans (masks, norms,
+    rank-exact steps, rebalance permutations), so it still builds and
+    runs an eager ``shard_map`` closure on every call.
+
     ``pipeline_depth`` (core/schedule.py): 2 = double-buffered
     comm/compute overlap, 1 = serial (bit-identical output), 0 = rolled
     fori_loop ablation; ``None`` takes the plan's depth under ``auto``
@@ -645,11 +738,14 @@ def distributed_matmul(
     profiler annotations ``dbcsr.multiply`` ⊃ ``dbcsr.plan``,
     ``dbcsr.stacks``, ``dbcsr.dispatch``, ``dbcsr.finish`` (plus
     ``dbcsr.verify`` ⊃ ``dbcsr.repair``), with the JAX runtime's
-    lowerings and compiles on the root and the dispatch; inactive with
-    no profiler session.  With ``obs.enable()`` active — and only then
-    — they are also recorded as spans, the dispatch waits for the
-    device, and the plan's predicted-vs-measured cost is logged for the
-    planner scoreboard.  The output is bit identical either way.
+    lowerings and compiles on the root and the dispatch, and a
+    densified dispatch's ``program_cache`` ("hit" or "miss"); inactive
+    with no profiler session.  With ``obs.enable()`` active — and only
+    then — they are also recorded as spans, the dispatch waits for the
+    device, the counters ``dispatch.program_cache.hits`` / ``.misses``
+    count densified dispatches, and the plan's predicted-vs-measured
+    cost is logged for the planner scoreboard.  The output is bit
+    identical either way.
     """
     call = dict(
         mesh=mesh, grid=grid, algorithm=algorithm, densify=densify,
@@ -720,7 +816,13 @@ def _distributed_matmul(
     synchronisation and plan-outcome log run, only when telemetry is
     on as well.  ``_finish(c, plan)``, when given, is the caller's own
     finishing of the product (``dbcsr.multiply``'s result mask and
-    wrap), run inside the finish span; its value is returned."""
+    wrap), run inside the finish span; its value is returned.
+
+    The dispatch (``_run``): densified, the cached program of
+    ``_densified_program``, which records ``program_cache`` on the
+    dispatch span; blocked, the algorithm's eager ``shard_map`` over
+    this call's local multiply, then the inverse rebalance permutation.
+    """
     _tele = _live and obs.enabled()
     m, k = a.shape
     k2, n = b.shape
@@ -907,7 +1009,7 @@ def _distributed_matmul(
 
         # ---- local multiply strategy (densified vs blocked) --------------
         if densify:
-            lm = densified_local_matmul(precision, kernel=local_kernel)
+            lm = None   # the densified program builds its own
         else:
             blocked_kw = dict(
                 block_m=block_m, block_k=block_k, block_n=block_n,
@@ -997,32 +1099,25 @@ def _distributed_matmul(
     # The dispatch is wrapped in a re-runnable closure: at a fixed
     # config the whole pipeline is deterministic, so the ABFT repair
     # path re-executes it once and splices only the flagged blocks —
-    # bitwise equal to a clean run.
+    # bitwise equal to a clean run (the densified path re-runs its
+    # cached program).
     def _run():
-        if algorithm == "cannon":
-            c = cannon_matmul(
-                a_exec, b_exec, mesh=mesh, grid=grid, local_matmul=lm,
-                precision=precision, pipeline_depth=depth, **kw)
-        elif algorithm == "cannon25d":
-            c = cannon25d_matmul(
-                a_exec, b_exec, mesh=mesh, grid=grid, local_matmul=lm,
-                precision=precision, pipeline_depth=depth, **kw)
-        elif algorithm in ("ts_k", "ts_m", "ts_n"):
-            c = tall_skinny_matmul(
-                a_exec, b_exec, mesh=mesh, grid=grid, mode=algorithm,
-                local_matmul=lm, precision=precision, pipeline_depth=depth,
-                **kw)
-        else:
-            c = summa_matmul(
-                a_exec, b_exec, mesh=mesh, grid=grid, local_matmul=lm,
-                precision=precision, pipeline_depth=depth, **kw)
+        if densify:
+            program, hit = _densified_program(
+                algorithm, a, b, mesh=mesh, grid=grid, depth=depth,
+                precision=precision, local_kernel=local_kernel, kw=kw)
+            return program(a, b), hit
+        c = _schedule_matmul(
+            algorithm, a_exec, b_exec, mesh=mesh, grid=grid,
+            local_matmul=lm, precision=precision, pipeline_depth=depth,
+            **kw)
         if rb is not None:
             from repro.sparsity.balance import (permute_block_cols,
                                                 permute_block_rows)
 
             c = permute_block_rows(c, rb.inv_m, block_m)
             c = permute_block_cols(c, rb.inv_n, block_n)
-        return c
+        return c, None
 
     sched_stats_cache = [None]
 
@@ -1043,11 +1138,17 @@ def _distributed_matmul(
                             counters=True, algorithm=algorithm,
                             densify=bool(densify),
                             pipeline_depth=depth) as dsp:
+            t0 = time.perf_counter()
+            c, hit = _run()
+            if hit is not None:
+                dsp.set(program_cache="hit" if hit else "miss")
+                if _tele:
+                    obs.counter("dispatch.program_cache."
+                                + ("hits" if hit else "misses")).inc()
             # telemetry off: enqueue and return — no timing, no sync
             if not _tele:
-                return _run()
-            t0 = time.perf_counter()
-            c = jax.block_until_ready(_run())
+                return c
+            c = jax.block_until_ready(c)
             dispatch_times.append(time.perf_counter() - t0)
         try:
             ss = _sched_stats()

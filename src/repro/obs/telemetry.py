@@ -14,9 +14,12 @@ pipeline:
       plan                         norms, masks, occupancy, planner
       stacks                       the local multiply: stack plans,
                                    per-step masks and norms
-      dispatch                     the schedule engine's call: closure,
-                                   trace, lowering, compile-cache
-                                   lookup, launch
+      dispatch                     the schedule engine's call: the
+                                   densified path's cached program
+                                   (``program_cache`` hit or miss) or
+                                   the blocked path's eager shard_map
+                                   (trace, lowering, compile-cache
+                                   lookup); launch
       verify                       ABFT checksum verification
         repair                     re-execution after a detection
           dispatch
@@ -104,7 +107,8 @@ class _Span:
     """An open span: the profiler annotation ``dbcsr.<name>``, the
     :class:`SpanRecord` under ``enable()``, and with ``counters`` the
     JAX runtime's counts over the span on both.  ``set()`` attaches
-    attrs to the record."""
+    attrs to the record and, under a profiler session, to the
+    annotation's metadata."""
 
     __slots__ = ("_name", "_cat", "_attrs", "_counters", "_ann", "_mark",
                  "_tracer", "rec")
@@ -121,6 +125,8 @@ class _Span:
     def set(self, **attrs) -> None:
         if self.rec is not None:
             self.rec.attrs.update(attrs)
+        if self._ann.is_enabled():
+            self._ann.set_metadata(**attrs)
 
     def __enter__(self) -> "_Span":
         global _ANNOTATION
@@ -142,9 +148,7 @@ class _Span:
     def __exit__(self, exc_type, exc, tb) -> bool:
         try:
             if self._mark is not None:
-                counts = runtime.since(self._mark)
-                self._ann.set_metadata(**counts)
-                self.set(**counts)
+                self.set(**runtime.since(self._mark))
             if self.rec is not None:
                 if exc_type is not None:
                     self.rec.attrs.setdefault("error", exc_type.__name__)
