@@ -117,7 +117,9 @@ def build_cannon_schedule(
     if local_shape is not None:
         ml, kl, nl = local_shape
         step_bytes = (ml * kl + kl * nl) * itemsize
-        prologue_bytes = step_bytes if (skew or step_offset) else 0
+        # a one-chip grid's skew is a permutation onto itself
+        prologue_bytes = step_bytes if (skew or step_offset) and pg > 1 \
+            else 0
 
     return Schedule(
         algorithm="cannon",

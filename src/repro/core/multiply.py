@@ -65,6 +65,7 @@ from typing import Callable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 
@@ -81,7 +82,8 @@ from .summa import (build_summa_gather_schedule, build_summa_schedule,
                     summa_gather_rank_steps, summa_matmul, summa_n_panels,
                     summa_rank_steps, summa_step_masks, summa_step_norms)
 from .tall_skinny import (build_ts_schedule, tall_skinny_matmul,
-                          ts_rank_steps, ts_step_masks, ts_step_norms)
+                          ts_rank_steps, ts_specs, ts_step_masks,
+                          ts_step_norms)
 
 __all__ = ["distributed_matmul"]
 
@@ -478,6 +480,82 @@ def _schedule_stats(algorithm: str, *, grid, mesh, local_shape, itemsize,
     }
 
 
+def _boundary_bytes(x, mesh, spec) -> Optional[int]:
+    """The most bytes any device receives when ``x`` is laid out as
+    ``spec`` on ``mesh`` at a ``shard_map``'s boundary: the elements of
+    its shard that it does not already hold, times the itemsize.  None
+    where ``x`` has no sharding to count from (a host array, a
+    tracer)."""
+    src = None if isinstance(x, jax.core.Tracer) else getattr(
+        x, "sharding", None)
+    if src is None:
+        return None
+    shape = tuple(x.shape)
+    dst = NamedSharding(mesh, spec)
+    if src.is_equivalent_to(dst, len(shape)):
+        return 0
+    held = src.devices_indices_map(shape)
+
+    def extent(index, other=None):
+        n = 1
+        for dim, s, o in zip(shape, index, other or index):
+            lo, hi, _ = s.indices(dim)
+            olo, ohi, _ = o.indices(dim)
+            n *= max(0, min(hi, ohi) - max(lo, olo))
+        return n
+
+    worst = 0
+    for device, index in dst.devices_indices_map(shape).items():
+        have = held.get(device)
+        missing = extent(index) - (0 if have is None
+                                   else extent(index, have))
+        worst = max(worst, missing)
+    return worst * jnp.dtype(x.dtype).itemsize
+
+
+def _comm_count(algorithm: str, a, b, *, mesh, grid, local_shape,
+                empty_steps=frozenset(), kw: dict) -> dict:
+    """The schedule's host-static accounting of one multiply:
+    ``comm_steps``, its collective phases (prologue, steps and epilogue
+    that move data, and the resharding of operands at the ``shard_map``
+    boundary), and ``comm_bytes``, the most bytes any chip receives over
+    them in the dtype the schedule hands its collectives, the operands'
+    (a compiler may narrow them on the wire: XLA on the TPU sends bf16
+    for a float32 product at ``Precision.DEFAULT``, half of this count).
+    ``comm_bytes`` is left out where the
+    boundary cannot be counted, never reported as 0 for a transfer that
+    happens.  Built from shapes, shardings and the schedules' own byte
+    counts alone: no pricing."""
+    itemsize = int(jnp.dtype(jnp.promote_types(a.dtype, b.dtype)).itemsize)
+    meta = schedule_step_meta(_build_meta_schedule(
+        algorithm, grid=grid, mesh=mesh, local_shape=local_shape,
+        itemsize=itemsize, empty_steps=empty_steps, reduce_kw=kw))
+    phases = [meta["prologue_comm_bytes"], *meta["step_comm_bytes"],
+              meta["epilogue_comm_bytes"]]
+    if algorithm.startswith("ts_"):
+        axes = ((grid.row_axis, grid.col_axis) if grid.stack_axis is None
+                else (grid.stack_axis, grid.row_axis, grid.col_axis))
+        specs = ts_specs(algorithm, axes,
+                         kw.get("reduce", "reduce_scatter"))[0]
+    else:
+        specs = (P(grid.row_axis, grid.col_axis),) * 2
+    boundary = [_boundary_bytes(x, mesh, spec)
+                for x, spec in zip((a, b), specs)]
+    out = {"comm_steps": sum(1 for p in phases if p) + any(boundary)}
+    if None not in boundary:
+        out["comm_bytes"] = int(sum(phases) + sum(boundary))
+    return out
+
+
+def _counted(algorithm: str, a, b, **kw) -> dict:
+    """``_comm_count``, or nothing where it fails: the accounting must
+    never break the multiply."""
+    try:
+        return _comm_count(algorithm, a, b, **kw)
+    except Exception:
+        return {}
+
+
 # ---------------------------------------------------------------------------
 # dispatch: the schedule call, and the densified path's cached programs
 # ---------------------------------------------------------------------------
@@ -506,7 +584,8 @@ def _schedule_matmul(algorithm: str, a, b, *, mesh, grid, local_matmul,
 
 
 _PROGRAM_CACHE_SIZE = 512   # as the planner's plan cache
-_programs: "collections.OrderedDict[tuple, Callable]" = \
+# key -> (program, its _comm_count)
+_programs: "collections.OrderedDict[tuple, Tuple[Callable, dict]]" = \
     collections.OrderedDict()
 _programs_lock = threading.Lock()
 
@@ -523,10 +602,13 @@ def _operand_key(x) -> tuple:
 
 def _densified_program(algorithm: str, a, b, *, mesh, grid, depth: int,
                        precision, local_kernel: Optional[str],
-                       kw: dict) -> Tuple[Callable, bool]:
+                       local_shape: tuple,
+                       kw: dict) -> Tuple[Callable, dict, bool]:
     """The densified dispatch as one ``jax.jit`` program ``(a, b) ->
-    C``, and whether it came from the cache (a hit: this key's program
-    is compiled, so the call neither traces, lowers nor compiles).
+    C``, its ``_comm_count`` (held beside it, so a warm call prices
+    nothing), and whether it came from the cache (a hit: this key's
+    program is compiled, so the call neither traces, lowers nor
+    compiles).
 
     The key is the static configuration the program depends on
     (algorithm, mesh, grid, pipeline depth, precision, local kernel and
@@ -539,10 +621,10 @@ def _densified_program(algorithm: str, a, b, *, mesh, grid, depth: int,
     key = (algorithm, mesh, grid, depth, precision, local_kernel,
            tuple(sorted(kw.items())), _operand_key(a), _operand_key(b))
     with _programs_lock:
-        program = _programs.get(key)
-        if program is not None:
+        entry = _programs.get(key)
+        if entry is not None:
             _programs.move_to_end(key)
-            return program, True
+            return (*entry, True)
     lm = densified_local_matmul(precision, kernel=local_kernel)
     options = dict(key[6])
 
@@ -551,12 +633,14 @@ def _densified_program(algorithm: str, a, b, *, mesh, grid, depth: int,
                                 local_matmul=lm, precision=precision,
                                 pipeline_depth=depth, **options)
 
-    program = jax.jit(densified_dispatch)
+    entry = (jax.jit(densified_dispatch),
+             _counted(algorithm, a, b, mesh=mesh, grid=grid,
+                      local_shape=local_shape, kw=options))
     with _programs_lock:
-        _programs[key] = program
+        _programs[key] = entry
         while len(_programs) > _PROGRAM_CACHE_SIZE:
             _programs.popitem(last=False)
-    return program, False
+    return (*entry, False)
 
 
 def _verified_result(verify, a, b, c, rerun, *, plan, block_m, block_k,
@@ -738,14 +822,19 @@ def distributed_matmul(
     profiler annotations ``dbcsr.multiply`` ⊃ ``dbcsr.plan``,
     ``dbcsr.stacks``, ``dbcsr.dispatch``, ``dbcsr.finish`` (plus
     ``dbcsr.verify`` ⊃ ``dbcsr.repair``), with the JAX runtime's
-    lowerings and compiles on the root and the dispatch, and a
-    densified dispatch's ``program_cache`` ("hit" or "miss"); inactive
-    with no profiler session.  With ``obs.enable()`` active — and only
-    then — they are also recorded as spans, the dispatch waits for the
-    device, the counters ``dispatch.program_cache.hits`` / ``.misses``
-    count densified dispatches, and the plan's predicted-vs-measured
-    cost is logged for the planner scoreboard.  The output is bit
-    identical either way.
+    lowerings and compiles on the root and the dispatch, a densified
+    dispatch's ``program_cache`` ("hit" or "miss"), and the dispatch's
+    ``comm_steps`` and ``comm_bytes`` (``_comm_count``: the collective
+    phases and the most bytes any chip receives, held beside a
+    densified program, counted per call on the blocked path only when
+    a profiler session or telemetry takes it); inactive with no
+    profiler session.  With ``obs.enable()`` active — and only then —
+    they are also recorded as spans, the dispatch waits for the
+    device, the counters
+    ``dispatch.program_cache.hits`` / ``.misses`` count densified
+    dispatches and ``schedule.comm_bytes`` sums ``comm_bytes``, and the
+    plan's predicted-vs-measured cost is logged for the planner
+    scoreboard.  The output is bit identical either way.
     """
     call = dict(
         mesh=mesh, grid=grid, algorithm=algorithm, densify=densify,
@@ -1103,10 +1192,11 @@ def _distributed_matmul(
     # cached program).
     def _run():
         if densify:
-            program, hit = _densified_program(
+            program, comm, hit = _densified_program(
                 algorithm, a, b, mesh=mesh, grid=grid, depth=depth,
-                precision=precision, local_kernel=local_kernel, kw=kw)
-            return program(a, b), hit
+                precision=precision, local_kernel=local_kernel,
+                local_shape=(ml, kl, nl), kw=kw)
+            return program(a, b), hit, comm
         c = _schedule_matmul(
             algorithm, a_exec, b_exec, mesh=mesh, grid=grid,
             local_matmul=lm, precision=precision, pipeline_depth=depth,
@@ -1117,7 +1207,7 @@ def _distributed_matmul(
 
             c = permute_block_rows(c, rb.inv_m, block_m)
             c = permute_block_cols(c, rb.inv_n, block_n)
-        return c, None
+        return c, None, None
 
     sched_stats_cache = [None]
 
@@ -1139,23 +1229,31 @@ def _distributed_matmul(
                             densify=bool(densify),
                             pipeline_depth=depth) as dsp:
             t0 = time.perf_counter()
-            c, hit = _run()
+            c, hit, comm = _run()
+            if comm is None and dsp.recording():
+                # the blocked path counts this call's schedule, and only
+                # where a record or a profiler session takes the count
+                comm = _counted(
+                    algorithm, a_exec, b_exec, mesh=mesh, grid=grid,
+                    local_shape=(ml, kl, nl),
+                    empty_steps=getattr(lm, "empty_steps", frozenset()),
+                    kw=kw)
+            if comm:
+                dsp.set(**comm)
             if hit is not None:
                 dsp.set(program_cache="hit" if hit else "miss")
-                if _tele:
+            if _tele:
+                if hit is not None:
                     obs.counter("dispatch.program_cache."
                                 + ("hits" if hit else "misses")).inc()
+                if comm and "comm_bytes" in comm:
+                    obs.counter("schedule.comm_bytes").inc(
+                        comm["comm_bytes"])
             # telemetry off: enqueue and return — no timing, no sync
             if not _tele:
                 return c
             c = jax.block_until_ready(c)
             dispatch_times.append(time.perf_counter() - t0)
-        try:
-            ss = _sched_stats()
-        except Exception:
-            ss = None  # telemetry must never break the multiply
-        if ss is not None:
-            dsp.set(comm_bytes=int(ss.get("total_comm_bytes", 0)))
         return c
 
     c = _run_traced()

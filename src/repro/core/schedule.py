@@ -65,6 +65,13 @@ ablation form.
 Empty steps: the compute (and, via ``recv`` skipping, the broadcast)
 of an empty step is elided, but ``shift`` still runs — later Cannon
 steps need the rotated operands.
+
+Names: ``execute_schedule`` opens ``jax.named_scope("dbcsr.skew")``
+around the prologue, ``dbcsr.shift`` around every ``shift`` and
+``recv`` and ``dbcsr.reduce`` around the epilogue, so that under
+``jax.jit`` the collectives' ``op_name`` in the compiled program and
+the profiler's trace says which phase sent them.  A scope is metadata only: the
+ops, their order and the output are the same.
 """
 from __future__ import annotations
 
@@ -84,6 +91,8 @@ __all__ = [
 ]
 
 DEFAULT_PIPELINE_DEPTH = 2
+# the named scopes of the schedule's communication phases
+SKEW, SHIFT, REDUCE = "dbcsr.skew", "dbcsr.shift", "dbcsr.reduce"
 
 
 def _identity_prologue(a, b):
@@ -188,7 +197,8 @@ def execute_schedule(
         # recv offsets) cannot roll either
         depth = 1
 
-    carry = sched.prologue(a_blk, b_blk)
+    with jax.named_scope(SKEW):
+        carry = sched.prologue(a_blk, b_blk)
     # accumulator shape generalizes over leading batch dims: (m, n) for
     # one product, (G, m, n) for a fused product batch (the batched
     # multiply stacks G local operands as (G, ml, kl) x (G, kl, nl))
@@ -201,12 +211,15 @@ def execute_schedule(
 
         def body(_, loop_carry):
             inner, c_c = loop_carry
-            a_c, b_c = sched.recv(inner, 0)
+            with jax.named_scope(SHIFT):
+                a_c, b_c = sched.recv(inner, 0)
             c_c = c_c + local_matmul(a_c, b_c).astype(accum_dtype)
-            return rolled.shift(inner), c_c
+            with jax.named_scope(SHIFT):
+                inner = rolled.shift(inner)
+            return inner, c_c
 
         _, c = jax.lax.fori_loop(0, n, body, (carry, c))
-        return sched.epilogue(c).astype(out_dtype)
+        return _reduce(sched, c).astype(out_dtype)
 
     def compute(ops, t):
         a_t, b_t = ops
@@ -214,16 +227,25 @@ def execute_schedule(
                 else local_matmul(a_t, b_t))
         return part
 
-    ops = None if 0 in empty else sched.recv(carry, 0)
+    def advance(carry, t):
+        # the communication feeding step t + 1: the carry's shift, then
+        # that step's operands unless it is empty
+        with jax.named_scope(SHIFT):
+            nxt = sched.shift(carry, t)
+            return nxt, (None if t + 1 in empty else sched.recv(nxt, t + 1))
+
+    if 0 in empty:
+        ops = None
+    else:
+        with jax.named_scope(SHIFT):
+            ops = sched.recv(carry, 0)
     for t in range(n):
         nxt_carry = nxt_ops = None
         if depth >= 2 and t + 1 < n:
             # software double buffering: issue step t+1's communication
             # before step t's multiply so XLA overlaps the collective
             # with the compute
-            nxt_carry = sched.shift(carry, t)
-            if (t + 1) not in empty:
-                nxt_ops = sched.recv(nxt_carry, t + 1)
+            nxt_carry, nxt_ops = advance(carry, t)
         if t not in empty:
             part = compute(ops, t)
             if part is not None:
@@ -231,11 +253,14 @@ def execute_schedule(
         if t + 1 < n:
             if depth < 2:
                 # serial: all communication strictly after the multiply
-                nxt_carry = sched.shift(carry, t)
-                if (t + 1) not in empty:
-                    nxt_ops = sched.recv(nxt_carry, t + 1)
+                nxt_carry, nxt_ops = advance(carry, t)
             carry, ops = nxt_carry, nxt_ops
-    return sched.epilogue(c).astype(out_dtype)
+    return _reduce(sched, c).astype(out_dtype)
+
+
+def _reduce(sched: Schedule, c: jax.Array) -> jax.Array:
+    with jax.named_scope(REDUCE):
+        return sched.epilogue(c)
 
 
 def schedule_step_meta(sched: Schedule) -> dict:

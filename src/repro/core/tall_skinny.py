@@ -35,7 +35,8 @@ from .blocking import GridSpec
 from .cannon import _default_local_matmul
 from .schedule import Schedule, execute_schedule, resolve_pipeline_depth
 
-__all__ = ["tall_skinny_matmul", "build_ts_schedule", "ts_step_masks",
+__all__ = ["tall_skinny_matmul", "build_ts_schedule", "ts_specs",
+           "ts_step_masks",
            "ts_step_norms", "ts_rank_steps", "classify_shape",
            "ts_classify_ratio", "DEFAULT_TS_RATIO"]
 
@@ -312,16 +313,20 @@ def tall_skinny_matmul(
                                 out_dtype=out_dtype, pipeline_depth=depth,
                                 accum_dtype=accum)
 
-    if mode == "ts_m":
-        # zero-communication: shard the tall output dimension
-        in_specs = (P(axes, None), P(None, None))
-        out_spec = P(axes, None)
-    elif mode == "ts_n":
-        in_specs = (P(None, None), P(None, axes))
-        out_spec = P(None, axes)
-    else:  # ts_k
-        in_specs = (P(None, axes), P(axes, None))
-        out_spec = P(None, None) if reduce == "all_reduce" else P(axes, None)
+    in_specs, out_spec = ts_specs(mode, axes, reduce)
     fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_spec, check_vma=False)
     return fn(a, b)
+
+
+def ts_specs(mode: str, axes, reduce: str = "reduce_scatter"):
+    """``((A spec, B spec), C spec)`` of a tall-and-skinny variant's
+    ``shard_map``: operands arriving in another layout are resharded to
+    these at its boundary."""
+    if mode == "ts_m":
+        # zero-communication: shard the tall output dimension
+        return (P(axes, None), P(None, None)), P(axes, None)
+    if mode == "ts_n":
+        return (P(None, None), P(None, axes)), P(None, axes)
+    return ((P(None, axes), P(axes, None)),
+            P(None, None) if reduce == "all_reduce" else P(axes, None))

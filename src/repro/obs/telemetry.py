@@ -128,6 +128,11 @@ class _Span:
         if self._ann.is_enabled():
             self._ann.set_metadata(**attrs)
 
+    def recording(self) -> bool:
+        """Whether ``set()`` lands anywhere: a record under ``enable()``
+        or the annotation of a running profiler session."""
+        return self.rec is not None or self._ann.is_enabled()
+
     def __enter__(self) -> "_Span":
         global _ANNOTATION
         if _ANNOTATION is None:
@@ -166,6 +171,9 @@ class _NoopSpan:
 
     def set(self, **attrs) -> None:
         pass
+
+    def recording(self) -> bool:
+        return False
 
     def __enter__(self) -> "_NoopSpan":
         return self
