@@ -130,6 +130,9 @@ def build_cannon_schedule(
         rolled=RolledSpec(shift=rolled_shift),
         comm_op=f"ppermute(a:{col_axis}, b:{row_axis})",
         prologue_comm_bytes=prologue_bytes,
+        # whole-block ppermutes act on A's rows and B's columns alone;
+        # a one-chip grid's prologue moves nothing
+        row_col_panels=bool(skew or step_offset) and pg > 1,
         # the final step receives no shift: n_steps - 1 shifts total
         step_comm_bytes=tuple(
             step_bytes if t + 1 < n_steps else 0 for t in range(n_steps)),

@@ -113,6 +113,8 @@ def build_cannon25d_schedule(
         epilogue=epilogue,
         prologue_comm_bytes=prologue_bytes,
         epilogue_comm_bytes=epilogue_bytes,
+        # the fused skew is a whole-block ppermute, as are the shifts
+        row_col_panels=pg > 1,
     )
 
 

@@ -124,6 +124,10 @@ def densified_local_matmul(precision=jax.lax.Precision.DEFAULT,
             return jax.lax.dot(a, b, precision=precision,
                                preferred_element_type=jnp.float32)
 
+    # a product of A's row panels and B's column panels is that block
+    # of the product, so the schedule may run it on row/column panels
+    # (schedule.skew_panels)
+    f.row_col_panels = True
     return f
 
 

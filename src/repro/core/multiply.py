@@ -75,7 +75,8 @@ from .cannon import (build_cannon_schedule, cannon_matmul, cannon_rank_steps,
 from .cannon25d import build_cannon25d_schedule, cannon25d_matmul
 from .densify import blocked_local_matmul, densified_local_matmul
 from .engine import rank_stack_executor
-from .schedule import resolve_pipeline_depth, schedule_step_meta
+from .schedule import (Schedule, resolve_pipeline_depth, schedule_step_meta,
+                       skew_panels)
 from .stacks import normalize_block_masks
 from .summa import (build_summa_gather_schedule, build_summa_schedule,
                     summa_gather_masks, summa_gather_norms,
@@ -514,7 +515,8 @@ def _boundary_bytes(x, mesh, spec) -> Optional[int]:
 
 
 def _comm_count(algorithm: str, a, b, *, mesh, grid, local_shape,
-                empty_steps=frozenset(), kw: dict) -> dict:
+                empty_steps=frozenset(), kw: dict,
+                sched: Optional[Schedule] = None) -> dict:
     """The schedule's host-static accounting of one multiply:
     ``comm_steps``, its collective phases (prologue, steps and epilogue
     that move data, and the resharding of operands at the ``shard_map``
@@ -525,11 +527,14 @@ def _comm_count(algorithm: str, a, b, *, mesh, grid, local_shape,
     ``comm_bytes`` is left out where the
     boundary cannot be counted, never reported as 0 for a transfer that
     happens.  Built from shapes, shardings and the schedules' own byte
-    counts alone: no pricing."""
-    itemsize = int(jnp.dtype(jnp.promote_types(a.dtype, b.dtype)).itemsize)
-    meta = schedule_step_meta(_build_meta_schedule(
-        algorithm, grid=grid, mesh=mesh, local_shape=local_shape,
-        itemsize=itemsize, empty_steps=empty_steps, reduce_kw=kw))
+    counts alone: no pricing.  ``sched`` is the meta schedule
+    (``_build_meta_schedule``) where the caller has built it."""
+    if sched is None:
+        sched = _build_meta_schedule(
+            algorithm, grid=grid, mesh=mesh, local_shape=local_shape,
+            itemsize=_itemsize(a, b), empty_steps=empty_steps,
+            reduce_kw=kw)
+    meta = schedule_step_meta(sched)
     phases = [meta["prologue_comm_bytes"], *meta["step_comm_bytes"],
               meta["epilogue_comm_bytes"]]
     if algorithm.startswith("ts_"):
@@ -545,6 +550,10 @@ def _comm_count(algorithm: str, a, b, *, mesh, grid, local_shape,
     if None not in boundary:
         out["comm_bytes"] = int(sum(phases) + sum(boundary))
     return out
+
+
+def _itemsize(a, b) -> int:
+    return int(jnp.dtype(jnp.promote_types(a.dtype, b.dtype)).itemsize)
 
 
 def _counted(algorithm: str, a, b, **kw) -> dict:
@@ -584,7 +593,7 @@ def _schedule_matmul(algorithm: str, a, b, *, mesh, grid, local_matmul,
 
 
 _PROGRAM_CACHE_SIZE = 512   # as the planner's plan cache
-# key -> (program, its _comm_count)
+# key -> (program, its _comm_count and skew_panels)
 _programs: "collections.OrderedDict[tuple, Tuple[Callable, dict]]" = \
     collections.OrderedDict()
 _programs_lock = threading.Lock()
@@ -605,8 +614,9 @@ def _densified_program(algorithm: str, a, b, *, mesh, grid, depth: int,
                        local_shape: tuple,
                        kw: dict) -> Tuple[Callable, dict, bool]:
     """The densified dispatch as one ``jax.jit`` program ``(a, b) ->
-    C``, its ``_comm_count`` (held beside it, so a warm call prices
-    nothing), and whether it came from the cache (a hit: this key's
+    C``, its ``_comm_count`` with the schedule's ``skew_panels``, from
+    one meta schedule (held beside it, so a warm call prices nothing),
+    and whether it came from the cache (a hit: this key's
     program is compiled, so the call neither traces, lowers nor
     compiles).
 
@@ -633,9 +643,14 @@ def _densified_program(algorithm: str, a, b, *, mesh, grid, depth: int,
                                 local_matmul=lm, precision=precision,
                                 pipeline_depth=depth, **options)
 
+    sched = _build_meta_schedule(
+        algorithm, grid=grid, mesh=mesh, local_shape=local_shape,
+        itemsize=_itemsize(a, b), empty_steps=frozenset(), reduce_kw=options)
+    ml, _, nl = local_shape
     entry = (jax.jit(densified_dispatch),
-             _counted(algorithm, a, b, mesh=mesh, grid=grid,
-                      local_shape=local_shape, kw=options))
+             {**_counted(algorithm, a, b, mesh=mesh, grid=grid,
+                         local_shape=local_shape, kw=options, sched=sched),
+              "skew_panels": skew_panels(sched, lm, ml, nl, depth)})
     with _programs_lock:
         _programs[key] = entry
         while len(_programs) > _PROGRAM_CACHE_SIZE:
@@ -827,10 +842,12 @@ def distributed_matmul(
     ``comm_steps`` and ``comm_bytes`` (``_comm_count``: the collective
     phases and the most bytes any chip receives, held beside a
     densified program, counted per call on the blocked path only when
-    a profiler session or telemetry takes it); inactive with no
-    profiler session.  With ``obs.enable()`` active — and only then —
-    they are also recorded as spans, the dispatch waits for the
-    device, the counters
+    a profiler session or telemetry takes it), and its ``skew_panels``
+    (``schedule.skew_panels``: 2 where the schedule runs its skew and
+    steps on row/column panels, else 1; held beside a densified
+    program); inactive with no profiler session.  With
+    ``obs.enable()`` active — and only then — they are also recorded as
+    spans, the dispatch waits for the device, the counters
     ``dispatch.program_cache.hits`` / ``.misses`` count densified
     dispatches and ``schedule.comm_bytes`` sums ``comm_bytes``, and the
     plan's predicted-vs-measured cost is logged for the planner
@@ -1240,6 +1257,9 @@ def _distributed_matmul(
                     kw=kw)
             if comm:
                 dsp.set(**comm)
+            if hit is None:
+                # a blocked multiply's stack plans span the whole blocks
+                dsp.set(skew_panels=1)
             if hit is not None:
                 dsp.set(program_cache="hit" if hit else "miss")
             if _tele:

@@ -55,12 +55,30 @@ ablation form.
                        Bit-identical output (the same float ops on the
                        same values in the same accumulation order);
                        only the issue order — and therefore the
-                       overlap — changes.
+                       overlap — changes.  Where depth 2 panels the
+                       prologue (below), it is allclose to depth 1.
   pipeline_depth = 0   rolled ``fori_loop`` form (smaller HLO, no
                        overlap) where the schedule provides a ``rolled``
                        spec; falls back to depth 1 otherwise.  Kept for
                        the HLO-size ablation (the legacy
                        ``double_buffer=False``).
+
+Panelled prologue (depth 2): Cannon's skew is a transfer that step 0's
+multiply waits on, and no double buffering reaches it.  Where the
+schedule's prologue moves bytes between chips and, like its shifts and
+receives, acts on A's rows and B's columns alone (``Schedule.
+row_col_panels``: Cannon, Cannon-2.5D), the local multiply is a plain
+product (``local_matmul.row_col_panels``: the densified dot) and the
+local blocks are large enough (``skew_panels``), ``execute_schedule``
+runs the schedule on two panels: A's upper rows with B's left columns,
+and A's lower rows with B's right columns.  Each step is then four
+products, one per quarter of C, in the order (0, 0), (0, 1), (1, 0),
+(1, 1): the first needs only panel 0, so panel 1's skew moves under it.
+C is assembled from its quarters once, after the last step.  Every
+element of C is the same sum in the same step order, so the output is
+allclose to one panel's, and bitwise the same where the backend's dot
+blocks a quarter's product as it blocks the whole (the v5e does at the
+2x2 benchmark cell's shape; the CPU does not at every shape).
 
 Empty steps: the compute (and, via ``recv`` skipping, the broadcast)
 of an empty step is elided, but ``shift`` still runs — later Cannon
@@ -88,11 +106,23 @@ __all__ = [
     "execute_schedule",
     "resolve_pipeline_depth",
     "schedule_step_meta",
+    "skew_panels",
 ]
 
 DEFAULT_PIPELINE_DEPTH = 2
 # the named scopes of the schedule's communication phases
 SKEW, SHIFT, REDUCE = "dbcsr.skew", "dbcsr.shift", "dbcsr.reduce"
+# The least local rows of A and columns of B for which ``skew_panels``
+# panels the prologue: the smallest square local block at which it was
+# measured to pay.  Its gain is the half of the skew that moves under
+# the first quarter's product, its cost the pass that assembles C; on a
+# v5e 2x2 host, program alone, it saved 2.7%, 5.3% and 4.1% a call at
+# local blocks of 16,896, 8,448 and 5,632, and smaller blocks were not
+# measured
+SKEW_PANEL_MIN = 5632
+# the panels split at a multiple of the lane width, so each quarter of
+# C stays aligned to the chip's (8, 128) tiles
+SKEW_PANEL_ALIGN = 128
 
 
 def _identity_prologue(a, b):
@@ -146,9 +176,31 @@ class Schedule:
     prologue_comm_bytes: int = 0
     step_comm_bytes: Tuple[int, ...] = ()  # per-step estimate, len n_steps
     epilogue_comm_bytes: int = 0
+    # the prologue moves bytes between chips, and prologue, shift and
+    # recv each map slices of A's rows and B's columns to the same
+    # slices of their results, with the carry the pair (A, B): so
+    # ``execute_schedule`` may run the schedule on row/column panels
+    # (``skew_panels``)
+    row_col_panels: bool = False
 
     def replace(self, **kw) -> "Schedule":
         return dataclasses.replace(self, **kw)
+
+
+def skew_panels(sched: Schedule, local_matmul: Callable, ml: int, nl: int,
+                pipeline_depth: int) -> int:
+    """How many row/column panels ``execute_schedule`` runs the schedule
+    on: 2 at depth 2 where the schedule's prologue moves bytes and both
+    the schedule and the local multiply can be sliced by A's rows and
+    B's columns, no step is empty, and the local blocks hold at least
+    ``SKEW_PANEL_MIN`` rows of A and columns of B; else 1, the one-panel
+    program."""
+    if (pipeline_depth >= 2 and sched.row_col_panels
+            and not sched.empty_steps
+            and getattr(local_matmul, "row_col_panels", False)
+            and min(ml, nl) >= SKEW_PANEL_MIN):
+        return 2
+    return 1
 
 
 def resolve_pipeline_depth(pipeline_depth: Optional[int],
@@ -196,6 +248,10 @@ def execute_schedule(
         # cannot express; schedules without a rolled spec (per-step
         # recv offsets) cannot roll either
         depth = 1
+    if skew_panels(sched, local_matmul, a_blk.shape[-2], b_blk.shape[-1],
+                   depth) > 1:
+        return _execute_panelled(sched, a_blk, b_blk, local_matmul,
+                                 out_dtype, accum_dtype)
 
     with jax.named_scope(SKEW):
         carry = sched.prologue(a_blk, b_blk)
@@ -256,6 +312,55 @@ def execute_schedule(
                 nxt_carry, nxt_ops = advance(carry, t)
             carry, ops = nxt_carry, nxt_ops
     return _reduce(sched, c).astype(out_dtype)
+
+
+def _execute_panelled(sched: Schedule, a_blk, b_blk, local_matmul,
+                      out_dtype, accum_dtype) -> jax.Array:
+    """``execute_schedule`` at depth 2 on two row/column panels (the
+    module docstring): the prologue, shifts and receives run once per
+    panel, each step runs the four quarters' products, and C is
+    assembled from its quarters after the last step."""
+    hm = _split(a_blk.shape[-2])
+    hn = _split(b_blk.shape[-1])
+    with jax.named_scope(SKEW):
+        carries = [sched.prologue(a_blk[..., :hm, :], b_blk[..., :hn]),
+                   sched.prologue(a_blk[..., hm:, :], b_blk[..., hn:])]
+
+    with jax.named_scope(SHIFT):
+        ops = [sched.recv(carry, 0) for carry in carries]
+    quarters, last = {}, None
+    for t in range(sched.n_steps):
+        if t + 1 < sched.n_steps:
+            # as at depth 2: step t+1's communication before step t
+            with jax.named_scope(SHIFT):
+                carries = [sched.shift(carry, t) for carry in carries]
+                nxt_ops = [sched.recv(carry, t + 1) for carry in carries]
+        for i, j in ((0, 0), (0, 1), (1, 0), (1, 1)):
+            a_i, b_j = ops[i][0], ops[j][1]
+            if last is not None:
+                # each product waits for the one before it, so XLA keeps
+                # this order (else it may run a product that needs panel
+                # 1 first, with the skew exposed) and fuses each add
+                # into its own product's dot
+                quarters[last], a_i, b_j = jax.lax.optimization_barrier(
+                    (quarters[last], a_i, b_j))
+            part = local_matmul(a_i, b_j).astype(accum_dtype)
+            quarters[i, j] = (part + quarters[i, j] if (i, j) in quarters
+                              else part)
+            last = (i, j)
+        if t + 1 < sched.n_steps:
+            ops = nxt_ops
+    c = jnp.concatenate(
+        [jnp.concatenate([quarters[i, 0], quarters[i, 1]], axis=-1)
+         for i in (0, 1)], axis=-2)
+    return _reduce(sched, c).astype(out_dtype)
+
+
+def _split(n: int) -> int:
+    """Where a panelled prologue splits ``n`` rows or columns: about
+    half, at a multiple of ``SKEW_PANEL_ALIGN``."""
+    return max(SKEW_PANEL_ALIGN,
+               round(n / 2 / SKEW_PANEL_ALIGN) * SKEW_PANEL_ALIGN)
 
 
 def _reduce(sched: Schedule, c: jax.Array) -> jax.Array:

@@ -477,6 +477,14 @@ def candidate_cost(
         overlappable = comm_bytes
         messages = 1
         mem = (ml * kl + kl * nl + ml * nl) * e
+    # imported here: repro.core imports this module
+    from repro.core.schedule import SKEW_PANEL_MIN
+    if (algorithm in ("cannon", "cannon25d") and densify
+            and pipeline_depth >= 2 and prob.pr > 1
+            and min(ml, nl) >= SKEW_PANEL_MIN):
+        # the panelled program (core/schedule.py, skew_panels) holds C's
+        # float32 quarters beside the C they are assembled into
+        mem += ml * nl * 4.0
 
     comm_s = comm_bytes / hw.bytes_per_s
     overhead_s += messages * hw.latency_s
