@@ -5,16 +5,12 @@
 """
 from .blocking import BlockLayout, GridSpec
 from .multiply import distributed_matmul
-from .cannon import (cannon_matmul, build_cannon_schedule,
-                     cannon_step_masks, cannon_step_norms)
+from .cannon import cannon_matmul, build_cannon_schedule
 from .cannon25d import cannon25d_matmul, build_cannon25d_schedule
 from .tall_skinny import (tall_skinny_matmul, build_ts_schedule,
-                          ts_step_masks, ts_step_norms, classify_shape,
-                          ts_classify_ratio, DEFAULT_TS_RATIO)
+                          classify_shape, ts_classify_ratio, DEFAULT_TS_RATIO)
 from .summa import (summa_matmul, build_summa_schedule,
-                    build_summa_gather_schedule, summa_step_masks,
-                    summa_gather_masks, summa_step_norms,
-                    summa_gather_norms)
+                    build_summa_gather_schedule)
 from .schedule import (Schedule, execute_schedule, DEFAULT_PIPELINE_DEPTH,
                        resolve_pipeline_depth)
 from .densify import densify, undensify, to_blocks, from_blocks
@@ -33,7 +29,4 @@ __all__ = [
     "resolve_pipeline_depth", "build_cannon_schedule",
     "build_cannon25d_schedule", "build_summa_schedule",
     "build_summa_gather_schedule", "build_ts_schedule",
-    "cannon_step_masks", "summa_step_masks", "summa_gather_masks",
-    "ts_step_masks", "cannon_step_norms", "summa_step_norms",
-    "summa_gather_norms", "ts_step_norms",
 ]

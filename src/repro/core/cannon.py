@@ -18,8 +18,9 @@ TPU adaptation notes (see DESIGN.md §2):
 
 This module is a pure *schedule builder* plus the shard_map wrapper:
 ``build_cannon_schedule`` emits the step sequence (skew prologue,
-identity recv, neighbour-shift carry update), ``cannon_step_masks``
-emits the per-step occupancy-mask slices, and the unified driver
+identity recv, neighbour-shift carry update), ``cannon_step_map`` says
+which A and B chunks each rank holds at each step (the blocked path
+plans its stacks from it, core/steps.py), and the unified driver
 (``schedule.execute_schedule``) runs the loop.
 
 The local multiply is pluggable (``local_matmul``): ``densified`` uses a
@@ -29,19 +30,19 @@ analogue).
 """
 from __future__ import annotations
 
-from typing import Callable, List, Optional
+import functools
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .blocking import GridSpec
 from .schedule import (RolledSpec, Schedule, execute_schedule,
                        resolve_pipeline_depth)
+from .steps import StepMap
 
-__all__ = ["cannon_matmul", "build_cannon_schedule", "cannon_step_masks",
-           "cannon_step_norms", "cannon_rank_steps"]
+__all__ = ["cannon_matmul", "build_cannon_schedule", "cannon_step_map"]
 
 
 def _skew_perm(pg: int, which: str):
@@ -139,112 +140,17 @@ def build_cannon_schedule(
     )
 
 
-def cannon_step_masks(
-    am: np.ndarray, bm: np.ndarray, pg: int, c_repl: int = 1,
-) -> List[np.ndarray]:
-    """Per-shift-step local pair-presence tensors for (2.5D) Cannon —
-    the schedule builder's per-step mask slices.
-
-    At inner step t, device (i, j) of replica p holds the A chunk
-    (i, q) and B chunk (q, j) with q = (i + j + p*spr + t) % pg.  The
-    returned (nbr_l, nbk_l, nbc_l) tensor for step t is the union over
-    all (p, i, j) of that rank's chunk-product presence — the tightest
-    plan every rank can share under SPMD.  Block-structured sparsity
-    (banded / block-diagonal operands) makes whole steps empty here,
-    which the schedule driver then skips.
-    """
-    nbr, nbk = am.shape
-    nbc = bm.shape[1]
-    if nbr % pg or nbk % pg or nbc % pg:
-        raise ValueError(
-            f"block grid ({nbr},{nbk},{nbc}) not divisible by cannon grid "
-            f"side {pg}")
-    if c_repl < 1 or pg % c_repl:
-        raise ValueError(f"grid side {pg} not divisible by replication {c_repl}")
-    lr, lk, lc = nbr // pg, nbk // pg, nbc // pg
-    spr = pg // c_repl  # shift steps each replica executes
-    out = []
-    for t in range(spr):
-        pair = np.zeros((lr, lk, lc), dtype=bool)
-        for p in range(c_repl):
-            off = t + p * spr
-            for i in range(pg):
-                for j in range(pg):
-                    q = (i + j + off) % pg
-                    ac = am[i * lr:(i + 1) * lr, q * lk:(q + 1) * lk]
-                    if not ac.any():
-                        continue
-                    bc = bm[q * lk:(q + 1) * lk, j * lc:(j + 1) * lc]
-                    pair |= ac[:, :, None] & bc[None, :, :]
-        out.append(pair)
-    return out
-
-
-def cannon_step_norms(
-    an: np.ndarray, bn: np.ndarray, pg: int, c_repl: int = 1,
-) -> List[np.ndarray]:
-    """Per-shift-step local pair NORM-PRODUCT tensors for (2.5D) Cannon
-    — the norm twin of ``cannon_step_masks`` for the on-the-fly filter
-    (repro.sparsity).
-
-    Where the mask builder unions per-rank *presence* (SPMD: the step
-    plan must cover every rank), the norm builder takes the per-rank
-    MAX of ``norm(A_ik) * norm(B_kj)`` — union-of-max.  A triple is
-    then dropped by ``filter_eps`` only when it falls below eps on
-    EVERY rank sharing the traced program: the tightest SPMD-uniform
-    filter, conservative in exactly the way the mask union is.
-    """
-    nbr, nbk = an.shape
-    nbc = bn.shape[1]
-    if nbr % pg or nbk % pg or nbc % pg:
-        raise ValueError(
-            f"block grid ({nbr},{nbk},{nbc}) not divisible by cannon grid "
-            f"side {pg}")
-    if c_repl < 1 or pg % c_repl:
-        raise ValueError(f"grid side {pg} not divisible by replication {c_repl}")
-    an = np.asarray(an, dtype=np.float32)
-    bn = np.asarray(bn, dtype=np.float32)
-    lr, lk, lc = nbr // pg, nbk // pg, nbc // pg
-    spr = pg // c_repl
-    out = []
-    for t in range(spr):
-        pair = np.zeros((lr, lk, lc), dtype=np.float32)
-        for p in range(c_repl):
-            off = t + p * spr
-            for i in range(pg):
-                for j in range(pg):
-                    q = (i + j + off) % pg
-                    ac = an[i * lr:(i + 1) * lr, q * lk:(q + 1) * lk]
-                    if not ac.any():
-                        continue
-                    bc = bn[q * lk:(q + 1) * lk, j * lc:(j + 1) * lc]
-                    np.maximum(pair, ac[:, :, None] * bc[None, :, :],
-                               out=pair)
-        out.append(pair)
-    return out
-
-
-def cannon_rank_steps(
-    am: np.ndarray, bm: np.ndarray, pg: int, c_repl: int = 1,
-    a_norms: Optional[np.ndarray] = None,
-    b_norms: Optional[np.ndarray] = None,
-) -> List[List[dict]]:
-    """Rank-exact twin of ``cannon_step_masks``/``cannon_step_norms``:
-    per step, per RANK local mask (and norm) kwargs instead of the
-    union over ranks.
-
-    ``out[t][r]`` is the mask/norm kwarg dict for the rank with flat
-    index ``r = (p * pg + i) * pg + j`` (stack-major, matching
-    ``cannon25d._skew25d_perm``; plain Cannon is the ``c_repl == 1``
-    slice ``r = i * pg + j``) at inner shift step ``t`` — the exact A
-    chunk ``(i, q)`` x B chunk ``(q, j)`` with
-    ``q = (i + j + t + p*spr) % pg``.  The factored ``a_mask``/
-    ``b_mask`` form is exact per rank (no cross-rank union), and the
-    norms are the rank's own chunk norms — eps filtering against them
-    is DBCSR's true local filter rather than the union-of-max bound.
-    """
-    nbr, nbk = am.shape
-    nbc = bm.shape[1]
+@functools.lru_cache(maxsize=256)
+def cannon_step_map(nbr: int, nbk: int, nbc: int, pg: int,
+                    c_repl: int = 1) -> StepMap:
+    """The step map (core/steps.py) of (2.5D) Cannon on a ``pg`` x
+    ``pg`` grid over an (nbr, nbk, nbc) block grid: at inner step t,
+    rank ``(p*pg + i)*pg + j`` (replica p of ``c_repl``; plain Cannon is
+    ``p = 0``) holds A chunk (i, q) and B chunk (q, j) with
+    ``q = (i + j + p*spr + t) % pg``, ``spr = pg // c_repl`` the steps
+    each replica runs — where the skew (``_skew_perm``, or
+    ``cannon25d._skew25d_perm``) and t shifts (``_shift_perm``) bring
+    them."""
     if nbr % pg or nbk % pg or nbc % pg:
         raise ValueError(
             f"block grid ({nbr},{nbk},{nbc}) not divisible by cannon grid "
@@ -253,26 +159,11 @@ def cannon_rank_steps(
         raise ValueError(f"grid side {pg} not divisible by replication {c_repl}")
     lr, lk, lc = nbr // pg, nbk // pg, nbc // pg
     spr = pg // c_repl
-    if a_norms is not None:
-        a_norms = np.asarray(a_norms, dtype=np.float32)
-        b_norms = np.asarray(b_norms, dtype=np.float32)
-    steps: List[List[dict]] = []
-    for t in range(spr):
-        ranks: List[dict] = []
-        for p in range(c_repl):
-            for i in range(pg):
-                rs = slice(i * lr, (i + 1) * lr)
-                for j in range(pg):
-                    q = (i + j + t + p * spr) % pg
-                    ks = slice(q * lk, (q + 1) * lk)
-                    cs = slice(j * lc, (j + 1) * lc)
-                    kw = {"a_mask": am[rs, ks], "b_mask": bm[ks, cs]}
-                    if a_norms is not None:
-                        kw["a_norms"] = a_norms[rs, ks]
-                        kw["b_norms"] = b_norms[ks, cs]
-                    ranks.append(kw)
-        steps.append(ranks)
-    return steps
+    return StepMap.of(
+        [[(i * lr, (i + j + p * spr + t) % pg * lk, j * lc)
+          for p in range(c_repl) for i in range(pg) for j in range(pg)]
+         for t in range(spr)],
+        (lr, lk, lc), pair=True)
 
 
 def _default_local_matmul(precision):

@@ -22,33 +22,32 @@ bit-identical output.
 
 Occupancy threading (blocked path): ``a_mask`` / ``b_mask`` are the
 *global* block-occupancy masks of the operands (host-side numpy bool).
-For every data-exchange step of the chosen algorithm — each cannon
-shift, each summa panel — the per-algorithm mask builders
-(``cannon_step_masks`` / ``summa_step_masks`` / ``ts_step_masks``)
-slice the global masks down to the block ranges every mesh rank holds
-at that step and union them over ranks (shard_map traces ONE program
-for all devices, so the per-step plan must cover every rank's present
-triples; the union is the tightest SPMD-uniform *shared* plan).  Plans
-are memoized per shifted-mask content fingerprint (core/engine.py), and
-a step whose unioned mask product is empty skips its ``execute_plan`` —
-and for summa, the panel broadcast — entirely.  The densified path
-ignores the masks: absent blocks are stored as zeros, so one big GEMM
-is already correct.
+Each algorithm states once which block ranges every mesh rank holds at
+every data-exchange step — each cannon shift, each summa panel — as
+its step map (``cannon_step_map`` / ``summa_step_map`` /
+``ts_step_map``), and the views of core/steps.py reduce that map over
+the masks (and norms).  The union view unions every rank's slice per
+step (shard_map traces ONE program for all devices, so a shared plan
+must cover every rank's present triples; the union is the tightest
+SPMD-uniform one).  Plans are memoized per mask content fingerprint
+(core/engine.py), and a step with no retained triple on any rank skips
+its ``execute_plan`` — and for summa, the panel broadcast — entirely.
+The densified path ignores the masks and builds no map: absent blocks
+are stored as zeros, so one big GEMM is already correct.
 
 Rank-exact execution (default for masked/filtered blocked multi-rank
-paths; ``rank_exact=False`` restores the union): instead of one shared
-union plan per step, the per-rank builders (``cannon_rank_steps`` /
-``summa_rank_steps`` / ``ts_rank_steps``) emit each rank's EXACT
-mask/norm slice and the engine stacks the per-rank plans into one
-host-constant slab every rank indexes with ``jax.lax.axis_index``
-inside shard_map (core/engine.rank_stack_executor) — still one traced
-program, but a rank executes only its own retained triples, never the
-union's.  Per-step emptiness stays host-static as the all-ranks-empty
+paths; ``rank_exact=False`` plans from the union view instead): the
+rank view gives each rank's EXACT mask/norm slices, and the engine
+stacks the per-rank plans into one host-constant slab every rank
+indexes with ``jax.lax.axis_index`` inside shard_map
+(core/engine.rank_stack_executor) — still one traced program, but a
+rank executes only its own retained triples, never the union's.
+Per-step emptiness stays host-static as the all-ranks-empty
 intersection (identical to union emptiness: the max norm product over
 ranks clears eps iff some rank retains a triple).  Steps whose
 per-rank slices are content-identical (dense padding, uniform fill)
-collapse to the shared union executor, bitwise-identical to the legacy
-trace.  On top of that, the planner's costed permutation pass
+collapse to the shared union executor, bitwise-identical to the union
+path.  On top of that, the planner's costed permutation pass
 (repro.sparsity.balance) can permute block rows/cols of A/B before the
 multiply and invert the permutation on C, flattening per-rank load
 imbalance when the predicted compute saved exceeds the shuffle's cost
@@ -60,7 +59,7 @@ import collections
 import dataclasses
 import threading
 import time
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,21 +69,18 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import obs
 
 from .blocking import GridSpec
-from .cannon import (build_cannon_schedule, cannon_matmul, cannon_rank_steps,
-                     cannon_step_masks, cannon_step_norms)
+from .cannon import build_cannon_schedule, cannon_matmul, cannon_step_map
 from .cannon25d import build_cannon25d_schedule, cannon25d_matmul
 from .densify import blocked_local_matmul, densified_local_matmul
 from .engine import rank_stack_executor
 from .schedule import (Schedule, resolve_pipeline_depth, schedule_step_meta,
                        skew_panels)
 from .stacks import normalize_block_masks
+from .steps import StepMap, empty_steps, rank_view, union_view
 from .summa import (build_summa_gather_schedule, build_summa_schedule,
-                    summa_gather_masks, summa_gather_norms,
-                    summa_gather_rank_steps, summa_matmul, summa_n_panels,
-                    summa_rank_steps, summa_step_masks, summa_step_norms)
-from .tall_skinny import (build_ts_schedule, tall_skinny_matmul,
-                          ts_rank_steps, ts_specs, ts_step_masks,
-                          ts_step_norms)
+                    summa_matmul, summa_n_panels, summa_step_map)
+from .tall_skinny import (build_ts_schedule, tall_skinny_matmul, ts_specs,
+                          ts_step_map)
 
 __all__ = ["distributed_matmul"]
 
@@ -98,34 +94,6 @@ def _block_masks(
     operand is dense (all blocks present)."""
     return normalize_block_masks(m // block_m, k // block_k, n // block_n,
                                  a_mask, b_mask)
-
-
-def _masks_empty(mask_kwargs: dict) -> bool:
-    """Host-static per-step emptiness: no mask-present triple — or,
-    under a ``filter_eps`` with norms, no triple whose norm-product
-    bound clears eps (norm filtering can empty a step whose binary
-    masks are non-empty; the schedule driver then skips it exactly
-    like a mask-empty step)."""
-    eps = mask_kwargs.get("filter_eps")
-    if "pair_mask" in mask_kwargs or "pair_norms" in mask_kwargs:
-        pm = mask_kwargs.get("pair_mask")
-        if pm is not None and not pm.any():
-            return True
-        pn = mask_kwargs.get("pair_norms")
-        if eps and pn is not None:
-            kept = pn if pm is None else np.where(pm, pn, 0.0)
-            return not bool((kept.astype(np.float64) >= float(eps)).any())
-        return False
-    ua, ub = mask_kwargs["a_mask"], mask_kwargs["b_mask"]
-    if not bool(np.any(ua.any(axis=0) & ub.any(axis=1))):
-        return True
-    un, vn = mask_kwargs.get("a_norms"), mask_kwargs.get("b_norms")
-    if eps and un is not None and vn is not None:
-        # max retained product per k: (max_i masked a) * (max_j masked b)
-        ka = np.where(ua, un.astype(np.float64), 0.0).max(axis=0)
-        kb = np.where(ub, vn.astype(np.float64), 0.0).max(axis=1)
-        return not bool((ka * kb >= float(eps)).any())
-    return False
 
 
 def _global_occupancy(
@@ -144,8 +112,8 @@ def _global_occupancy(
     just binary occupancy.  An empty product returns 0.0, which the
     planner short-circuits to a trivial plan — including the case where
     eps filtering empties a product whose binary masks are non-empty
-    (the same contract as ``_masks_empty`` per step: the blocked cost
-    model must never divide by zero occupancy)."""
+    (the same contract as ``steps.masks_empty`` per step: the blocked
+    cost model must never divide by zero occupancy)."""
     filtering = filter_eps is not None and (
         a_norms is not None or b_norms is not None)
     if a_mask is None and b_mask is None and not filtering:
@@ -200,37 +168,27 @@ def _collect_executor_stats(lm, densify: bool) -> Optional[dict]:
             )
         return stats
     plan = getattr(lm, "executor_plan", None)
-    if plan is None:
-        return None
-    stats = plan.stats()
-    if hasattr(plan, "rank_entries"):
-        stats["rank_exact"] = True
-    return stats
+    return None if plan is None else plan.stats()
 
 
-def _stepwise_blocked_lm(
-    ml: int, kl: int, nl: int, *, mask_steps: List[dict], **blocked_kw,
-):
-    """A stepwise local multiply: one fused stack executor per data-
-    exchange step (plans deduplicated by mask fingerprint through the
-    engine memo).  Steps whose mask product is empty carry no executor;
-    the schedule driver skips them host-side.
-    """
-    fns, empty = [], set()
-    for t, mask_kwargs in enumerate(mask_steps):
-        if _masks_empty(mask_kwargs):
-            fns.append(None)
-            empty.add(t)
-        else:
-            fns.append(blocked_local_matmul(ml, kl, nl, **mask_kwargs,
-                                            **blocked_kw))
+def _stepwise_lm(steps: Sequence[Sequence[dict]], executor,
+                 filter_eps: Optional[float] = None):
+    """A stepwise local multiply: ``steps[t]`` lists step t's mask/norm
+    kwargs (the one union every rank shares, one per rank, or one per
+    fused group) and ``executor(steps[t])`` builds that step's stack
+    executor.  A step with no retained triple in any entry
+    (``steps.empty_steps``) carries none; the schedule driver skips it
+    host-side."""
+    empty = empty_steps(steps, filter_eps)
+    fns = [None if t in empty else executor(kws)
+           for t, kws in enumerate(steps)]
 
     def lm(a_loc: jax.Array, b_loc: jax.Array, step: int = 0):
         f = fns[step]
         return None if f is None else f(a_loc, b_loc)
 
     lm.stepwise = True
-    lm.empty_steps = frozenset(empty)
+    lm.empty_steps = empty
     lm.step_executors = fns
     return lm
 
@@ -262,10 +220,10 @@ def _rank_kwargs_equal(rank_kwargs: List[dict]) -> bool:
 
 def _rank_index_fn(algorithm: str, grid: GridSpec, mesh):
     """Zero-arg closure returning this rank's traced flat index inside
-    the shard_map body (``jax.lax.axis_index`` over the mesh axes),
-    matching the rank orderings the per-rank step builders emit:
-    cannon ``i*pg + j``; cannon25d / stacked tall-skinny stack-major
-    ``(s*pr + i)*pc + j``; summa / flat tall-skinny ``i*pc + j``."""
+    the shard_map body (``jax.lax.axis_index`` over the mesh axes), in
+    the step maps' rank order (core/steps.py): cannon ``i*pg + j``;
+    cannon25d / stacked tall-skinny stack-major ``(s*pr + i)*pc + j``;
+    summa / flat tall-skinny ``i*pc + j``."""
     pr, pc = grid.grid_shape(mesh)
     row, col = grid.row_axis, grid.col_axis
     stacked = (algorithm == "cannon25d"
@@ -279,63 +237,53 @@ def _rank_index_fn(algorithm: str, grid: GridSpec, mesh):
     return lambda: jax.lax.axis_index(row) * pc + jax.lax.axis_index(col)
 
 
-def _single_rank_lm(ml: int, kl: int, nl: int, *, rank_kwargs: List[dict],
-                    rank_index_fn, filter_eps: Optional[float] = None,
-                    **blocked_kw):
-    """Rank-exact local multiply for single-plan schedules (tall-skinny,
-    summa with the gather broadcast): one slab executor, or the union
-    ``blocked_local_matmul`` when every rank's slice is identical."""
-    if _rank_kwargs_equal(rank_kwargs):
-        return blocked_local_matmul(ml, kl, nl, **rank_kwargs[0],
-                                    filter_eps=filter_eps, **blocked_kw)
-    return rank_stack_executor(ml, kl, nl, rank_masks=rank_kwargs,
-                               rank_index_fn=rank_index_fn,
-                               filter_eps=filter_eps, **blocked_kw)
+def _summa_panels(pr: int, pc: int, kw: dict) -> int:
+    """SUMMA's panel count: the all-gather broadcast is one panel of
+    full K, the psum broadcast ``summa_n_panels``."""
+    return 1 if kw.get("bcast") == "gather" else summa_n_panels(pr, pc)
 
 
-def _stepwise_rank_blocked_lm(
-    ml: int, kl: int, nl: int, *, rank_steps: List[List[dict]],
-    rank_index_fn, filter_eps: Optional[float] = None, **blocked_kw,
-):
-    """Rank-exact stepwise local multiply: one stacked per-rank slab
-    executor per data-exchange step (core/engine.rank_stack_executor).
+def _step_map(algorithm: str, grid: GridSpec, mesh, block_grid: tuple,
+              kw: dict) -> StepMap:
+    """``algorithm``'s step map (core/steps.py) on ``mesh`` over the
+    (nbr, nbk, nbc) ``block_grid``; ``kw`` carries ``bcast``."""
+    pr, pc = grid.grid_shape(mesh)
+    if algorithm in ("cannon", "cannon25d"):
+        return cannon_step_map(
+            *block_grid, pr,
+            grid.stack_size(mesh) if algorithm == "cannon25d" else 1)
+    if algorithm == "summa":
+        return summa_step_map(*block_grid, pr, pc, _summa_panels(pr, pc, kw))
+    return ts_step_map(algorithm, *block_grid,
+                       pr * pc * grid.stack_size(mesh))
 
-    Step emptiness stays HOST-STATIC as the all-ranks-empty
-    intersection — ``max_r norm_product >= eps`` iff some rank retains
-    a triple, so this is exactly the union path's per-step skip set and
-    the comm schedule stays SPMD-uniform.  A step whose per-rank slices
-    are content-identical (uniform fill) collapses to the shared union
-    executor, bitwise-identical to the legacy trace."""
-    fns, empty = [], set()
-    for t, rkw in enumerate(rank_steps):
-        if all(_masks_empty({**r, "filter_eps": filter_eps}) for r in rkw):
-            fns.append(None)
-            empty.add(t)
-        elif _rank_kwargs_equal(rkw):
-            fns.append(blocked_local_matmul(
-                ml, kl, nl, **rkw[0], filter_eps=filter_eps, **blocked_kw))
-        else:
-            fns.append(rank_stack_executor(
-                ml, kl, nl, rank_masks=rkw, rank_index_fn=rank_index_fn,
-                filter_eps=filter_eps, **blocked_kw))
 
-    def lm(a_loc: jax.Array, b_loc: jax.Array, step: int = 0):
-        f = fns[step]
-        return None if f is None else f(a_loc, b_loc)
+def _blocked_steps_lm(ml: int, kl: int, nl: int, steps: List[List[dict]],
+                      *, rank_index_fn=None,
+                      filter_eps: Optional[float] = None, **blocked_kw):
+    """The blocked local multiply of a masked schedule (``_stepwise_lm``).
+    A step whose entries are content-identical — the union view's one
+    entry, or every rank's slice of a uniform fill — runs the shared
+    ``blocked_local_matmul``, bitwise the union path; any other step
+    runs one stacked per-rank slab that each rank indexes with
+    ``rank_index_fn`` (core/engine.rank_stack_executor; a union view
+    never needs it)."""
+    def executor(kws):
+        if _rank_kwargs_equal(kws):
+            return blocked_local_matmul(ml, kl, nl, **kws[0],
+                                        filter_eps=filter_eps, **blocked_kw)
+        return rank_stack_executor(ml, kl, nl, rank_masks=kws,
+                                   rank_index_fn=rank_index_fn,
+                                   filter_eps=filter_eps, **blocked_kw)
 
-    lm.stepwise = True
-    lm.empty_steps = frozenset(empty)
-    lm.step_executors = fns
-    return lm
+    return _stepwise_lm(steps, executor, filter_eps)
 
 
 def _rank_totals(lm) -> Optional[np.ndarray]:
     """Per-rank executed-entry totals over the whole multiply (summed
     across steps; collapsed/union steps charge every rank the shared
     plan's entries).  None when no step executed rank-exactly."""
-    fns = getattr(lm, "step_executors", None)
-    if fns is None:
-        fns = [lm]
+    fns = getattr(lm, "step_executors", [lm])
     plans = [getattr(f, "executor_plan", None)
              for f in fns if f is not None]
     ranked = [p for p in plans if hasattr(p, "rank_entries")]
@@ -766,10 +714,9 @@ def distributed_matmul(
     ``b_norms`` are *global* per-block Frobenius norms (block-grid
     float arrays); when omitted they are computed on the fly from the
     payloads (requires concrete arrays — call outside jit, as with
-    ``return_plan``).  Norms ride the same per-shift / per-panel
-    slicing machinery as the masks (``cannon_step_norms`` /
-    ``summa_step_norms`` / ``ts_step_norms``; SPMD union semantics
-    become union-of-max), a step with no retained triple is skipped
+    ``return_plan``).  Norms are sliced by the same step map as the
+    masks (core/steps.py; the union view's SPMD union becomes
+    union-of-max), a step with no retained triple is skipped
     entirely, and the planner's occupancy becomes the norm-predicted
     retained fraction.  ``filter_eps=0.0`` is bit-identical to the
     unfiltered path; the densified local path ignores triple filtering
@@ -1072,7 +1019,6 @@ def _distributed_matmul(
 
         # ---- local multiply geometry (per schedule step) ------------------
         pr, pc = grid.grid_shape(mesh)
-        pg = p_all = n_panels = None
         if algorithm.startswith("ts_"):
             p_all = pr * pc * grid.stack_size(mesh)
             shapes = {
@@ -1092,21 +1038,14 @@ def _distributed_matmul(
                 raise ValueError(
                     f"shape ({m},{k},{n}) not divisible by grid side {pg}")
             ml, kl, nl = m // pg, k // pg, n // pg
-        elif kw.get("bcast") == "gather":
-            # PUMMA-style broadcast: the local multiply sees the
-            # all-gathered full-K row of A / column of B — a single
-            # stack-plan geometry on any grid shape.
-            if (m % pr or n % pc) and not densify:
-                raise ValueError(
-                    f"shape ({m},{n}) not divisible by grid {pr}x{pc}")
-            ml, kl, nl = m // pr, k, n // pc
         else:
-            # summa psum: every panel's local multiply is
-            # (m/pr, k/n_panels) @ (k/n_panels, n/pc) — one per-panel
-            # stack-plan geometry shared by all panels, so non-square
-            # grids are fine (for square grids k/n_panels == k/pc, the
-            # historical full-local-K geometry).
-            n_panels = summa_n_panels(pr, pc)
+            # summa: every panel's local multiply is (m/pr, k/n_panels) @
+            # (k/n_panels, n/pc) — one stack-plan geometry shared by all
+            # panels, so non-square grids are fine (for square grids
+            # k/n_panels == k/pc, the historical full-local-K geometry).
+            # The all-gather broadcast is one panel: the local multiply
+            # sees the gathered full-K row of A / column of B.
+            n_panels = _summa_panels(pr, pc, kw)
             if (m % pr or n % pc or k % n_panels) and not densify:
                 raise ValueError(
                     f"shape ({m},{k},{n}) not divisible by summa grid "
@@ -1123,79 +1062,18 @@ def _distributed_matmul(
                 kernel=local_kernel or "smm", stack_bins=stack_bins)
             if not masked:
                 lm = blocked_local_matmul(ml, kl, nl, **blocked_kw)
-            elif algorithm in ("cannon", "cannon25d"):
-                c_repl = (grid.stack_size(mesh)
-                          if algorithm == "cannon25d" else 1)
-                if use_rank:
-                    lm = _stepwise_rank_blocked_lm(
-                        ml, kl, nl,
-                        rank_steps=cannon_rank_steps(
-                            am, bmk, pg, c_repl, a_norms=an_g, b_norms=bn_g),
-                        rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
-                        filter_eps=filter_eps, **blocked_kw)
-                else:
-                    steps = [{"pair_mask": pm}
-                             for pm in cannon_step_masks(am, bmk, pg, c_repl)]
-                    if filtering:
-                        for s, pn in zip(steps, cannon_step_norms(
-                                an_g, bn_g, pg, c_repl)):
-                            s.update(pair_norms=pn, filter_eps=filter_eps)
-                    lm = _stepwise_blocked_lm(ml, kl, nl, mask_steps=steps,
-                                              **blocked_kw)
-            elif algorithm == "summa" and kw.get("bcast") != "gather":
-                if use_rank:
-                    lm = _stepwise_rank_blocked_lm(
-                        ml, kl, nl,
-                        rank_steps=summa_rank_steps(
-                            am, bmk, pr, pc, n_panels,
-                            a_norms=an_g, b_norms=bn_g),
-                        rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
-                        filter_eps=filter_eps, **blocked_kw)
-                else:
-                    steps = [{"a_mask": ua, "b_mask": ub} for ua, ub in
-                             summa_step_masks(am, bmk, pr, pc, n_panels)]
-                    if filtering:
-                        for s, (una, unb) in zip(steps, summa_step_norms(
-                                an_g, bn_g, pr, pc, n_panels)):
-                            s.update(a_norms=una, b_norms=unb,
-                                     filter_eps=filter_eps)
-                    lm = _stepwise_blocked_lm(ml, kl, nl, mask_steps=steps,
-                                              **blocked_kw)
-            elif algorithm == "summa":
-                if use_rank:
-                    lm = _single_rank_lm(
-                        ml, kl, nl,
-                        rank_kwargs=summa_gather_rank_steps(
-                            am, bmk, pr, pc, a_norms=an_g, b_norms=bn_g),
-                        rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
-                        filter_eps=filter_eps, **blocked_kw)
-                else:
-                    ua, ub = summa_gather_masks(am, bmk, pr, pc)
-                    norm_kw = {}
-                    if filtering:
-                        una, unb = summa_gather_norms(an_g, bn_g, pr, pc)
-                        norm_kw = dict(a_norms=una, b_norms=unb,
-                                       filter_eps=filter_eps)
-                    lm = blocked_local_matmul(ml, kl, nl, a_mask=ua, b_mask=ub,
-                                              **norm_kw, **blocked_kw)
             else:
-                if use_rank:
-                    lm = _single_rank_lm(
-                        ml, kl, nl,
-                        rank_kwargs=ts_rank_steps(
-                            algorithm, am, bmk, p_all,
-                            a_norms=an_g, b_norms=bn_g),
-                        rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
-                        filter_eps=filter_eps, **blocked_kw)
-                else:
-                    norm_kw = {}
-                    if filtering:
-                        norm_kw = dict(ts_step_norms(algorithm, an_g, bn_g,
-                                                     p_all),
-                                       filter_eps=filter_eps)
-                    lm = blocked_local_matmul(
-                        ml, kl, nl, **ts_step_masks(algorithm, am, bmk, p_all),
-                        **norm_kw, **blocked_kw)
+                # which blocks each rank holds at each step, planned as
+                # each rank's own slices or as their union
+                smap = _step_map(algorithm, grid, mesh,
+                                 (*am.shape, bmk.shape[1]), kw)
+                steps = (rank_view(smap, am, bmk, an_g, bn_g) if use_rank
+                         else [[u] for u in
+                               union_view(smap, am, bmk, an_g, bn_g)])
+                lm = _blocked_steps_lm(
+                    ml, kl, nl, steps,
+                    rank_index_fn=_rank_index_fn(algorithm, grid, mesh),
+                    filter_eps=filter_eps, **blocked_kw)
         if not densify and obs.enabled():
             imb = _rank_imbalance_of(_rank_totals(lm))
             if imb is not None:

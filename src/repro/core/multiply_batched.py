@@ -48,7 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -57,14 +57,14 @@ import numpy as np
 from repro import obs
 
 from .blocking import GridSpec
-from .cannon import cannon_matmul, cannon_step_masks, cannon_step_norms
+from .cannon import cannon_matmul
 from .densify import grouped_densified_local_matmul
 from .engine import batched_stack_executor
-from .multiply import (_block_masks, _global_occupancy,
-                       _masks_empty, _schedule_stats, is_live)
+from .multiply import (_block_masks, _global_occupancy, _schedule_stats,
+                       _step_map, _stepwise_lm, is_live)
 from .schedule import resolve_pipeline_depth
-from .summa import (summa_matmul, summa_n_panels, summa_step_masks,
-                    summa_step_norms)
+from .steps import union_view
+from .summa import summa_matmul, summa_n_panels
 # canonical definition lives with the cost model (the planner restricts
 # its batched enumeration by it; cost_model imports nothing from core)
 from repro.planner.cost_model import BATCHED_ALGORITHMS
@@ -80,38 +80,6 @@ def _per_group(seq: Optional[Sequence], g: int, n_groups: int, name: str):
         raise ValueError(f"{name} has {len(seq)} entries for {n_groups} "
                          f"products")
     return seq[g]
-
-
-def _stepwise_batched_lm(
-    n_groups: int, ml: int, kl: int, nl: int, *,
-    group_mask_steps: List[List[dict]],
-    filter_eps: Optional[float] = None,
-    **batched_kw,
-):
-    """A stepwise *batched* local multiply: one fused batched executor
-    per data-exchange step (``group_mask_steps[t][g]`` is group ``g``'s
-    mask/norm kwargs at step ``t``).  A step is empty — and host-side
-    skipped by the schedule driver — only when every group's mask/norm
-    product is empty at that step; a group that is individually empty at
-    a non-empty step contributes zero stacks to the fused tensor."""
-    fns, empty = [], set()
-    for t, gms in enumerate(group_mask_steps):
-        if all(_masks_empty(dict(gm, filter_eps=filter_eps)) for gm in gms):
-            fns.append(None)
-            empty.add(t)
-        else:
-            fns.append(batched_stack_executor(
-                n_groups, ml, kl, nl, group_masks=gms,
-                filter_eps=filter_eps, **batched_kw))
-
-    def lm(a_loc: jax.Array, b_loc: jax.Array, step: int = 0):
-        f = fns[step]
-        return None if f is None else f(a_loc, b_loc)
-
-    lm.stepwise = True
-    lm.empty_steps = frozenset(empty)
-    lm.step_executors = fns
-    return lm
 
 
 def _collect_batched_executor_stats(lm, densify: bool) -> Optional[dict]:
@@ -340,7 +308,6 @@ def _distributed_matmul_batched(
     with obs.maybe_span(_live, "stacks", cat="stacks"):
         # ---- local multiply geometry ------------------------------------
         pr, pc = grid.grid_shape(mesh)
-        pg = n_panels = None
         if algorithm == "cannon":
             pg = grid.validate_square(mesh)
             if (m % pg or k % pg or n % pg) and not densify:
@@ -384,38 +351,18 @@ def _distributed_matmul_batched(
                         an_g = np.where(am, an_g, np.float32(0.0))
                         bn_g = np.where(bmk, bn_g, np.float32(0.0))
                     group_ab.append((am, bmk, an_g, bn_g))
-                if algorithm == "cannon":
-                    n_steps = pg
-                    per_group = [cannon_step_masks(am, bmk, pg)
-                                 for am, bmk, _, _ in group_ab]
-                    steps = [[{"pair_mask": per_group[gi][t]}
-                              for gi in range(g_count)]
-                             for t in range(n_steps)]
-                    if filtering:
-                        per_group_n = [cannon_step_norms(an_g, bn_g, pg)
-                                       for _, _, an_g, bn_g in group_ab]
-                        for t in range(n_steps):
-                            for gi in range(g_count):
-                                steps[t][gi]["pair_norms"] = per_group_n[gi][t]
-                else:
-                    n_steps = n_panels
-                    per_group = [summa_step_masks(am, bmk, pr, pc, n_panels)
-                                 for am, bmk, _, _ in group_ab]
-                    steps = [[dict(zip(("a_mask", "b_mask"),
-                                       per_group[gi][t]))
-                              for gi in range(g_count)]
-                             for t in range(n_steps)]
-                    if filtering:
-                        per_group_n = [summa_step_norms(an_g, bn_g, pr, pc,
-                                                        n_panels)
-                                       for _, _, an_g, bn_g in group_ab]
-                        for t in range(n_steps):
-                            for gi in range(g_count):
-                                una, unb = per_group_n[gi][t]
-                                steps[t][gi].update(a_norms=una, b_norms=unb)
-                lm = _stepwise_batched_lm(
-                    g_count, ml, kl, nl, group_mask_steps=steps,
-                    filter_eps=filter_eps, **batched_kw)
+                # one step map for the whole batch; a step is empty only
+                # when every group's union is
+                smap = _step_map(algorithm, grid, mesh,
+                                 (m // block_m, k // block_k, n // block_n),
+                                 kw)
+                views = [union_view(smap, *ab) for ab in group_ab]
+                lm = _stepwise_lm(
+                    list(zip(*views)),
+                    lambda gms: batched_stack_executor(
+                        g_count, ml, kl, nl, group_masks=gms,
+                        filter_eps=filter_eps, **batched_kw),
+                    filter_eps)
 
     # ---- data exchange (one schedule for the whole batch) ------------
     def _run():

@@ -24,26 +24,28 @@ The panel loop is the unified schedule engine (core/schedule.py):
 ``build_summa_schedule`` emits one step per panel whose ``recv`` is the
 masked-allreduce broadcast (operands stay resident — ``shift`` is the
 identity), so at ``pipeline_depth=2`` the broadcast of panel t+1 is
-issued before the local multiply of panel t.
+issued before the local multiply of panel t.  ``summa_step_map`` says
+which blocks each rank multiplies at each panel (one panel of full K
+for the all-gather); the blocked path plans its stacks from it
+(core/steps.py).
 """
 from __future__ import annotations
 
+import functools
 import math
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .blocking import GridSpec
 from .cannon import _default_local_matmul
 from .schedule import Schedule, execute_schedule, resolve_pipeline_depth
+from .steps import StepMap
 
 __all__ = ["summa_matmul", "summa_n_panels", "build_summa_schedule",
-           "build_summa_gather_schedule", "summa_step_masks",
-           "summa_gather_masks", "summa_step_norms", "summa_gather_norms",
-           "summa_rank_steps", "summa_gather_rank_steps"]
+           "build_summa_gather_schedule", "summa_step_map"]
 
 
 def summa_n_panels(pr: int, pc: int) -> int:
@@ -115,185 +117,23 @@ def build_summa_schedule(
     )
 
 
-def summa_step_masks(
-    am: np.ndarray, bm: np.ndarray, pr: int, pc: int, n_panels: int,
-) -> List[Tuple[np.ndarray, np.ndarray]]:
-    """Per-panel (a_mask, b_mask) unions for psum-broadcast SUMMA — the
-    schedule builder's per-step mask slices.
-
-    Panel p covers the global K block range [p*nbk/n_panels, ...); the
-    A-side union runs over the pr row chunks, the B-side over the pc
-    column chunks.  Because the row and column ranks vary independently,
-    the union of per-rank products equals the product of the factored
-    unions — no 3D pair tensor needed.
-    """
-    nbr, nbk = am.shape
-    nbc = bm.shape[1]
+@functools.lru_cache(maxsize=256)
+def summa_step_map(nbr: int, nbk: int, nbc: int, pr: int, pc: int,
+                   n_panels: int) -> StepMap:
+    """The step map (core/steps.py) of SUMMA on a (pr, pc) grid over an
+    (nbr, nbk, nbc) block grid: at panel p, rank ``i*pc + j`` multiplies
+    its A row chunk i and B column chunk j over the panel's K range
+    ``[p*nbk/n_panels, (p+1)*nbk/n_panels)``.  The all-gather broadcast
+    is the map of one panel: every rank sees the full K range."""
     if nbr % pr or nbc % pc or nbk % n_panels:
         raise ValueError(
             f"block grid ({nbr},{nbk},{nbc}) not divisible by summa grid "
             f"{pr}x{pc} with {n_panels} panels")
     lr, lc, lkp = nbr // pr, nbc // pc, nbk // n_panels
-    out = []
-    for p in range(n_panels):
-        ksl = slice(p * lkp, (p + 1) * lkp)
-        ua = np.zeros((lr, lkp), dtype=bool)
-        for i in range(pr):
-            ua |= am[i * lr:(i + 1) * lr, ksl]
-        ub = np.zeros((lkp, lc), dtype=bool)
-        for j in range(pc):
-            ub |= bm[ksl, j * lc:(j + 1) * lc]
-        out.append((ua, ub))
-    return out
-
-
-def summa_step_norms(
-    an: np.ndarray, bn: np.ndarray, pr: int, pc: int, n_panels: int,
-) -> List[Tuple[np.ndarray, np.ndarray]]:
-    """Per-panel (a_norms, b_norms) max-unions for psum-broadcast SUMMA
-    — the norm twin of ``summa_step_masks`` (repro.sparsity).
-
-    SPMD union-of-max semantics: the A-side takes the elementwise MAX
-    over the pr row chunks, the B-side over the pc column chunks.  The
-    factored product ``max_i(an) * max_j(bn)`` upper-bounds every
-    rank's norm product, so ``filter_eps`` never drops a triple some
-    rank still needs — the same conservativeness as the factored mask
-    union."""
-    nbr, nbk = an.shape
-    nbc = bn.shape[1]
-    if nbr % pr or nbc % pc or nbk % n_panels:
-        raise ValueError(
-            f"block grid ({nbr},{nbk},{nbc}) not divisible by summa grid "
-            f"{pr}x{pc} with {n_panels} panels")
-    an = np.asarray(an, dtype=np.float32)
-    bn = np.asarray(bn, dtype=np.float32)
-    lr, lc, lkp = nbr // pr, nbc // pc, nbk // n_panels
-    out = []
-    for p in range(n_panels):
-        ksl = slice(p * lkp, (p + 1) * lkp)
-        ua = np.zeros((lr, lkp), dtype=np.float32)
-        for i in range(pr):
-            np.maximum(ua, an[i * lr:(i + 1) * lr, ksl], out=ua)
-        ub = np.zeros((lkp, lc), dtype=np.float32)
-        for j in range(pc):
-            np.maximum(ub, bn[ksl, j * lc:(j + 1) * lc], out=ub)
-        out.append((ua, ub))
-    return out
-
-
-def summa_gather_norms(
-    an: np.ndarray, bn: np.ndarray, pr: int, pc: int,
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Factored max-unions for PUMMA-style (all-gather) SUMMA — the
-    norm twin of ``summa_gather_masks``: one step, A maxed over row
-    chunks, B over column chunks."""
-    nbr, nbk = an.shape
-    nbc = bn.shape[1]
-    if nbr % pr or nbc % pc:
-        raise ValueError(
-            f"block grid ({nbr},{nbc}) not divisible by grid {pr}x{pc}")
-    an = np.asarray(an, dtype=np.float32)
-    bn = np.asarray(bn, dtype=np.float32)
-    lr, lc = nbr // pr, nbc // pc
-    ua = np.zeros((lr, nbk), dtype=np.float32)
-    for i in range(pr):
-        np.maximum(ua, an[i * lr:(i + 1) * lr], out=ua)
-    ub = np.zeros((nbk, lc), dtype=np.float32)
-    for j in range(pc):
-        np.maximum(ub, bn[:, j * lc:(j + 1) * lc], out=ub)
-    return ua, ub
-
-
-def summa_gather_masks(
-    am: np.ndarray, bm: np.ndarray, pr: int, pc: int,
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Factored unions for PUMMA-style (all-gather) SUMMA: the local
-    multiply sees the full K extent, so there is a single step whose A
-    mask unions over row chunks and B mask over column chunks."""
-    nbr, nbk = am.shape
-    nbc = bm.shape[1]
-    if nbr % pr or nbc % pc:
-        raise ValueError(
-            f"block grid ({nbr},{nbc}) not divisible by grid {pr}x{pc}")
-    lr, lc = nbr // pr, nbc // pc
-    ua = np.zeros((lr, nbk), dtype=bool)
-    for i in range(pr):
-        ua |= am[i * lr:(i + 1) * lr]
-    ub = np.zeros((nbk, lc), dtype=bool)
-    for j in range(pc):
-        ub |= bm[:, j * lc:(j + 1) * lc]
-    return ua, ub
-
-
-def summa_rank_steps(
-    am: np.ndarray, bm: np.ndarray, pr: int, pc: int, n_panels: int,
-    a_norms: Optional[np.ndarray] = None,
-    b_norms: Optional[np.ndarray] = None,
-) -> List[List[dict]]:
-    """Rank-exact twin of ``summa_step_masks``/``summa_step_norms``:
-    per panel, per RANK exact local mask (and norm) kwargs.
-
-    ``out[p][r]`` is the kwarg dict for rank ``r = i * pc + j`` at
-    panel ``p`` — its own A row chunk against the panel's K slice and
-    the panel's K slice against its own B column chunk, no cross-rank
-    union and no union-of-max norms.
-    """
-    nbr, nbk = am.shape
-    nbc = bm.shape[1]
-    if nbr % pr or nbc % pc or nbk % n_panels:
-        raise ValueError(
-            f"block grid ({nbr},{nbk},{nbc}) not divisible by summa grid "
-            f"{pr}x{pc} with {n_panels} panels")
-    lr, lc, lkp = nbr // pr, nbc // pc, nbk // n_panels
-    if a_norms is not None:
-        a_norms = np.asarray(a_norms, dtype=np.float32)
-        b_norms = np.asarray(b_norms, dtype=np.float32)
-    steps: List[List[dict]] = []
-    for p in range(n_panels):
-        ksl = slice(p * lkp, (p + 1) * lkp)
-        ranks: List[dict] = []
-        for i in range(pr):
-            rs = slice(i * lr, (i + 1) * lr)
-            for j in range(pc):
-                cs = slice(j * lc, (j + 1) * lc)
-                kw = {"a_mask": am[rs, ksl], "b_mask": bm[ksl, cs]}
-                if a_norms is not None:
-                    kw["a_norms"] = a_norms[rs, ksl]
-                    kw["b_norms"] = b_norms[ksl, cs]
-                ranks.append(kw)
-        steps.append(ranks)
-    return steps
-
-
-def summa_gather_rank_steps(
-    am: np.ndarray, bm: np.ndarray, pr: int, pc: int,
-    a_norms: Optional[np.ndarray] = None,
-    b_norms: Optional[np.ndarray] = None,
-) -> List[dict]:
-    """Rank-exact twin of ``summa_gather_masks``/``summa_gather_norms``
-    for the single-step all-gather variant: rank ``r = i * pc + j``
-    multiplies its exact A row chunk (full K) by its exact B column
-    chunk."""
-    nbr, nbk = am.shape
-    nbc = bm.shape[1]
-    if nbr % pr or nbc % pc:
-        raise ValueError(
-            f"block grid ({nbr},{nbc}) not divisible by grid {pr}x{pc}")
-    lr, lc = nbr // pr, nbc // pc
-    if a_norms is not None:
-        a_norms = np.asarray(a_norms, dtype=np.float32)
-        b_norms = np.asarray(b_norms, dtype=np.float32)
-    ranks: List[dict] = []
-    for i in range(pr):
-        rs = slice(i * lr, (i + 1) * lr)
-        for j in range(pc):
-            cs = slice(j * lc, (j + 1) * lc)
-            kw = {"a_mask": am[rs], "b_mask": bm[:, cs]}
-            if a_norms is not None:
-                kw["a_norms"] = a_norms[rs]
-                kw["b_norms"] = b_norms[:, cs]
-            ranks.append(kw)
-    return ranks
+    return StepMap.of(
+        [[(i * lr, p * lkp, j * lc) for i in range(pr) for j in range(pc)]
+         for p in range(n_panels)],
+        (lr, lkp, lc), pair=False)
 
 
 def build_summa_gather_schedule(row_axis: str, col_axis: str,

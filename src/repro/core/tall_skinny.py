@@ -21,24 +21,28 @@ row-sharded and moves (P-1)/P * M*N per device, strictly less.
 Two degenerate variants are provided for the other tall-skinny shapes:
   * M large (A tall): shard M, replicate B — **zero** communication.
   * N large (B wide): shard N, replicate A — zero communication.
+
+Each variant is a one-step schedule (``build_ts_schedule``);
+``ts_step_map`` says which blocks each device multiplies in that step,
+and the blocked path plans its stacks from it (core/steps.py).
 """
 from __future__ import annotations
 
-from typing import Callable, List, Optional
+import functools
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .blocking import GridSpec
 from .cannon import _default_local_matmul
 from .schedule import Schedule, execute_schedule, resolve_pipeline_depth
+from .steps import StepMap
 
 __all__ = ["tall_skinny_matmul", "build_ts_schedule", "ts_specs",
-           "ts_step_masks",
-           "ts_step_norms", "ts_rank_steps", "classify_shape",
-           "ts_classify_ratio", "DEFAULT_TS_RATIO"]
+           "ts_step_map", "classify_shape", "ts_classify_ratio",
+           "DEFAULT_TS_RATIO"]
 
 # The historical hardcoded tall/skinny threshold.  The live threshold
 # is planner-owned (the cost-model crossover where tall-skinny's O(1)
@@ -141,134 +145,29 @@ def build_ts_schedule(
     )
 
 
-def ts_step_masks(mode: str, am: np.ndarray, bm: np.ndarray,
-                  p_all: int) -> dict:
-    """Single-step mask kwargs for the tall-and-skinny variants (the
-    contraction/tall dimension is sharded over all ``p_all`` devices) —
-    the schedule builder's per-step mask slice, as a union over ranks."""
-    nbr, nbk = am.shape
-    nbc = bm.shape[1]
+@functools.lru_cache(maxsize=256)
+def ts_step_map(mode: str, nbr: int, nbk: int, nbc: int,
+                p_all: int) -> StepMap:
+    """The step map (core/steps.py) of a tall-and-skinny variant over an
+    (nbr, nbk, nbc) block grid: one step, at which device ``d`` of the
+    ``p_all`` shards (the joint mesh axes flattened) multiplies its A
+    column chunk by its B row chunk (``ts_k``), its A row chunk by all
+    of B (``ts_m``) or all of A by its B column chunk (``ts_n``)."""
+    dims = {"ts_k": ("K", nbk), "ts_m": ("M", nbr), "ts_n": ("N", nbc)}
+    if mode not in dims:
+        raise ValueError(mode)
+    name, nb = dims[mode]
+    if nb % p_all:
+        raise ValueError(f"{name} block grid {nb} not divisible by {p_all}")
+    chunk = nb // p_all
     if mode == "ts_k":
-        if nbk % p_all:
-            raise ValueError(f"K block grid {nbk} not divisible by {p_all}")
-        lk = nbk // p_all
-        pair = np.zeros((nbr, lk, nbc), dtype=bool)
-        for d in range(p_all):
-            ac = am[:, d * lk:(d + 1) * lk]
-            if not ac.any():
-                continue
-            bc = bm[d * lk:(d + 1) * lk, :]
-            pair |= ac[:, :, None] & bc[None, :, :]
-        return {"pair_mask": pair}
+        return StepMap.of([[(0, d * chunk, 0) for d in range(p_all)]],
+                          (nbr, chunk, nbc), pair=True)
     if mode == "ts_m":
-        if nbr % p_all:
-            raise ValueError(f"M block grid {nbr} not divisible by {p_all}")
-        lr = nbr // p_all
-        ua = np.zeros((lr, nbk), dtype=bool)
-        for d in range(p_all):
-            ua |= am[d * lr:(d + 1) * lr]
-        return {"a_mask": ua, "b_mask": bm}
-    if nbc % p_all:
-        raise ValueError(f"N block grid {nbc} not divisible by {p_all}")
-    lc = nbc // p_all
-    ub = np.zeros((nbk, lc), dtype=bool)
-    for d in range(p_all):
-        ub |= bm[:, d * lc:(d + 1) * lc]
-    return {"a_mask": am, "b_mask": ub}
-
-
-def ts_step_norms(mode: str, an: np.ndarray, bn: np.ndarray,
-                  p_all: int) -> dict:
-    """Single-step norm kwargs for the tall-and-skinny variants — the
-    norm twin of ``ts_step_masks`` under SPMD union-of-max semantics
-    (repro.sparsity): where the mask builder unions presence over the
-    ``p_all`` shards, the norm builder takes the elementwise MAX, so
-    ``filter_eps`` never drops a triple some shard still needs."""
-    nbr, nbk = an.shape
-    nbc = bn.shape[1]
-    an = np.asarray(an, dtype=np.float32)
-    bn = np.asarray(bn, dtype=np.float32)
-    if mode == "ts_k":
-        if nbk % p_all:
-            raise ValueError(f"K block grid {nbk} not divisible by {p_all}")
-        lk = nbk // p_all
-        pair = np.zeros((nbr, lk, nbc), dtype=np.float32)
-        for d in range(p_all):
-            ac = an[:, d * lk:(d + 1) * lk]
-            if not ac.any():
-                continue
-            bc = bn[d * lk:(d + 1) * lk, :]
-            np.maximum(pair, ac[:, :, None] * bc[None, :, :], out=pair)
-        return {"pair_norms": pair}
-    if mode == "ts_m":
-        if nbr % p_all:
-            raise ValueError(f"M block grid {nbr} not divisible by {p_all}")
-        lr = nbr // p_all
-        ua = np.zeros((lr, nbk), dtype=np.float32)
-        for d in range(p_all):
-            np.maximum(ua, an[d * lr:(d + 1) * lr], out=ua)
-        return {"a_norms": ua, "b_norms": bn}
-    if nbc % p_all:
-        raise ValueError(f"N block grid {nbc} not divisible by {p_all}")
-    lc = nbc // p_all
-    ub = np.zeros((nbk, lc), dtype=np.float32)
-    for d in range(p_all):
-        np.maximum(ub, bn[:, d * lc:(d + 1) * lc], out=ub)
-    return {"a_norms": an, "b_norms": ub}
-
-
-def ts_rank_steps(mode: str, am: np.ndarray, bm: np.ndarray, p_all: int,
-                  a_norms: Optional[np.ndarray] = None,
-                  b_norms: Optional[np.ndarray] = None) -> List[dict]:
-    """Rank-exact twin of ``ts_step_masks``/``ts_step_norms``: one
-    exact mask/norm kwarg dict per device ``d`` (the joint-axes
-    flattened shard index), instead of the union over shards.
-
-    ts_k shards K: device ``d`` multiplies its A column chunk by its B
-    row chunk.  ts_m shards M (its A row chunk x full B); ts_n shards
-    N (full A x its B column chunk).
-    """
-    nbr, nbk = am.shape
-    nbc = bm.shape[1]
-    if a_norms is not None:
-        a_norms = np.asarray(a_norms, dtype=np.float32)
-        b_norms = np.asarray(b_norms, dtype=np.float32)
-    ranks: List[dict] = []
-    if mode == "ts_k":
-        if nbk % p_all:
-            raise ValueError(f"K block grid {nbk} not divisible by {p_all}")
-        lk = nbk // p_all
-        for d in range(p_all):
-            ks = slice(d * lk, (d + 1) * lk)
-            kw = {"a_mask": am[:, ks], "b_mask": bm[ks, :]}
-            if a_norms is not None:
-                kw["a_norms"] = a_norms[:, ks]
-                kw["b_norms"] = b_norms[ks, :]
-            ranks.append(kw)
-        return ranks
-    if mode == "ts_m":
-        if nbr % p_all:
-            raise ValueError(f"M block grid {nbr} not divisible by {p_all}")
-        lr = nbr // p_all
-        for d in range(p_all):
-            rs = slice(d * lr, (d + 1) * lr)
-            kw = {"a_mask": am[rs], "b_mask": bm}
-            if a_norms is not None:
-                kw["a_norms"] = a_norms[rs]
-                kw["b_norms"] = b_norms
-            ranks.append(kw)
-        return ranks
-    if nbc % p_all:
-        raise ValueError(f"N block grid {nbc} not divisible by {p_all}")
-    lc = nbc // p_all
-    for d in range(p_all):
-        cs = slice(d * lc, (d + 1) * lc)
-        kw = {"a_mask": am, "b_mask": bm[:, cs]}
-        if a_norms is not None:
-            kw["a_norms"] = a_norms
-            kw["b_norms"] = b_norms[:, cs]
-        ranks.append(kw)
-    return ranks
+        return StepMap.of([[(d * chunk, 0, 0) for d in range(p_all)]],
+                          (chunk, nbk, nbc), pair=False)
+    return StepMap.of([[(0, 0, d * chunk) for d in range(p_all)]],
+                      (nbr, nbk, chunk), pair=False)
 
 
 def tall_skinny_matmul(

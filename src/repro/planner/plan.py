@@ -19,7 +19,7 @@ tests/test_planner.py via ``cost_model.N_EVALS``).
 An empty product short-circuits to a trivial zero-cost plan *before*
 any candidate is costed: the blocked-path model divides by
 occupancy-derived quantities and must never see occupancy zero (the
-``_masks_empty`` contract shared with core/multiply.py).  This fires
+``masks_empty`` contract of core/steps.py).  This fires
 both for an empty binary-mask product AND for a norm-predicted-empty
 product — eps filtering (repro.sparsity) can empty a product whose
 binary masks are non-empty, in which case ``_global_occupancy``

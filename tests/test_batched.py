@@ -196,12 +196,23 @@ def test_add_recompute_norms(rng):
     np.testing.assert_allclose(eager.block_norms, lazy.norms(), rtol=1e-6)
 
 
+def _exact_mask(rng, nbr, nbc, fill):
+    """A mask with exactly ``round(fill * nbr * nbc)`` present blocks at
+    places drawn from ``rng``: its fill bin is the one the caller states,
+    whatever state the session's shared ``rng`` arrives in."""
+    if fill >= 1.0:
+        return None
+    mask = np.zeros(nbr * nbc, dtype=bool)
+    mask[rng.permutation(nbr * nbc)[:round(fill * nbr * nbc)]] = True
+    return mask.reshape(nbr, nbc)
+
+
 def _make_requests(rng, mesh, geoms_fills, block_size=32):
     reqs, refs = [], []
     for (m, k, n), fill in geoms_fills:
         A = rng.randn(m, k).astype(np.float32)
         B = rng.randn(k, n).astype(np.float32)
-        am = _rand_mask(rng, m // block_size, k // block_size, fill)
+        am = _exact_mask(rng, m // block_size, k // block_size, fill)
         a = dbcsr.create(A, mesh=mesh, block_size=block_size, block_mask=am)
         b = dbcsr.create(B, mesh=mesh, block_size=block_size)
         reqs.append((a, b))

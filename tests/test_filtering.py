@@ -17,11 +17,11 @@ import jax.numpy as jnp
 from conftest import run_subprocess_devices
 from repro.core import dbcsr, engine
 from repro.core.blocking import BlockLayout, GridSpec
-from repro.core.cannon import cannon_step_norms
+from repro.core.cannon import cannon_step_map
 from repro.core.densify import blocked_local_matmul
-from repro.core.multiply import _masks_empty
 from repro.core.stacks import build_stacks
-from repro.core.summa import summa_step_norms
+from repro.core.steps import masks_empty as _masks_empty, union_view
+from repro.core.summa import summa_step_map
 from repro.launch.mesh import make_mesh
 from repro.sparsity.filter import (count_retained_triples, product_mask,
                                    retained_pair_presence)
@@ -247,11 +247,20 @@ def test_filtered_executor_matches_dropped_triple_reference(rng):
 
 
 # ---------------------------------------------------------------------------
-# step-norm builders: SPMD union-of-max semantics
+# the union view's norms: SPMD union-of-max semantics
 # ---------------------------------------------------------------------------
 
 
-def test_cannon_step_norms_union_of_max(rng):
+def _union_norms(smap, an, bn):
+    """The union view's norms per step, with every block present."""
+    am = np.ones(an.shape, bool)
+    bm = np.ones(bn.shape, bool)
+    return [s["pair_norms"] if "pair_norms" in s
+            else (s["a_norms"], s["b_norms"])
+            for s in union_view(smap, am, bm, an, bn)]
+
+
+def test_cannon_union_view_norms_union_of_max(rng):
     """Brute force: at each step the built tensor is the max over all
     (i, j) ranks of that rank's chunk norm products — so eps drops a
     triple only when it is sub-eps on EVERY rank."""
@@ -259,7 +268,7 @@ def test_cannon_step_norms_union_of_max(rng):
     nb = pg * lb
     an = np.abs(rng.randn(nb, nb)).astype(np.float32)
     bn = np.abs(rng.randn(nb, nb)).astype(np.float32)
-    steps = cannon_step_norms(an, bn, pg)
+    steps = _union_norms(cannon_step_map(nb, nb, nb, pg), an, bn)
     assert len(steps) == pg
     for t, built in enumerate(steps):
         ref = np.zeros((lb, lb, lb))
@@ -272,12 +281,12 @@ def test_cannon_step_norms_union_of_max(rng):
         np.testing.assert_allclose(built, ref, rtol=1e-6)
 
 
-def test_summa_step_norms_factored_max(rng):
+def test_summa_union_view_norms_factored_max(rng):
     pr = pc = 2
     nb = 4
     an = np.abs(rng.randn(nb, nb)).astype(np.float32)
     bn = np.abs(rng.randn(nb, nb)).astype(np.float32)
-    panels = summa_step_norms(an, bn, pr, pc, 2)
+    panels = _union_norms(summa_step_map(nb, nb, nb, pr, pc, 2), an, bn)
     assert len(panels) == 2
     for p, (ua, ub) in enumerate(panels):
         ksl = slice(p * 2, (p + 1) * 2)

@@ -100,7 +100,7 @@ def test_explain_lists_candidates():
 
 
 # ---------------------------------------------------------------------------
-# plan cache + trivial plan (the _masks_empty short-circuit)
+# plan cache + trivial plan (the masks_empty short-circuit)
 # ---------------------------------------------------------------------------
 
 

@@ -10,9 +10,11 @@ Three layers:
    (densified and blocked/stepwise).  ``pipeline_depth=2`` must agree
    numerically (allclose) with depth 1.
 2. **Mask-slice property tests** (host-side): the per-step union mask
-   slices emitted by the schedule builders (``cannon_step_masks`` /
-   ``summa_step_masks`` / ``ts_step_masks``) must match a brute-force
-   enumeration of every rank's present triples at every step.
+   slices of each schedule's step map (``union_view`` of
+   ``cannon_step_map`` / ``summa_step_map`` / ``ts_step_map``) must
+   match a brute-force enumeration of every rank's present triples at
+   every step, and Cannon's map must name the K chunk its skew and
+   shifts deliver to each rank.
 3. **Ragged executor bins** (host-side): the size-binned stack executor
    must be bit-identical to the legacy looped dispatch, collapse to a
    single legacy-layout bin for uniform (dense) plans, and report the
@@ -24,6 +26,8 @@ import numpy as np
 import pytest
 
 from conftest import run_subprocess_devices
+
+from repro.core.steps import union_view
 
 # ---------------------------------------------------------------------------
 # 1. oracle battery: schedule engine vs the pre-refactor loops
@@ -42,12 +46,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.compat import make_mesh
 from repro.core.blocking import GridSpec
 from repro.core.cannon import (_default_local_matmul, _shift_perm,
-                               _skew_perm, cannon_matmul, cannon_step_masks)
+                               _skew_perm, cannon_matmul, cannon_step_map)
 from repro.core.cannon25d import _skew25d_perm, cannon25d_matmul
-from repro.core.summa import summa_matmul, summa_n_panels, summa_step_masks
+from repro.core.summa import summa_matmul, summa_n_panels, summa_step_map
 from repro.core.tall_skinny import tall_skinny_matmul
-from repro.core.multiply import _stepwise_blocked_lm, distributed_matmul
+from repro.core.multiply import _blocked_steps_lm, distributed_matmul
 from repro.core.stacks import normalize_block_masks
+from repro.core.steps import union_view
 
 
 # ---- pre-refactor loops, preserved verbatim as the oracle -------------
@@ -212,16 +217,14 @@ def blocked_lm_for(algo, mesh, grid, m, k, n, am, bm):
     if algo in ("cannon", "cannon25d"):
         pg = pr
         c_repl = grid.stack_size(mesh) if algo == "cannon25d" else 1
-        steps = [{"pair_mask": pm}
-                 for pm in cannon_step_masks(amn, bmn, pg, c_repl)]
-        return _stepwise_blocked_lm(m // pg, k // pg, n // pg,
-                                    mask_steps=steps, **kw)
+        smap = cannon_step_map(*amn.shape, bmn.shape[1], pg, c_repl)
+        steps = [[u] for u in union_view(smap, amn, bmn)]
+        return _blocked_steps_lm(m // pg, k // pg, n // pg, steps, **kw)
     assert algo == "summa"
     n_panels = summa_n_panels(pr, pc)
-    steps = [{"a_mask": ua, "b_mask": ub}
-             for ua, ub in summa_step_masks(amn, bmn, pr, pc, n_panels)]
-    return _stepwise_blocked_lm(m // pr, k // n_panels, n // pc,
-                                mask_steps=steps, **kw)
+    smap = summa_step_map(*amn.shape, bmn.shape[1], pr, pc, n_panels)
+    steps = [[u] for u in union_view(smap, amn, bmn)]
+    return _blocked_steps_lm(m // pr, k // n_panels, n // pc, steps, **kw)
 
 
 def run_case(tag, legacy_fn, engine_fn, depth2_fn, ref):
@@ -381,7 +384,7 @@ def test_auto_plan_carries_schedule(battery):
 
 
 # ---------------------------------------------------------------------------
-# 2. mask-slice property tests: builders vs brute-force rank enumeration
+# 2. mask-slice property tests: step maps vs brute-force rank enumeration
 # ---------------------------------------------------------------------------
 
 
@@ -391,16 +394,23 @@ def _random_masks(rng, nbr, nbk, nbc, fill):
     return am, bm
 
 
+def _union_masks(smap, am, bm):
+    """The union view's masks per step: the pair tensor, or the factored
+    (a_mask, b_mask) pair."""
+    return [s["pair_mask"] if "pair_mask" in s else (s["a_mask"], s["b_mask"])
+            for s in union_view(smap, am, bm)]
+
+
 @pytest.mark.parametrize("pg,c_repl", [(2, 1), (4, 1), (4, 2), (3, 1)])
 @pytest.mark.parametrize("fill", [1.0, 0.4, 0.1])
-def test_cannon_step_masks_match_per_rank_enumeration(pg, c_repl, fill):
-    from repro.core.cannon import cannon_step_masks
+def test_cannon_union_view_matches_per_rank_enumeration(pg, c_repl, fill):
+    from repro.core.cannon import cannon_step_map
 
     rng = np.random.RandomState(pg * 10 + int(fill * 10))
     lr, lk, lc = 2, 3, 2
     nbr, nbk, nbc = pg * lr, pg * lk, pg * lc
     am, bm = _random_masks(rng, nbr, nbk, nbc, fill)
-    got = cannon_step_masks(am, bm, pg, c_repl)
+    got = _union_masks(cannon_step_map(nbr, nbk, nbc, pg, c_repl), am, bm)
     spr = pg // c_repl
     assert len(got) == spr
 
@@ -420,17 +430,69 @@ def test_cannon_step_masks_match_per_rank_enumeration(pg, c_repl, fill):
         np.testing.assert_array_equal(got[t], want[t])
 
 
+@pytest.mark.parametrize("pg", [2, 4])
+@pytest.mark.parametrize("c_repl", [1, 2])
+def test_cannon_step_map_matches_skew_and_shifts(pg, c_repl):
+    """The host's step map and the device's permutations state one fact:
+    at step t, rank r holds the A and B chunks that the skew
+    (``_skew_perm``, or ``_skew25d_perm`` for 2.5D) and then t
+    applications of ``_shift_perm`` deliver to it."""
+    from repro.core.cannon import _shift_perm, _skew_perm, cannon_step_map
+    from repro.core.cannon25d import _skew25d_perm
+
+    spr = pg // c_repl
+    lr, lk, lc = 2, 3, 1
+    smap = cannon_step_map(pg * lr, pg * lk, pg * lc, pg, c_repl)
+
+    def flat(p, i, j):
+        return (p * pg + i) * pg + j
+
+    ranks = [(p, i, j) for p in range(c_repl) for i in range(pg)
+             for j in range(pg)]
+    # every rank enters with its own chunks, replicated over replicas:
+    # A chunk (row i, K j) and B chunk (K i, column j)
+    a_held = [(i, j) for _, i, j in ranks]
+    b_held = [(i, j) for _, i, j in ranks]
+
+    def permute(held, pairs):
+        out = list(held)
+        for src, dst in pairs:
+            out[dst] = held[src]
+        return out
+
+    if c_repl == 1:
+        a_held = permute(a_held, _skew_perm(pg, "a"))
+        b_held = permute(b_held, _skew_perm(pg, "b"))
+    else:
+        a_held = permute(a_held, _skew25d_perm(pg, c_repl, spr, "a"))
+        b_held = permute(b_held, _skew25d_perm(pg, c_repl, spr, "b"))
+    # A shifts along the column axis, B along the row axis
+    a_shift = [(flat(p, i, s), flat(p, i, d)) for p in range(c_repl)
+               for i in range(pg) for s, d in _shift_perm(pg)]
+    b_shift = [(flat(p, s, j), flat(p, d, j)) for p in range(c_repl)
+               for j in range(pg) for s, d in _shift_perm(pg)]
+    assert len(smap.index) == spr
+    for t in range(spr):
+        for r, ((rows, ks), (ks_b, cols)) in enumerate(smap.index[t]):
+            assert ks == ks_b
+            assert a_held[r] == (rows.start // lr, ks.start // lk), (t, r)
+            assert b_held[r] == (ks.start // lk, cols.start // lc), (t, r)
+        a_held = permute(a_held, a_shift)
+        b_held = permute(b_held, b_shift)
+
+
 @pytest.mark.parametrize("pr,pc", [(2, 2), (4, 1), (2, 4), (3, 2)])
 @pytest.mark.parametrize("fill", [1.0, 0.4, 0.1])
-def test_summa_step_masks_match_per_rank_enumeration(pr, pc, fill):
-    from repro.core.summa import summa_n_panels, summa_step_masks
+def test_summa_union_view_matches_per_rank_enumeration(pr, pc, fill):
+    from repro.core.summa import summa_n_panels, summa_step_map
 
     rng = np.random.RandomState(pr * 10 + pc + int(fill * 10))
     n_panels = summa_n_panels(pr, pc)
     lr, lc, lkp = 2, 2, 2
     nbr, nbc, nbk = pr * lr, pc * lc, n_panels * lkp
     am, bm = _random_masks(rng, nbr, nbk, nbc, fill)
-    got = summa_step_masks(am, bm, pr, pc, n_panels)
+    got = _union_masks(summa_step_map(nbr, nbk, nbc, pr, pc, n_panels),
+                       am, bm)
     assert len(got) == n_panels
     for p in range(n_panels):
         ksl = slice(p * lkp, (p + 1) * lkp)
@@ -452,8 +514,8 @@ def test_summa_step_masks_match_per_rank_enumeration(pr, pc, fill):
 
 @pytest.mark.parametrize("mode", ["ts_k", "ts_m", "ts_n"])
 @pytest.mark.parametrize("fill", [1.0, 0.3])
-def test_ts_step_masks_match_per_rank_enumeration(mode, fill):
-    from repro.core.tall_skinny import ts_step_masks
+def test_ts_union_view_matches_per_rank_enumeration(mode, fill):
+    from repro.core.tall_skinny import ts_step_map
 
     rng = np.random.RandomState(
         {"ts_k": 11, "ts_m": 22, "ts_n": 33}[mode] + int(fill * 10))
@@ -462,7 +524,7 @@ def test_ts_step_masks_match_per_rank_enumeration(mode, fill):
         4 * (p_all if mode == "ts_k" else 1), \
         4 * (p_all if mode == "ts_n" else 1)
     am, bm = _random_masks(rng, nbr, nbk, nbc, fill)
-    got = ts_step_masks(mode, am, bm, p_all)
+    got = union_view(ts_step_map(mode, nbr, nbk, nbc, p_all), am, bm)[0]
     if mode == "ts_k":
         lk = nbk // p_all
         want = np.zeros((nbr, lk, nbc), dtype=bool)

@@ -15,10 +15,21 @@ from conftest import run_subprocess_devices
 from repro.core import engine
 from repro.core.blocking import BlockLayout
 from repro.core.densify import blocked_local_matmul
-from repro.core.cannon import cannon_step_masks as _cannon_pair_masks
-from repro.core.multiply import _masks_empty, _stepwise_blocked_lm
-from repro.core.summa import summa_step_masks as _summa_panel_masks
+from repro.core.cannon import cannon_step_map
+from repro.core.multiply import _blocked_steps_lm
+from repro.core.steps import masks_empty as _masks_empty, union_view
+from repro.core.summa import summa_step_map
 from repro.core.stacks import build_stacks
+
+
+def _cannon_pair_masks(am, bm, pg):
+    smap = cannon_step_map(*am.shape, bm.shape[1], pg)
+    return [s["pair_mask"] for s in union_view(smap, am, bm)]
+
+
+def _summa_panel_masks(am, bm, pr, pc, n_panels):
+    smap = summa_step_map(*am.shape, bm.shape[1], pr, pc, n_panels)
+    return [(s["a_mask"], s["b_mask"]) for s in union_view(smap, am, bm)]
 
 
 def _expand(mask, bs):
@@ -292,8 +303,8 @@ def test_cannon_pair_masks_skip_steps():
     bm2[:4, :4] = True  # B chunk (0, 0) only
     pairs2 = _cannon_pair_masks(am2, bm2, 2)
     assert [p.any() for p in pairs2] == [True, False]
-    lm = _stepwise_blocked_lm(32, 32, 32, mask_steps=[
-        {"pair_mask": p} for p in pairs2],
+    lm = _blocked_steps_lm(32, 32, 32, [
+        [{"pair_mask": p}] for p in pairs2],
         block_m=8, block_k=8, block_n=8, stack_size=None, align=None,
         kernel="ref")
     assert lm.stepwise and lm.empty_steps == frozenset({1})
