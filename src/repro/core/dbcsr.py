@@ -16,6 +16,8 @@ DBCSR).  This keeps every array shape static — mandatory for pjit.
 from __future__ import annotations
 
 import dataclasses
+import math
+import numbers
 from typing import Optional
 
 import jax
@@ -37,7 +39,9 @@ __all__ = ["DBCSRMatrix", "create", "multiply", "multiply_batched",
 class DBCSRMatrix:
     """A distributed blocked matrix.
 
-    data       : (rows, cols) jax.Array, sharded P(row_axis, col_axis)
+    data       : (rows, cols) jax.Array, sharded P(row_axis, col_axis):
+                 the payload times any factor ``scale`` left pending
+                 (multiplied on the first read and kept on the instance)
     layout     : block structure metadata
     grid       : mesh-axis names of the process grid
     block_mask : optional (nbr, nbc) numpy bool — block-sparse occupancy
@@ -49,13 +53,21 @@ class DBCSRMatrix:
     Products returned by ``multiply`` additionally carry the executed
     ``MultiplyPlan`` as a plain ``last_plan`` attribute (host-side
     observability only — not part of the pytree, does not survive jit).
+
+    A concrete factor given to ``scale`` stays pending beside the
+    payload, as a host attribute of this matrix: ``multiply`` folds it
+    into a densified product, and ``data`` (so also ``tree_flatten``)
+    multiplies it out.
     """
 
-    data: jax.Array
+    data: jax.Array     # a property over the payload, set below the class
     layout: BlockLayout
     grid: GridSpec
     block_mask: Optional[np.ndarray] = None
     block_norms: Optional[np.ndarray] = None
+
+    _factor = None      # the pending scale factor; None: nothing pending
+    _scaled = None      # payload * _factor, once ``data`` has read it
 
     # -- pytree protocol (data is a leaf; the rest is static) ----------
     def tree_flatten(self):
@@ -84,10 +96,22 @@ class DBCSRMatrix:
             norms = np.frombuffer(raw, dtype=np.float32).reshape(shape).copy()
         return cls(children[0], layout, grid, mask, norms)
 
+    def _read_data(self) -> jax.Array:
+        if self._factor is None:
+            return self._payload
+        if self._scaled is None:
+            self._scaled = self._payload * self._factor
+            if obs.enabled():
+                obs.counter("scale.materialized").inc()
+        return self._scaled
+
+    def _write_data(self, value) -> None:
+        self._payload, self._factor, self._scaled = value, None, None
+
     # -- DBCSR-like API -------------------------------------------------
     @property
     def shape(self):
-        return self.data.shape
+        return self._payload.shape
 
     @property
     def occupancy(self) -> float:
@@ -133,12 +157,20 @@ class DBCSRMatrix:
         mask = None if self.block_mask is None else self.block_mask.T.copy()
         norms = (None if self.block_norms is None
                  else self.block_norms.T.copy())
-        return DBCSRMatrix(self.data.T, layout, self.grid, mask, norms)
+        out = DBCSRMatrix(self._payload.T, layout, self.grid, mask, norms)
+        out._factor = self._factor
+        return out
 
     def trace(self) -> jax.Array:
         return jnp.trace(self.data)
 
     def scale(self, alpha) -> "DBCSRMatrix":
+        """A * alpha.  A concrete real ``alpha`` (Python or NumPy) is
+        not multiplied here: the result keeps this payload with the
+        pending factor times ``alpha``, which ``multiply`` folds into a
+        densified product and ``data`` multiplies out on first read.
+        Any other ``alpha`` (a device array, a tracer) multiplies the
+        data now."""
         norms = None
         if self.block_norms is not None:
             try:
@@ -149,8 +181,21 @@ class DBCSRMatrix:
                          * np.float32(abs(float(alpha)))).astype(np.float32)
             except Exception:  # traced alpha cannot reach host numpy
                 norms = None
-        return dataclasses.replace(self, data=self.data * alpha,
-                                   block_norms=norms)
+        if not isinstance(alpha, numbers.Real):
+            return dataclasses.replace(self, data=self.data * alpha,
+                                       block_norms=norms)
+        out = DBCSRMatrix(self._payload, self.layout, self.grid,
+                          self.block_mask, norms)
+        out._factor = alpha if self._factor is None else self._factor * alpha
+        if obs.enabled():
+            obs.counter("scale.deferred").inc()
+        return out
+
+
+# the dataclass has read its fields' defaults by now, so ``data`` can
+# become a property: __init__, ``dataclasses.replace`` and the pytree
+# still take and give it as a field
+DBCSRMatrix.data = property(DBCSRMatrix._read_data, DBCSRMatrix._write_data)
 
 
 def _sharding(mesh: Mesh, grid: GridSpec) -> NamedSharding:
@@ -374,14 +419,21 @@ def multiply(
         c.verification = plan.verification
         return (c, plan) if return_plan else c
 
-    live = is_live(a.data, b.data)
+    # the operands' pending scale factors become one factor of the
+    # product, for the densified program to apply to C; every other path
+    # takes the operands' data
+    factors = [x._factor for x in (a, b) if x._factor is not None]
+    live = is_live(a._payload, b._payload)
     with obs.maybe_span(live, "multiply", cat="multiply", counters=True,
-                        **root_attrs(algorithm, a.data, b.data)):
+                        **root_attrs(algorithm, a._payload, b._payload)):
         an = bn = None
         if filter_eps is not None:
             an, bn = a.norms(), b.norms()
         return _distributed_matmul(
-            a.data, b.data, mesh=mesh, grid=a.grid,
+            a._payload, b._payload, alpha=math.prod(factors, start=1.0),
+            _scaled_operands=((lambda: (a.data, b.data)) if factors
+                              else None),
+            mesh=mesh, grid=a.grid,
             algorithm=algorithm, densify=densify,
             block_m=a.layout.block_rows, block_k=a.layout.block_cols,
             block_n=b.layout.block_cols,

@@ -561,12 +561,13 @@ def _densified_program(algorithm: str, a, b, *, mesh, grid, depth: int,
                        precision, local_kernel: Optional[str],
                        local_shape: tuple,
                        kw: dict) -> Tuple[Callable, dict, bool]:
-    """The densified dispatch as one ``jax.jit`` program ``(a, b) ->
-    C``, its ``_comm_count`` with the schedule's ``skew_panels``, from
-    one meta schedule (held beside it, so a warm call prices nothing),
-    and whether it came from the cache (a hit: this key's
-    program is compiled, so the call neither traces, lowers nor
-    compiles).
+    """The densified dispatch as one ``jax.jit`` program ``(a, b,
+    alpha) -> alpha * (a @ b)`` (``alpha`` a float32 scalar argument,
+    so a new factor compiles nothing), its ``_comm_count`` with the
+    schedule's ``skew_panels``, from one meta schedule (held beside it,
+    so a warm call prices nothing), and whether it came from the cache
+    (a hit: this key's program is compiled, so the call neither traces,
+    lowers nor compiles).
 
     The key is the static configuration the program depends on
     (algorithm, mesh, grid, pipeline depth, precision, local kernel and
@@ -586,10 +587,13 @@ def _densified_program(algorithm: str, a, b, *, mesh, grid, depth: int,
     lm = densified_local_matmul(precision, kernel=local_kernel)
     options = dict(key[6])
 
-    def densified_dispatch(a, b):
-        return _schedule_matmul(algorithm, a, b, mesh=mesh, grid=grid,
-                                local_matmul=lm, precision=precision,
-                                pipeline_depth=depth, **options)
+    def densified_dispatch(a, b, alpha):
+        c = _schedule_matmul(algorithm, a, b, mesh=mesh, grid=grid,
+                             local_matmul=lm, precision=precision,
+                             pipeline_depth=depth, **options)
+        # XLA fuses the factor into the op that writes C: the dot's
+        # output on one chip, the assembly of C's quarters on a mesh
+        return (c * alpha).astype(c.dtype)
 
     sched = _build_meta_schedule(
         algorithm, grid=grid, mesh=mesh, local_shape=local_shape,
@@ -785,10 +789,12 @@ def distributed_matmul(
     ``dbcsr.stacks``, ``dbcsr.dispatch``, ``dbcsr.finish`` (plus
     ``dbcsr.verify`` ⊃ ``dbcsr.repair``), with the JAX runtime's
     lowerings and compiles on the root and the dispatch, a densified
-    dispatch's ``program_cache`` ("hit" or "miss"), and the dispatch's
-    ``comm_steps`` and ``comm_bytes`` (``_comm_count``: the collective
-    phases and the most bytes any chip receives, held beside a
-    densified program, counted per call on the blocked path only when
+    dispatch's ``program_cache`` ("hit" or "miss") and ``alpha_fused``
+    (1 where the densified program applied a pending scale factor
+    other than 1 that ``dbcsr.multiply`` handed it, else 0), and the
+    dispatch's ``comm_steps`` and ``comm_bytes`` (``_comm_count``: the
+    collective phases and the most bytes any chip receives, held beside
+    a densified program, counted per call on the blocked path only when
     a profiler session or telemetry takes it), and its ``skew_panels``
     (``schedule.skew_panels``: 2 where the schedule runs its skew and
     steps on row/column panels, else 1; held beside a densified
@@ -859,6 +865,8 @@ def _distributed_matmul(
     verify: Optional[str] = None,
     verify_budget: Optional[float] = None,
     return_plan: bool = False,
+    alpha: float = 1.0,
+    _scaled_operands: Optional[Callable] = None,
     _live: bool = False,
     _finish=None,
     **kw,
@@ -871,10 +879,18 @@ def _distributed_matmul(
     finishing of the product (``dbcsr.multiply``'s result mask and
     wrap), run inside the finish span; its value is returned.
 
+    ``alpha`` and ``_scaled_operands``: ``dbcsr.multiply`` passes the
+    operands' payloads, the product of their pending scale factors as
+    ``alpha``, and ``_scaled_operands() -> (A, B)``, the operands with
+    their factors multiplied out.  The densified program applies
+    ``alpha`` to C; the blocked path and ABFT verification take
+    ``_scaled_operands()`` instead.
+
     The dispatch (``_run``): densified, the cached program of
-    ``_densified_program``, which records ``program_cache`` on the
-    dispatch span; blocked, the algorithm's eager ``shard_map`` over
-    this call's local multiply, then the inverse rebalance permutation.
+    ``_densified_program``, which records ``program_cache`` and
+    ``alpha_fused`` on the dispatch span; blocked, the algorithm's
+    eager ``shard_map`` over this call's local multiply, then the
+    inverse rebalance permutation.
     """
     _tele = _live and obs.enabled()
     m, k = a.shape
@@ -980,6 +996,11 @@ def _distributed_matmul(
                             "summa"):
             raise ValueError(f"unknown algorithm {algorithm!r}")
         depth = resolve_pipeline_depth(pipeline_depth, double_buffer)
+        if _scaled_operands is not None and (not densify
+                                             or verify is not None):
+            # the checksums and the stack plans see the operands as scaled
+            a, b = _scaled_operands()
+            alpha = 1.0
 
     with obs.maybe_span(_live, "stacks", cat="stacks"):
         # ---- costed rebalance: permute the block distribution -------------
@@ -1091,7 +1112,7 @@ def _distributed_matmul(
                 algorithm, a, b, mesh=mesh, grid=grid, depth=depth,
                 precision=precision, local_kernel=local_kernel,
                 local_shape=(ml, kl, nl), kw=kw)
-            return program(a, b), hit, comm
+            return program(a, b, np.float32(alpha)), hit, comm
         c = _schedule_matmul(
             algorithm, a_exec, b_exec, mesh=mesh, grid=grid,
             local_matmul=lm, precision=precision, pipeline_depth=depth,
@@ -1135,6 +1156,7 @@ def _distributed_matmul(
                     kw=kw)
             if comm:
                 dsp.set(**comm)
+            dsp.set(alpha_fused=int(alpha != 1))
             if hit is None:
                 # a blocked multiply's stack plans span the whole blocks
                 dsp.set(skew_panels=1)

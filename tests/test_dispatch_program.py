@@ -5,7 +5,10 @@ has seen neither lowers nor compiles, and its dispatch span says
 ``program_cache="hit"``; a changed precision, algorithm or shape is a
 miss with a program of its own; the blocked path keeps its eager
 dispatch and records neither; and a call under an outer ``jax.jit``
-still works.
+still works.  The operands' pending ``scale`` factors fold into the
+cached program as one traced factor of C: a new factor is a hit that
+compiles nothing, the dispatch reads ``alpha_fused``, and the blocked
+path and ABFT take the scaled data instead.
 
 Each case runs its battery in one subprocess, on its own host mesh, so
 that the program cache starts empty: ``ts_m`` on 1x1 and ``cannon`` on
@@ -24,12 +27,12 @@ from repro.core import dbcsr
 
 BATTERY = r"""
 import json, sys
-import numpy as np, jax
+import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.compat import make_mesh
-from repro.core import dbcsr, multiply
+from repro.core import dbcsr, multiply, schedule
 from repro.core.blocking import GridSpec
 from repro.core.densify import blocked_local_matmul
 from repro.core.multiply import distributed_matmul, _schedule_matmul
@@ -46,15 +49,16 @@ out = {}
 def operands(m=M, k=K, n=N):
     a = rng.randn(m, k).astype(np.float32)
     b = rng.randn(k, n).astype(np.float32)
-    return (a, b, dbcsr.create(a, mesh=mesh, block_size=BS),
-            dbcsr.create(b, mesh=mesh, block_size=BS))
+    am = dbcsr.create(a, mesh=mesh, block_size=BS)
+    bm = dbcsr.create(b, mesh=mesh, block_size=BS)
+    jax.block_until_ready((am.data, bm.data))
+    return a, b, am, bm
 
 
 def call(am, bm, **kw):
     # one multiply under telemetry: its product, the runtime's counts
     # over the call and the dispatch span's program_cache
     kw = {"algorithm": algorithm, "densify": True, **kw}
-    jax.block_until_ready((am.data, bm.data))
     n_programs = len(multiply._programs)
     obs.enable()
     mark = runtime.mark()
@@ -64,10 +68,14 @@ def call(am, bm, **kw):
     (disp,) = [s for s in obs.last_trace() if s.name == "dispatch"]
     hits = obs.counter("dispatch.program_cache.hits").value
     misses = obs.counter("dispatch.program_cache.misses").value
+    materialized = obs.counter("scale.materialized").value
     obs.disable()
     return c, {"lowerings": counts["lowerings"],
                "compiles": counts["compiles"],
                "program_cache": disp.attrs.get("program_cache"),
+               "alpha_fused": disp.attrs.get("alpha_fused"),
+               "skew_panels": disp.attrs.get("skew_panels"),
+               "materialized": materialized,
                "hits": hits, "misses": misses,
                "new_programs": len(multiply._programs) - n_programs}
 
@@ -122,6 +130,38 @@ out["outer_jit_vs_f64"] = bool(np.allclose(c_outer, ref1, rtol=1e-4,
                                            atol=1e-4))
 out["outer_jit_equals_miss"] = bool(np.allclose(c_outer, c_miss,
                                                 rtol=1e-6, atol=1e-6))
+
+# pending scale factors folded into the program, on blocks large enough
+# for Cannon's panelled program once its threshold is lowered to the
+# lane width (local 304 x 176 @ 176 x 224 on 2x2)
+schedule.SKEW_PANEL_MIN = 128
+a4, b4, a4m, b4m = operands(608, 352, 448)
+c_plain, out["plain"] = call(a4m, b4m)
+s, r = 1.25, np.float32(-0.5)
+c_scaled, out["scaled"] = call(a4m.scale(s), b4m.scale(r))
+c_new_alpha, out["new_alpha"] = call(a4m.scale(0.875), b4m)
+c_eager, _ = call(a4m.scale(jnp.float32(s)), b4m.scale(jnp.float32(r)))
+mag = np.abs(a4).astype(np.float64) @ np.abs(b4).astype(np.float64)
+
+
+def err(c, ref, factor):
+    return float(np.max(np.abs(c - factor * ref) / (abs(factor) * mag)))
+
+
+out["scaled_err"] = err(c_scaled, c_plain, s * float(r))
+out["new_alpha_err"] = err(c_new_alpha, c_plain, 0.875)
+out["scaled_vs_eager_err"] = err(c_scaled, c_eager, 1.0)
+# the blocked path and ABFT take the scaled data
+c_blocked_scaled, out["blocked_scaled"] = call(
+    a4m.scale(s), b4m.scale(r), densify=False, local_kernel="ref")
+c_blocked_eager, _ = call(a4m.scale(jnp.float32(s)),
+                          b4m.scale(jnp.float32(r)), densify=False,
+                          local_kernel="ref")
+out["blocked_scaled_equals_eager"] = bool(
+    np.array_equal(c_blocked_scaled, c_blocked_eager))
+c_verified, out["verified"] = call(a4m.scale(s), b4m.scale(r),
+                                   verify="checksum")
+out["verified_err"] = err(c_verified, c_eager, 1.0)
 print(json.dumps(out))
 """
 
@@ -134,7 +174,8 @@ def battery(request):
     code = (f"import sys; sys.argv = ['battery', {algorithm!r}, "
             f"'{pr}', '{pc}']\n" + BATTERY)
     stdout = run_subprocess_devices(code, n_devices=pr * pc)
-    return json.loads(stdout.strip().splitlines()[-1])
+    return dict(json.loads(stdout.strip().splitlines()[-1]),
+                case=request.param)
 
 
 def test_first_call_is_a_miss_with_its_own_program(battery):
@@ -186,6 +227,46 @@ def test_distributed_matmul_inside_an_outer_jit(battery):
     assert battery["outer_jit_equals_miss"]
 
 
+# one bf16 pass on each operand: the worst element's error over |A||B|
+ONE_BF16_PASS = 2.0 ** -8
+
+
+def test_scaled_multiply_folds_its_factor_into_the_product(battery):
+    """multiply(A.scale(s), B.scale(r)) is multiply(A, B) times s r, and
+    the eagerly scaled product, within one bf16 pass; the dispatch says
+    the factor was folded, and nothing multiplied A or B out."""
+    assert battery["scaled_err"] <= ONE_BF16_PASS
+    assert battery["new_alpha_err"] <= ONE_BF16_PASS
+    assert battery["scaled_vs_eager_err"] <= ONE_BF16_PASS
+    for name in ("scaled", "new_alpha"):
+        assert battery[name]["alpha_fused"] == 1
+        assert battery[name]["materialized"] == 0
+    assert battery["plain"]["alpha_fused"] == 0
+
+
+def test_warm_call_with_a_new_factor_compiles_nothing(battery):
+    for name in ("scaled", "new_alpha"):
+        assert battery[name]["program_cache"] == "hit"
+        assert battery[name]["new_programs"] == 0
+        assert battery[name]["lowerings"] == battery[name]["compiles"] == 0
+
+
+def test_scaled_multiply_keeps_the_skew_panels_rule(battery):
+    # Cannon on 2x2 takes the panelled program, as the unscaled call does
+    panels = 2 if battery["case"] == "cannon-2x2" else 1
+    assert battery["plain"]["skew_panels"] == panels
+    assert battery["scaled"]["skew_panels"] == panels
+
+
+def test_blocked_and_verified_multiplies_take_the_scaled_data(battery):
+    blocked, verified = battery["blocked_scaled"], battery["verified"]
+    assert blocked["alpha_fused"] == verified["alpha_fused"] == 0
+    # each reads both operands' data: two passes each
+    assert (blocked["materialized"], verified["materialized"]) == (2, 4)
+    assert battery["blocked_scaled_equals_eager"]
+    assert battery["verified_err"] <= ONE_BF16_PASS
+
+
 def test_profiled_warm_dispatch_carries_its_hit(tmp_path):
     # the profiler's trace of a warm densified call: the dispatch span's
     # metadata says "hit", and the call lowered and compiled nothing
@@ -207,3 +288,34 @@ def test_profiled_warm_dispatch_carries_its_hit(tmp_path):
     (disp,) = [s for s in spans if s.name == "dbcsr.dispatch"]
     assert disp.attrs["program_cache"] == "hit"
     assert root.attrs["lowerings"] == root.attrs["compiles"] == 0
+
+
+def test_benchmark_step_never_multiplies_the_scale_out():
+    # the timed path of ``closed_fresh_scale``: form scales A, call
+    # multiplies, wait blocks on C; the factor is folded every step
+    from types import SimpleNamespace
+
+    from bench.steps import scaled_multiply
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    rng = np.random.RandomState(2)
+    a, b = (rng.randn(88, 88).astype(np.float32) for _ in range(2))
+    ops = SimpleNamespace(a=a, b=b, a_mask=None, b_mask=None,
+                          a_norms=None, b_norms=None)
+    config = {"block_size": 22, "multiply": {"densify": True}}
+    step = scaled_multiply.build(config, ops, mesh, chips=1)
+    obs.clear_metrics()
+    obs.enable()
+    try:
+        for s in (0.95, 1.05, 0.9):
+            c = step.call(step.form(s))
+            step.wait(c)
+            (disp,) = [x for x in obs.last_trace() if x.name == "dispatch"]
+            assert disp.attrs["alpha_fused"] == 1
+            np.testing.assert_allclose(np.asarray(c.data), s * (a @ b),
+                                       rtol=1e-4, atol=1e-3)
+        assert obs.counter("scale.deferred").value == 3
+        assert obs.counter("scale.materialized").value == 0
+    finally:
+        obs.disable()
+        obs.clear_metrics()

@@ -70,7 +70,7 @@ _, out["warm"] = dispatch_attrs(a=A.scale(1.0625), **cannon)
 # the compiled program's op names
 (key,) = [k for k in multiply._programs if k[0] == "cannon"]
 program = multiply._programs[key][0]
-hlo = program.lower(A.data, B.data).compile().as_text()
+hlo = program.lower(A.data, B.data, np.float32(1)).compile().as_text()
 out["op_names"] = sorted({line.split('op_name="', 1)[1].split('"', 1)[0]
                           for line in hlo.splitlines() if 'op_name="' in line})
 

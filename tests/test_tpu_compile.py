@@ -7,7 +7,8 @@ installed TPU compiler: the smm stack kernel at DBCSR's block sizes at a
 30,000-entry stack, the fused scan executor, one Cannon and one SUMMA
 ``shard_map`` program on the 2x2 mesh, and the benchmark's 2x2 dense
 product, whose scheduled program must overlap the skew's second panel
-with the first quarter's dot.
+with the first quarter's dot; both benchmark cells' programs take the
+scale factor of their product in an op they run anyway.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
@@ -28,7 +29,7 @@ from jax.sharding import AxisType, SingleDeviceSharding
 from repro.core import multiply, schedule
 from repro.core.blocking import GridSpec
 from repro.core.cannon import cannon_matmul
-from repro.core.densify import blocked_local_matmul
+from repro.core.densify import blocked_local_matmul, densified_local_matmul
 from repro.core.summa import summa_matmul, summa_n_panels
 from repro.kernels.smm import ops as smm_ops
 
@@ -135,6 +136,7 @@ def test_distributed_multiply_compiles_on_2x2(mesh2x2, algorithm, densify):
 
 
 CELL_N = 33792   # the benchmark's dense-n33792-2x2 cell
+ONE_CHIP_N = 11264   # the benchmark's dense-n11264-1chip cell
 HLO_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.+?) ([a-z][a-z0-9-]*)\((.*)$")
 
 
@@ -147,7 +149,8 @@ def _cell_program(mesh):
         "cannon", x, x, mesh=mesh, grid=GridSpec(), depth=2,
         precision=jax.lax.Precision.DEFAULT, local_kernel=None,
         local_shape=(CELL_N // 2,) * 3, kw={})
-    return program.lower(x, x).compile(), info["skew_panels"]
+    alpha = jax.ShapeDtypeStruct((), jnp.float32)
+    return program.lower(x, x, alpha).compile(), info["skew_panels"]
 
 
 def _entry_ops(text):
@@ -209,3 +212,39 @@ def test_cell_skew_panels_overlap_the_first_dot(mesh2x2, monkeypatch):
     grown = (compiled.memory_analysis().temp_size_in_bytes
              - one.memory_analysis().temp_size_in_bytes)
     assert grown < 1 << 30
+
+
+@pytest.mark.parametrize("side", [1, 2], ids=["one_chip", "2x2"])
+def test_cell_scale_factor_adds_no_pass_over_c(topo, side, monkeypatch):
+    """The factor ``dbcsr.multiply`` folds into each cell's densified
+    program lands in an op the program runs anyway, the dot's output on
+    one chip and the assembly of C's quarters on 2x2: the program runs
+    fusions of the same shapes as the schedule without the factor, one
+    of them takes the factor, and no op multiplies on its own."""
+    n = ONE_CHIP_N if side == 1 else CELL_N
+    mesh = Mesh(np.array(topo.devices[:side * side]).reshape(side, side),
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    x = _sds((n, n), jnp.float32, NamedSharding(mesh, P("data", "model")))
+    monkeypatch.setattr(multiply, "_programs", collections.OrderedDict())
+    program, _, _ = multiply._densified_program(
+        "cannon", x, x, mesh=mesh, grid=GridSpec(), depth=2,
+        precision=jax.lax.Precision.DEFAULT, local_kernel=None,
+        local_shape=(n // side,) * 3, kw={})
+    alpha = jax.ShapeDtypeStruct((), jnp.float32)
+    scaled = _entry_ops(program.lower(x, x, alpha).compile().as_text())
+    plain = _entry_ops(jax.jit(lambda a, b: multiply._schedule_matmul(
+        "cannon", a, b, mesh=mesh, grid=GridSpec(),
+        local_matmul=densified_local_matmul(),
+        precision=jax.lax.Precision.DEFAULT, pipeline_depth=2)).lower(
+            x, x).compile().as_text())
+
+    def fusions(ops):
+        return collections.Counter(shape for _, shape, opcode, _ in ops
+                                   if opcode == "fusion")
+
+    assert fusions(scaled) == fusions(plain)
+    assert [name for name, _, opcode, _ in scaled
+            if opcode == "fusion" and "multiply" in name] != []
+    assert [name for name, _, opcode, _ in plain
+            if opcode == "fusion" and "multiply" in name] == []
+    assert not [op for op in scaled if op[2] == "multiply"]
